@@ -13,11 +13,11 @@ available devices, two ways:
 Plus the scaling-story series (ISSUE 14): a short swarm churn storm
 (tools/swarm_run.py, device-free scheduler path) contributes
 ``sessions_per_chip``, ``fairness_jain_index``, and ``eviction_ms_p95``
-so MULTICHIP_*.json tracks multi-tenant packing across PRs, not only raw
-encoder throughput.
+so one run reports multi-tenant packing next to raw encoder throughput.
 
-Prints ONE JSON line with the better aggregate as the headline value and
-both breakdowns.
+Refuses to run without a TPU (``runtime.require_tpu``). Prints ONE JSON
+line with the better aggregate as the headline value, both breakdowns and
+the device JAX reports; exits non-zero if any phase raised.
 """
 
 from __future__ import annotations
@@ -144,7 +144,7 @@ def bench_mesh() -> dict:
         "mesh_sessions": n_sessions,
         # the devices the mesh actually spans (a SELKIES_TPU_MESH spec
         # may use fewer than the host has) — per-chip derivations from
-        # MULTICHIP_*.json must divide by this, not the host inventory
+        # this output must divide by this, not the host inventory
         "mesh_devices": int(mesh.devices.size),
         "mesh_spec": (f"session:{n_sess_ax},"
                       f"stripe:{mesh.shape['stripe']}"),
@@ -292,7 +292,12 @@ def bench_swarm() -> dict:
     }
 
 
-def main() -> None:
+def main() -> int:
+    from selkies_tpu.runtime import enable_compile_cache, require_tpu
+
+    device = require_tpu()
+    print("device:", json.dumps(device), file=sys.stderr)
+    enable_compile_cache()
     import jax.numpy as jnp
 
     from selkies_tpu.capture.synthetic import DeviceScrollSource
@@ -359,8 +364,9 @@ def main() -> None:
     else:
         best, best_sessions = fps, N_SESSIONS
         mode = "solo"
-    print(json.dumps({
+    result = {
         "metric": "tpuenc_jpeg_multisession_aggregate_fps",
+        "device": device,
         "value": round(best, 2),
         "unit": "fps",
         "mode": mode,
@@ -379,7 +385,12 @@ def main() -> None:
         **mesh,
         **sfe,
         **bench_swarm(),
-    }))
+    }
+    print(json.dumps(result))
+    failed = sorted(k for k in result if k.endswith("_error"))
+    if failed:
+        print("FAILED phases:", ", ".join(failed), file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":

@@ -231,6 +231,13 @@ class AsyncEncodeDriver:
 
     # -- driver thread ------------------------------------------------------
 
+    def compiling_for_s(self) -> float:
+        """Seconds the wrapped pipeline has been inside a program's
+        first-use compile (0.0 otherwise) — the capture loop's cue that a
+        quiet driver is compiling, not wedged."""
+        probe = getattr(self.pipe, "compiling_for_s", None)
+        return probe() if probe is not None else 0.0
+
     def pop_trace(self, seq: int):
         """Stage intervals for a harvested frame, keyed by DRIVER seq
         (the seq try_submit returned) — the capture loop's side of the

@@ -1,13 +1,12 @@
 """Device-side (on-TPU) H.264 CAVLC entropy coding for P slices.
 
-Why: the H.264 path's named steady-state bottleneck (BENCH_r05
-``h264_bottleneck``) is the per-batch D2H read of the block-sparse
+Why: the H.264 path's steady state paid a per-batch D2H read of the block-sparse
 coefficient buffer, plus a per-session host CPU cost for the native CAVLC
 coder (encoder/h264.py ``_entropy_pool``).  The JPEG path already proved
 the fix (encoder/device_entropy.py): run entropy coding on device and
 fetch only the compressed bits.  A P slice's mean bitstream is ~12.7 KB
 at 1080p — far below the sparse level transfer — so packing CAVLC on
-device shrinks the named bottleneck directly AND removes the per-session
+device shrinks that transfer directly AND removes the per-session
 host entropy threads (the "millions of users" scaling wall).
 
 Unlike CABAC, every CAVLC context is *data-parallel*: the nC context of a

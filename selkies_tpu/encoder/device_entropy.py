@@ -1,8 +1,8 @@
 """Device-side (on-TPU) baseline-JPEG Huffman entropy coding.
 
 Why: pulling DCT coefficients to the host costs ~6 MB/frame of D2H traffic —
-the dominant cost on PCIe-attached chips at high session counts and fatal on
-tunneled devices. Entropy coding *on device* shrinks the per-frame transfer to
+the dominant cost at high session counts. Entropy coding *on device*
+shrinks the per-frame transfer to
 the compressed bitstream itself (tens of KB). This is SURVEY.md §7 "hard part
 1" resolved as a data-parallel Huffman formulation that fits XLA/TPU.
 

@@ -31,6 +31,7 @@ from . import entropy_py
 from .h264_device import StagingRing
 from .jfif import EOI, jfif_headers
 from ..native import entropy_lib
+from ..runtime import CompileWatch
 from .jpeg_tables import std_tables
 
 
@@ -133,9 +134,9 @@ def _device_pipeline(pad_h: int, pad_w: int, stripe_h: int,
             alpha_inv=alpha_inv if watermark else None)
         words, nbytes, base, ovf = packer_fn(yq, cbq, crq)
         # One fetchable buffer per frame: 4*S words of metadata followed by
-        # the packed bitstream. Tunneled/RPC transports pay ~25-100 ms per
-        # transfer regardless of size, so the host must be able to harvest a
-        # frame with a single D2H read (see pipeline.PipelinedJpegEncoder).
+        # the packed bitstream, so the host harvests a frame with a single
+        # D2H read whatever a read's fixed cost is (see
+        # pipeline.PipelinedJpegEncoder).
         head = jnp.concatenate([
             nbytes.astype(jnp.uint32),
             base.astype(jnp.uint32),
@@ -231,6 +232,9 @@ class JpegStripeEncoder:
         #: this content and the degradation ladder's host rung is cheaper
         self.host_fallback_stripes_total = 0
 
+        #: first-use compile signal for this encoder's step (read by
+        #: the capture loop's wedge detector through the wrappers)
+        self.compile_watch = CompileWatch()
         self._prev = jnp.zeros((self.pad_h, self.pad_w, 3), dtype=jnp.uint8)
         self._static_frames = np.zeros(self.n_stripes, dtype=np.int64)
         self._painted = np.zeros(self.n_stripes, dtype=bool)
@@ -423,9 +427,10 @@ class JpegStripeEncoder:
         crows = self.stripe_h // 16
 
         if self.entropy == "device":
-            packed, new_prev, yq, cbq, crq = self._step(
-                self._stage_frame(frame), self._prev, self._qy, self._qc,
-                qsel, self._wm_scaled, self._alpha_inv)
+            with self.compile_watch.first_use("step"):
+                packed, new_prev, yq, cbq, crq = self._step(
+                    self._stage_frame(frame), self._prev, self._qy,
+                    self._qc, qsel, self._wm_scaled, self._alpha_inv)
             self._prev = new_prev
             mw = META_WORDS_PER_STRIPE * self.n_stripes
             head_np = np.asarray(packed[:mw])
@@ -442,11 +447,12 @@ class JpegStripeEncoder:
                     words_np, base_np, nbytes_np, ovf_np, emit, yq, cbq, crq)
             return self._assemble(emit, is_paint, scans)
 
-        yq, cbq, crq, damage, new_prev = _device_encode(
-            self._stage_frame(frame), self._prev, self._qy, self._qc, qsel,
-            stripe_h=self.stripe_h,
-            wm_scaled=self._wm_scaled, alpha_inv=self._alpha_inv,
-        )
+        with self.compile_watch.first_use("encode"):
+            yq, cbq, crq, damage, new_prev = _device_encode(
+                self._stage_frame(frame), self._prev, self._qy, self._qc,
+                qsel, stripe_h=self.stripe_h,
+                wm_scaled=self._wm_scaled, alpha_inv=self._alpha_inv,
+            )
         self._prev = new_prev
         yq, cbq, crq, damage = (np.asarray(a) for a in (yq, cbq, crq, damage))
         emit, is_paint = self._decide_emits(

@@ -1,6 +1,6 @@
 """rANS entropy coding prototype — the BASELINE config-3 decision spike.
 
-Context (SURVEY.md §7 hard part 1, VERDICT round-1 item 10): after the
+Context (SURVEY.md §7 hard part 1): after the
 JPEG-stripe latency data landed, the deferred decision was whether a
 learned-codec/rANS profile should replace or join the Huffman scan. This
 module is the measurement instrument for that gate: a correct,

@@ -1,16 +1,29 @@
 """Native (C++) runtime components, built lazily with the system toolchain.
 
-Each lib is a single ``g++ -O3 -shared`` invocation cached next to the
-sources; if no toolchain is available the callers fall back to the
-pure-Python implementations (slower but correct).
+Each lib is a single ``g++ -O3 -shared`` invocation from the ``.cpp`` file
+git tracks, cached next to the sources under a name that carries the hash
+of that source and its flags — a copied tree (whose mtimes mean nothing)
+can never load a binary built from other code, and no binary is tracked.
+Builds are serialized by a lock file and land with ``os.replace``, so
+concurrent processes (six test workers importing at once) each end up
+loading a complete library.
+
+If no toolchain is available the callers fall back to the pure-Python
+implementations (slower but correct) and the failure is logged as an
+error; paths that must not degrade (``chip_smoke.py``) call
+:func:`require`, which raises instead.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import glob
+import hashlib
 import logging
 import os
 import subprocess
+import tempfile
 import threading
 from typing import Callable, Optional
 
@@ -38,27 +51,35 @@ def _sanitize_mode() -> str:
     return mode
 
 
-def _compile_lib(src: str, so: str, extra: tuple = (),
-                 sanitize: str = "") -> bool:
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", so, src]
+def _build_cmd(src: str, out: str, extra: tuple, sanitize: str) -> list:
+    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", out, src]
     if sanitize:
         cmd += [f"-fsanitize={sanitize}", "-g", "-fno-omit-frame-pointer"]
-    cmd += list(extra)
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except (subprocess.SubprocessError, FileNotFoundError) as e:
-        logger.warning("native build of %s failed (%s)", src, e)
-        return False
+    return cmd + list(extra)
 
 
-def _stale(so: str, src: str) -> bool:
-    if not os.path.exists(so):
-        return True
+def _compile_lib(src: str, so: str, extra: tuple = (),
+                 sanitize: str = "") -> None:
+    """Build ``so`` from ``src`` atomically: compile to a temp file beside
+    it, then ``os.replace`` — a reader never sees a half-written library.
+    Raises RuntimeError (with the compiler's stderr) on failure."""
+    fd, tmp = tempfile.mkstemp(prefix=os.path.basename(so) + ".",
+                               suffix=".tmp", dir=os.path.dirname(so))
+    os.close(fd)
     try:
-        return os.path.getmtime(so) < os.path.getmtime(src)
-    except OSError:
-        return False  # source missing but .so present: use the .so
+        try:
+            subprocess.run(_build_cmd(src, tmp, extra, sanitize), check=True,
+                           capture_output=True, timeout=300)
+        except subprocess.CalledProcessError as e:
+            raise RuntimeError(
+                f"native build of {src} failed:\n"
+                f"{e.stderr.decode(errors='replace')[-2000:]}") from e
+        except (subprocess.SubprocessError, FileNotFoundError) as e:
+            raise RuntimeError(f"native build of {src} failed: {e}") from e
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 class _LazyLib:
@@ -66,31 +87,61 @@ class _LazyLib:
 
     def __init__(self, name: str, extra: tuple = (),
                  register: Optional[Callable] = None) -> None:
+        self.name = name
         self.src = os.path.join(_DIR, name + ".cpp")
         # resolved once so the flags and the cache filename can't diverge
         # (an env-var change after import must not write an instrumented
         # binary under the production .so name)
         self.sanitize = _sanitize_mode()
         suffix = f"_{self.sanitize}" if self.sanitize else ""
-        self.so = os.path.join(_DIR, f"_libselkies_{name}{suffix}.so")
+        self._stem = os.path.join(_DIR, f"_libselkies_{name}{suffix}")
         self.extra = extra
         self.register = register
         self._lock = threading.Lock()
         self._lib: Optional[ctypes.CDLL] = None
         self._tried = False
+        self.error: Optional[Exception] = None
+
+    @property
+    def so(self) -> str:
+        """``<stem>.<hash of source + build command>.so`` — the name IS
+        the staleness check."""
+        h = hashlib.sha256()
+        with open(self.src, "rb") as f:
+            h.update(f.read())
+        h.update(" ".join(_build_cmd("src", "out", self.extra,
+                                     self.sanitize)).encode())
+        return f"{self._stem}.{h.hexdigest()[:12]}.so"
+
+    def _build(self, so: str) -> None:
+        # one builder per library across processes; the losers of the
+        # race wake to find the finished file and skip their own build
+        with open(self._stem + ".lock", "w") as lk:
+            fcntl.flock(lk, fcntl.LOCK_EX)
+            if os.path.exists(so):
+                return
+            _compile_lib(self.src, so, self.extra, sanitize=self.sanitize)
+            for old in glob.glob(self._stem + ".*.so"):
+                if old != so:                 # builds of other sources
+                    try:
+                        os.unlink(old)
+                    except OSError:
+                        pass
 
     def get(self) -> Optional[ctypes.CDLL]:
         with self._lock:
             if self._lib is not None or self._tried:
                 return self._lib
             self._tried = True
-            if _stale(self.so, self.src) and not _compile_lib(
-                    self.src, self.so, self.extra, sanitize=self.sanitize):
-                return None
             try:
-                lib = ctypes.CDLL(self.so)
-            except OSError as e:
-                logger.warning("native lib %s load failed: %s", self.so, e)
+                so = self.so
+                if not os.path.exists(so):
+                    self._build(so)
+                lib = ctypes.CDLL(so)
+            except (OSError, RuntimeError) as e:
+                self.error = e
+                logger.error("native lib %s unavailable, pure-Python "
+                             "fallback in use: %s", self.name, e)
                 return None
             if self.register is not None:
                 self.register(lib)
@@ -211,3 +262,15 @@ def conformance_lib() -> Optional[ctypes.CDLL]:
 def audio_lib() -> Optional[ctypes.CDLL]:
     """Opus/Pulse audio runtime (the pcmflux equivalent), or None."""
     return _AUDIO.get()
+
+
+def require(*names: str) -> None:
+    """Build and load the named libs (``entropy``, ``cavlc``,
+    ``conformance``, ``audio``) or raise with the builder's error — for
+    paths where a silent pure-Python fallback would hide a broken build."""
+    libs = {"entropy": _ENTROPY, "cavlc": _CAVLC,
+            "conformance": _CONFORMANCE, "audio": _AUDIO}
+    for n in names:
+        if libs[n].get() is None:
+            raise RuntimeError(
+                f"native lib {n!r} required but unavailable: {libs[n].error}")

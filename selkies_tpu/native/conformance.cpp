@@ -143,7 +143,7 @@ int conf_dec_flush(void *h, uint8_t *y, uint8_t *u, uint8_t *v,
 }  // extern "C"
 
 // ---------------------------------------------------------------------------
-// Reference x264 encoder (quality-gate tooling, VERDICT r3 item 4).
+// Reference x264 encoder (quality-gate tooling).
 //
 // The reference's daily driver is pixelflux's x264 at preset superfast with
 // zerolatency tuning (reference gstwebrtc_app.py:609-640 x264enc

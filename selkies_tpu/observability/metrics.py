@@ -110,7 +110,7 @@ class Metrics:
         self.fetch_wait_ms = Histogram(
             "tpuenc_fetch_wait_ms", "Host wall time blocked materializing "
             "an eagerly-started D2H fetch (~0 when the overlap hides the "
-            "transfer; the RPC floor when it does not)",
+            "transfer; the transfer's latency when it does not)",
             buckets=(0.5, 1, 2, 4, 8, 16, 33, 66, 100, 250, float("inf")),
             registry=self.registry)
         # ISSUE 2: supervision / degradation observability — dropped and

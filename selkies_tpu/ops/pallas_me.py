@@ -24,8 +24,10 @@ the complete search per stripe with the planes resident on-chip:
 
 The public entry :func:`me_mc_stripes` takes stripe-batched planes
 (S, H, W) and returns (mv, pred_y, pred_cb, pred_cr) with the same
-semantics as ``vmap(full_search_mc)``. Falls back to interpreter mode
-off-TPU so the CPU test mesh exercises the same code path.
+semantics as ``vmap(full_search_mc)``. The kernel is compiled for the
+attached device unless the run asked for interpreter mode
+(``runtime.pallas_interpret``: the ``tpu_interpret`` setting, which
+tests/conftest.py sets so the CPU test mesh exercises the same code path).
 """
 
 from __future__ import annotations
@@ -38,12 +40,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..runtime import pallas_interpret
 from .motion import _offsets, pad_replicate
-
-#: jax ≥ 0.5 renamed TPUCompilerParams → CompilerParams; accept either so
-#: the interpret-mode CPU path keeps working on older runtimes
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 MB = 16
 
@@ -217,7 +215,7 @@ def me_mc_stripes(cur, ref, ref_cb, ref_cr, *, search: int = 12,
     with selection semantics identical to ``vmap(full_search_mc)``.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     S, h, w = cur.shape
     hc, wc = ref_cb.shape[-2:]
     nby, nbx = h // MB, w // MB
@@ -231,6 +229,10 @@ def me_mc_stripes(cur, ref, ref_cb, ref_cr, *, search: int = 12,
 
     kern = functools.partial(_me_mc_kernel, search=search, h=h, w=w,
                              hc=hc, wc=wc)
+    # inside a shard_map (the mesh H.264 lanes) the outputs vary over the
+    # same mesh axes as the planes; jax's varying-axes check wants that
+    # said on every out_shape (empty outside a shard_map)
+    vma = jax.typeof(cur).vma
     rank_w, py, pcb, pcr = pl.pallas_call(
         kern,
         grid=(S,),
@@ -256,10 +258,10 @@ def me_mc_stripes(cur, ref, ref_cb, ref_cr, *, search: int = 12,
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((S, nby, nbx), jnp.int32),
-            jax.ShapeDtypeStruct((S, h, w), jnp.uint8),
-            jax.ShapeDtypeStruct((S, hc, wc), jnp.uint8),
-            jax.ShapeDtypeStruct((S, hc, wc), jnp.uint8),
+            jax.ShapeDtypeStruct((S, nby, nbx), jnp.int32, vma=vma),
+            jax.ShapeDtypeStruct((S, h, w), jnp.uint8, vma=vma),
+            jax.ShapeDtypeStruct((S, hc, wc), jnp.uint8, vma=vma),
+            jax.ShapeDtypeStruct((S, hc, wc), jnp.uint8, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((max(8, nby), max(128, nbx)), jnp.int32),
@@ -268,7 +270,7 @@ def me_mc_stripes(cur, ref, ref_cb, ref_cr, *, search: int = 12,
         # 4K stripes (w=3840) need ~18 MB of scoped VMEM (the rolled
         # int32 window + the indicator constants); the default 16 MB
         # scope is conservative, not the physical limit
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )(ranks, cur, ref_pad, cbp, crp)

@@ -20,13 +20,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..encoder.jpeg import _encode_body
-
-#: jax ≥ 0.5 promoted shard_map out of experimental; accept either spelling
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - older runtimes
-    from jax.experimental.shard_map import shard_map
-
+from ..runtime import CompileWatch
 
 def fetch_sharded_prefix(prefix):
     """Materialize an eagerly-fetching sharded device array shard by
@@ -63,6 +57,21 @@ def fetch_sharded_prefix(prefix):
         t0 = time.perf_counter()
         host = np.asarray(prefix)
         return host, {0: (time.perf_counter() - t0) * 1000.0}
+
+
+def plane_sharding(mesh: Mesh) -> NamedSharding:
+    """``P("session", "stripe")`` on ``mesh``, spelled the way a jitted
+    step's OUTPUTS come back: axes of size 1 dropped, trailing ``None``
+    trimmed. jit keys its compiled programs on argument shardings, and a
+    state array that enters the first step as ``P("session", "stripe")``
+    returns as e.g. ``P(None, "stripe")`` — an equivalent sharding, a
+    different key, and so a second compile of the same (for H.264,
+    minutes-long) program on the second step. Starting in the returned
+    form compiles each step once."""
+    spec = [a if mesh.shape[a] > 1 else None for a in ("session", "stripe")]
+    while spec and spec[-1] is None:
+        spec.pop()
+    return NamedSharding(mesh, P(*spec))
 
 
 def make_mesh(
@@ -139,7 +148,7 @@ def make_batched_step(mesh: Mesh, stripe_h: int):
         total_bits = jax.lax.psum(session_bits.sum(), "session")
         return yq, cbq, crq, damage, new_prev, session_bits, total_bits
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(
@@ -166,7 +175,7 @@ def make_batched_entropy_step(mesh: Mesh, pad_h: int, pad_w: int,
                               stripe_h: int):
     """Sharded multi-session step that carries encode *through* device
     entropy coding: one mesh dispatch yields wire-ready packed bitstreams
-    for every session (VERDICT round-1 item 2 — BASELINE config 5).
+    for every session.
 
     Stripes are independent JPEGs (DC prediction resets per stripe,
     device_entropy.scan_geometry), so each device entropy-codes its local
@@ -218,7 +227,7 @@ def make_batched_entropy_step(mesh: Mesh, pad_h: int, pad_w: int,
         packed = jnp.concatenate([head, words], axis=1)[:, None, :]
         return (packed, new_prev, yq, cbq, crq, session_bytes, total_bytes)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(
@@ -378,8 +387,11 @@ class MeshStripeEncoder:
 
         self._step, (self.s_local, self._mw, self._cap, self._packer) = \
             make_batched_entropy_step(mesh, self.pad_h, self.pad_w, stripe_h)
-        self._frame_sharding = NamedSharding(mesh, P("session", "stripe"))
-        self._qsel_sharding = NamedSharding(mesh, P("session", "stripe"))
+        #: first-use compile signal of this lane's program (the sessions'
+        #: capture loops read it through their coordinator facade)
+        self.compile_watch = CompileWatch()
+        self._frame_sharding = plane_sharding(mesh)
+        self._qsel_sharding = plane_sharding(mesh)
         self._prev = jax.device_put(
             jnp.zeros((n_sessions, self.pad_h, self.pad_w, 3), jnp.uint8),
             self._frame_sharding)
@@ -412,7 +424,7 @@ class MeshStripeEncoder:
         zeroed prev frame so no stale pixels leak across occupants.
 
         force_keyframe alone is NOT enough the day an inter profile
-        rides the mesh (VERDICT r2 weak item 6): the previous occupant's
+        rides the mesh: the previous occupant's
         pixels would persist in the prev/reference planes and in the
         idle-tick re-present buffer."""
         self.force_keyframe(session)
@@ -482,9 +494,18 @@ class MeshStripeEncoder:
         qsel = jax.device_put(
             jnp.asarray(paint_candidate.astype(np.int32)),
             self._qsel_sharding)
+        # a PRIVATE copy goes to the device: JAX may still be reading a
+        # host array after device_put returns (and the CPU backend aliases
+        # it outright), while _last_host is rewritten by the next
+        # dispatch — with two dispatches in flight the lane encoded torn
+        # frames (chip_smoke.py --chips 4 caught it against a one-device
+        # replay)
+        if batch is self._last_host:
+            batch = batch.copy()
         frames_d = jax.device_put(jnp.asarray(batch), self._frame_sharding)
-        packed, self._prev, yq, cbq, crq, _sb, _total = self._step(
-            frames_d, self._prev, self._qy, self._qc, qsel)
+        with self.compile_watch.first_use("step"):
+            packed, self._prev, yq, cbq, crq, _sb, _total = self._step(
+                frames_d, self._prev, self._qy, self._qc, qsel)
 
         stride = self._mw + 1 + min(self._guess, self._cap)
         prefix = packed[:, :, :stride]

@@ -40,7 +40,8 @@ from ..encoder import device_cavlc as dcav
 from ..encoder import h264_device as dev
 from ..encoder.h264 import H264Stripe, encode_picture_nals_np, make_pps, make_sps
 from ..encoder.h264 import _entropy_pool
-from .mesh import fetch_sharded_prefix, shard_map
+from ..runtime import CompileWatch, pallas_interpret
+from .mesh import fetch_sharded_prefix, plane_sharding
 
 logger = logging.getLogger("selkies_tpu.parallel.h264")
 
@@ -64,7 +65,7 @@ def _merge_idr(enc_p: dev.StripeEncodeOut, enc_i: dev.StripeEncodeOut,
 def make_h264_mesh_step(mesh: Mesh, pad_h: int, pad_w: int, stripe_h: int,
                         *, search: int = dev.SEARCH, cap_frac: int = 4,
                         me: str = "xla", with_idr: bool = False,
-                        prefix: int = 0, entropy: str = "sparse",
+                        entropy: str = "sparse",
                         max_stripe_bytes: int = 0):
     """Build the jitted sharded multi-session H.264 step.
 
@@ -74,9 +75,9 @@ def make_h264_mesh_step(mesh: Mesh, pad_h: int, pad_w: int, stripe_h: int,
 
     frames [N, pad_h, pad_w, 3] uint8, sharded P("session", "stripe");
     plane state shards the same way; paint/idr are [N, S] int32 sharded
-    on ("session", "stripe"). ``me`` defaults to the XLA chunked search:
-    the Pallas kernel assumes the TPU backend, and the mesh path must
-    also run on the CPU test mesh — TPU deployments pass me="pallas".
+    on ("session", "stripe"). ``me`` here defaults to the XLA chunked
+    search; :class:`MeshH264Encoder` passes the compiled Pallas kernel
+    unless the run asked for interpreter mode.
 
     ``entropy="device"`` runs CAVLC shard-local (encoder/device_cavlc.py)
     so ``buf`` carries per-stripe bit-exact P-slice payloads instead of
@@ -130,11 +131,11 @@ def make_h264_mesh_step(mesh: Mesh, pad_h: int, pad_w: int, stripe_h: int,
         else:
             buf = dev._pack_sparse(flat16, damage, update,
                                    cap_frac=cap_frac)
-        # byte-prefix of the content-compacted buffer (head + bitmap +
-        # compacted cells), same contract as the solo encoder's
-        # two-tier head; harvest refetches exact rows on undershoot
-        if prefix:
-            buf = buf[:prefix]
+        # the fetched byte-prefix of this content-compacted buffer (head
+        # + bitmap + compacted cells) is cut by a program of its own
+        # (MeshH264Encoder._fetch_prefix), so this step — minutes of
+        # compile with device CAVLC inside — is built once per
+        # ``with_idr``, not once per prefix bucket
         return buf, flat16, y, cb, cr, nry, nrcb, nrcr
 
     def local_step(frames, prev_y, prev_cb, prev_cr,
@@ -146,7 +147,7 @@ def make_h264_mesh_step(mesh: Mesh, pad_h: int, pad_w: int, stripe_h: int,
         return (buf[:, None, :], flat16, y, cb, cr, nry, nrcb, nrcr)
 
     plane = P("session", "stripe")
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(plane, plane, plane, plane, plane, plane, plane,
@@ -210,7 +211,12 @@ class MeshH264Encoder:
         self.paint_over_trigger = int(paint_over_trigger_frames)
         self.search = search
         if me is None:
-            me = "pallas" if jax.default_backend() == "tpu" else "xla"
+            # the served default is the solo encoder's (SELKIES_TPU_ME,
+            # "pallas"). A run that asked for Pallas interpreter mode
+            # (tests/conftest.py) gets the XLA search instead: the
+            # interpreter's expansion of the kernel under vmap+shard_map
+            # compiles for minutes on the CPU test mesh.
+            me = "xla" if pallas_interpret() else dev._me_backend()
         self.me = me
 
         n = (stripe_h // MB) * (self.pad_w // MB)
@@ -251,9 +257,12 @@ class MeshH264Encoder:
             self._prefix = self._bucket(
                 self._fixed_bytes + self.s_local * (8 << 10))
 
-        self._steps: Dict[Tuple[bool, int], Any] = {}
+        self._steps: Dict[bool, Any] = {}
+        #: first-use compile signal of this lane's programs (the sessions'
+        #: capture loops read it through their coordinator facade)
+        self.compile_watch = CompileWatch()
 
-        plane = NamedSharding(mesh, P("session", "stripe"))
+        plane = plane_sharding(mesh)
         self._plane_sharding = plane
         self._frame_sharding = plane
         z = functools.partial(jax.device_put)
@@ -307,7 +316,7 @@ class MeshH264Encoder:
     def reset_session(self, session: int) -> None:
         """Recycle a slot: fresh history AND zeroed planes so no pixels
         leak across occupants (the inter refs would otherwise carry
-        them — the exact hazard VERDICT r2 flagged for mesh inter)."""
+        them — the known hazard for mesh inter)."""
         self.force_keyframe(session)
         self._frame_num[session] = 0
         self._last_host[session] = 0
@@ -335,14 +344,20 @@ class MeshH264Encoder:
             per <<= 1
         return min(self._fixed_bytes + per * self.s_local, self._buf_bytes)
 
-    def _step_for(self, with_idr: bool, prefix: int):
-        key = (with_idr, prefix)
+    @staticmethod
+    @functools.partial(jax.jit, static_argnames=("prefix",))
+    def _fetch_prefix(buf, *, prefix: int):
+        """[N, stripe_ax, L] → [N, stripe_ax, prefix], shard-local."""
+        return buf[:, :, :prefix]
+
+    def _step_for(self, with_idr: bool):
+        key = with_idr
         fn = self._steps.get(key)
         if fn is None:
             fn, _ = make_h264_mesh_step(
                 self.mesh, self.pad_h, self.pad_w, self.stripe_h,
                 search=self.search, me=self.me, with_idr=with_idr,
-                cap_frac=self._cap_frac, prefix=prefix,
+                cap_frac=self._cap_frac,
                 entropy="device" if self.entropy == "device" else "sparse",
                 max_stripe_bytes=self._cavlc_msb)
             self._steps[key] = fn
@@ -409,19 +424,30 @@ class MeshH264Encoder:
         self._need_idr &= reuse_prev[:, None]
 
         qp_arr = np.where(paint, self.paint_over_qp, self.qp)
-        fn = self._step_for(bool(idr.any()), self._prefix)
+        with_idr = bool(idr.any())
+        fn = self._step_for(with_idr)
+        # a PRIVATE copy goes to the device: JAX may still be reading a
+        # host array after device_put returns (and the CPU backend aliases
+        # it outright), while _last_host is rewritten by the next
+        # dispatch — with two dispatches in flight the lane encoded torn
+        # frames (chip_smoke.py --chips 4 caught it against a one-device
+        # replay)
+        if batch is self._last_host:
+            batch = batch.copy()
         frames_d = jax.device_put(jnp.asarray(batch),
                                   self._frame_sharding)
         paint_d = jax.device_put(jnp.asarray(paint.astype(np.int32)),
                                  self._plane_sharding)
         idr_d = jax.device_put(jnp.asarray(idr.astype(np.int32)),
                                self._plane_sharding)
-        (prefix, flat16, self._prev_y, self._prev_cb, self._prev_cr,
-         self._ref_y, self._ref_cb, self._ref_cr) = fn(
-            frames_d, self._prev_y, self._prev_cb, self._prev_cr,
-            self._ref_y, self._ref_cb, self._ref_cr,
-            paint_d, idr_d, jnp.int32(self.qp),
-            jnp.int32(self.paint_over_qp))
+        with self.compile_watch.first_use((with_idr, self._prefix)):
+            (buf, flat16, self._prev_y, self._prev_cb, self._prev_cr,
+             self._ref_y, self._ref_cb, self._ref_cr) = fn(
+                frames_d, self._prev_y, self._prev_cb, self._prev_cr,
+                self._ref_y, self._ref_cb, self._ref_cr,
+                paint_d, idr_d, jnp.int32(self.qp),
+                jnp.int32(self.paint_over_qp))
+            prefix = self._fetch_prefix(buf, prefix=self._prefix)
         prefix.copy_to_host_async()
         return _MeshH264Pending(
             prefix=prefix, buf=None, flat16=flat16, idr=idr,

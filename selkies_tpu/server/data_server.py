@@ -48,6 +48,7 @@ from ..robustness import (
     classify_verb,
     parse_limit_spec,
 )
+from ..runtime import COMPILE_GRACE_S
 from ..settings import SETTING_DEFINITIONS, Settings
 from .backpressure import CHECK_INTERVAL_S, BackpressureState
 
@@ -66,6 +67,11 @@ MAX_DISPLAY_DIM = 8192
 #: solo pipelines — the admission verdict and the acquire-time fallback
 #: must agree on this number, or verdicts shed clients solo could serve.
 MESH_BUCKET_CAP = 4
+
+#: floor of the capture loop's wedge deadline (it is four watchdog
+#: deadlines otherwise): a pipeline that neither accepts nor harvests for
+#: this long, and is not compiling, is dead
+WEDGE_MIN_S = 30.0
 
 
 def _clamp_dim(v: int) -> int:
@@ -242,7 +248,7 @@ def default_encoder_factory(
     docs/pipeline.md): a dedicated thread keeps >=2 batches in flight —
     dispatch of batch N+1 overlapped with batch N's D2H fetch — so the
     capture loop's submit/poll never touch the device and the served
-    encode latency tracks the chip, not the round-trip floor. Host
+    encode latency tracks the chip, not the dispatch/fetch floor. Host
     rungs keep the threaded adapter (their encode is synchronous by
     construction)."""
     from ..encoder.async_driver import AsyncEncodeDriver
@@ -252,9 +258,9 @@ def default_encoder_factory(
                                     ThreadedEncoderAdapter)
 
     #: frames encoded per device dispatch; >1 amortizes the fixed
-    #: dispatch RPC on tunneled transports at a latency cost — PCIe
-    #: deployments keep 1 (the re-armed batch deadline still bounds
-    #: staleness either way)
+    #: dispatch cost at a latency cost (the re-armed batch deadline still
+    #: bounds staleness either way). The default of 1 and what a larger
+    #: batch buys have not been re-measured on a directly attached chip.
     batch = max(1, int(os.environ.get("SELKIES_TPU_ASYNC_BATCH", "1")))
 
     ov = overrides or {}
@@ -405,12 +411,13 @@ class DataStreamingServer:
         self.bytes_sent = 0
         self.metrics = None         # wired by main() when prometheus is up
         self.audio_pipeline = None  # wired by main() when audio is enabled
+        self.warmup = None          # main.WarmUp: boot compile + its outcome
         self._audio_wanted = True   # cleared by STOP_AUDIO until re-requested
         self._last_layout = None    # last xrandr-applied Layout (dedup)
         #: mesh-batched encode (tpu_mesh setting, BASELINE configs 4/5):
         #: one coordinator per display geometry, lazily built — a
         #: mismatched-resolution join gets its own bucket instead of a
-        #: silent solo fallback (VERDICT r2 item 6)
+        #: silent solo fallback
         self.mesh_coordinators: Dict[Tuple[int, int, str], Any] = {}
         #: coordinator constructor override (tests / tools/swarm_run.py):
         #: same signature as MeshEncodeCoordinator — lets harnesses run
@@ -1470,15 +1477,17 @@ class DataStreamingServer:
             error_ticks = 0
             #: a pipeline that stops ACCEPTING submits and harvesting
             #: anything is wedged even though the loop itself still ticks
-            #: (e.g. a dead mesh worker); generous deadline so first-use
-            #: jit compiles never read as a wedge
+            #: (e.g. a dead mesh worker); a first-use jit compile is told
+            #: apart by THIS encoder's own signal (runtime.CompileWatch)
             wedge_s = None
             if sup is not None and sup.watchdog_timeout_s is not None:
-                wedge_s = max(4.0 * sup.watchdog_timeout_s, 30.0)
+                wedge_s = max(4.0 * sup.watchdog_timeout_s, WEDGE_MIN_S)
             accepted_at = time.monotonic()
             logger.info("capture loop started for %s (%dx%d@%g, rung=%s)",
                         st.display_id, st.width, st.height, fps, rung)
             consume_migration = getattr(encoder, "consume_migration", None)
+            compiling_for_s = getattr(encoder, "compiling_for_s",
+                                      lambda: 0.0)
             while True:
                 if sup is not None:
                     sup.beat()
@@ -1603,7 +1612,11 @@ class DataStreamingServer:
                 if any(stripes for _seq, stripes in harvested):
                     accepted = True
                 now = time.monotonic()
-                if accepted:
+                if accepted or 0.0 < compiling_for_s() < COMPILE_GRACE_S:
+                    # this display's encoder is inside a program's first
+                    # call — an XLA compile (minutes for the 1080p H.264
+                    # P step), not a wedge: the clock restarts when it
+                    # ends. Past the grace it reads as a wedge after all.
                     accepted_at = now
                 elif wedge_s is not None and now - accepted_at > wedge_s:
                     # loop ticks, nothing moves: dead mesh worker / wedged

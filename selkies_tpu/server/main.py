@@ -3,12 +3,20 @@
 from __future__ import annotations
 
 import asyncio
+import functools
 import logging
 import os
+import threading
+import time
+from typing import Optional
 
+from ..runtime import (COMPILE_GRACE_S, INTERPRET_ENV,
+                       enable_compile_cache)
 from ..settings import Settings
 from .app import StreamingApp
 from .data_server import DataStreamingServer
+
+logger = logging.getLogger("selkies_tpu")
 
 
 def run(settings: Settings) -> int:
@@ -19,52 +27,89 @@ def run(settings: Settings) -> int:
     return asyncio.run(_amain(settings)) or 0
 
 
-def _enable_compile_cache() -> None:
-    """Persistent XLA compilation cache: the first 1080p step costs tens of
-    seconds to compile; across restarts it should cost a disk read."""
-    try:
-        import jax
-
-        cache_dir = os.environ.get(
-            "SELKIES_JAX_CACHE",
-            os.path.join(os.path.expanduser("~"), ".cache", "selkies-tpu-xla"))
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        logging.getLogger("selkies_tpu").debug("compile cache unavailable")
+async def _amain(settings: Settings) -> int:
+    return await serve(build(settings))
 
 
-def _warm_default_geometry(settings: Settings) -> None:
-    """Background-compile the default encoder geometry so the first client
-    doesn't pay the jit stall on the event loop."""
-    import threading
+class WarmUp:
+    """Background compile of the default encoder geometry (keyframe AND
+    inter-frame programs) so the first client doesn't pay the jit stall.
 
-    def work():
+    The outcome is kept, not swallowed: ``done`` is set when the thread
+    ends, ``error`` holds what the first device compile raised (logged at
+    ERROR — a device that fails at boot is not a debug-level event) and
+    ``seconds`` how long the cold compile took."""
+
+    def __init__(self, settings: Settings) -> None:
+        self.settings = settings
+        self.done = threading.Event()
+        self.error: Optional[BaseException] = None
+        self.seconds = 0.0
+        if str(settings.tpu_mesh):
+            # displays ride mesh lanes, whose programs depend on the
+            # joining geometry; compiling the solo encoder here would
+            # spend minutes (and device memory) on a program that at
+            # most an overflow display ever runs
+            logger.info("tpu_mesh set: solo encoder warm-up skipped")
+            self.done.set()
+            return
+        threading.Thread(target=self._work, name="tpuenc-warmup",
+                         daemon=True).start()
+
+    def _work(self) -> None:
+        t0 = time.monotonic()
         try:
-            from ..server.data_server import default_encoder_factory
+            from ..capture.synthetic import SyntheticSource
+            from ..encoder.async_driver import AsyncEncodeDriver
+            from .data_server import default_encoder_factory
 
-            enc = default_encoder_factory(1920, 1080, settings)
-            import numpy as np
-
-            enc.submit(np.zeros((1080, 1920, 3), np.uint8))
-            enc.flush()
+            enc = default_encoder_factory(1920, 1080, self.settings)
+            flush = enc.flush     # the threaded adapter's blocks to the end
+            if isinstance(enc, AsyncEncodeDriver):
+                # the async driver's gives up after 60 s by default —
+                # shorter than the compile it waits for
+                flush = functools.partial(enc.flush,
+                                          timeout=COMPILE_GRACE_S)
+            # three frames of moving content: the first compiles the
+            # keyframe program, the next two the inter-frame program and
+            # the small fetch programs behind it — a stream's second
+            # frame must not be where a minutes-long compile lands
+            src = SyntheticSource(1920, 1080, pattern="desktop")
+            for _ in range(3):
+                enc.submit(src.next_frame())
+                flush()
             close = getattr(enc, "close", None)
             if close:
                 close()
-            logging.getLogger("selkies_tpu").info("encoder warm-up done")
-        except Exception:
-            logging.getLogger("selkies_tpu").debug("warm-up skipped")
+            logger.info("encoder warm-up done")
+        except BaseException as e:
+            self.error = e
+            logger.exception("encoder warm-up FAILED: the default 1080p "
+                             "encoder does not run on this device")
+        finally:
+            self.seconds = time.monotonic() - t0
+            self.done.set()
 
-    threading.Thread(target=work, name="tpuenc-warmup", daemon=True).start()
 
-
-async def _amain(settings: Settings) -> int:
-    _enable_compile_cache()
+def build(settings: Settings) -> DataStreamingServer:
+    """Everything boot decides before a socket opens: the runtime policy
+    (interpreter flag, compile cache), the app + data server pair, and
+    the background warm-up compile (kept on ``server.warmup``)."""
+    if settings.tpu_interpret.value:
+        os.environ[INTERPRET_ENV] = "true"
+    cache_dir = enable_compile_cache()   # raises: no quiet boot without it
+    logger.info("XLA compile cache: %s", cache_dir)
     app = StreamingApp(settings)
     server = DataStreamingServer(settings, app=app)
     app.data_server = server
-    _warm_default_geometry(settings)
+    server.warmup = WarmUp(settings)
+    return server
+
+
+async def serve(server: DataStreamingServer) -> int:
+    """Bring up every plane around a built server and serve until
+    cancelled."""
+    settings, app = server.settings, server.app
 
     if settings.audio_enabled.value:
         try:

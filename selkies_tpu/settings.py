@@ -425,8 +425,9 @@ SETTING_DEFINITIONS: List[Spec] = [
     IntSpec("tpu_sessions_per_chip", 1, "Frame-batched sessions per chip.", server_only=True),
     StrSpec("tpu_mesh", "", "Device mesh spec, e.g. 'session:8' (empty = single chip).",
             server_only=True),
-    BoolSpec("tpu_interpret", False, "Run Pallas kernels in interpreter mode.",
-             server_only=True),
+    BoolSpec("tpu_interpret", False, "Run Pallas kernels in interpreter mode "
+             "(the test suite asks for it; the program never falls into it "
+             "on its own — selkies_tpu/runtime.py).", server_only=True),
 ]
 
 _SPECS_BY_NAME: Dict[str, Spec] = {s.name: s for s in SETTING_DEFINITIONS}

@@ -389,7 +389,7 @@ def test_png_encoder_valid():
 
 
 # ---------------------------------------------------------------------------
-# international / IME coverage (VERDICT round-1 weakness 9)
+# international / IME coverage
 
 
 def test_cyrillic_keysym_reaches_backend():

@@ -1,5 +1,5 @@
 """Regression tests for minijs semantics the reference-client oracle
-depends on (VERDICT r3 item 7).
+depends on.
 
 Round 3 proved interpreter gaps are a product hazard: the oracle test
 was red because the reference client's settings handler called

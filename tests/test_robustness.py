@@ -23,6 +23,7 @@ from selkies_tpu.robustness import (FAILED, DegradationLadder, EncoderFault,
                                     FaultInjected, FaultInjector,
                                     InProcessClient, Supervisor)
 from selkies_tpu.server.app import StreamingApp
+from selkies_tpu.server import data_server
 from selkies_tpu.server.data_server import DataStreamingServer, DisplayState
 from selkies_tpu.settings import Settings
 
@@ -589,6 +590,72 @@ async def test_stalled_fetch_trips_watchdog():
         # watchdog restarts ride the health feed too
         assert any('"watchdog_restarts": 1' in t or
                    '"watchdog_restarts": 2' in t for t in ws.texts())
+    finally:
+        await close_client(ws, task)
+        await server.stop()
+
+
+# ---------------------------------------------------------------------------
+# a first-use compile is not a wedge — for the grace, and for its display
+
+
+class QuietEncoder(FakeEncoder):
+    """A driver whose thread sits inside one long dispatch: accepts
+    nothing, harvests nothing. ``compiling`` makes it say, through the
+    encoder's own signal (runtime.CompileWatch via the wrappers), that
+    the dispatch is a program's first use."""
+
+    def __init__(self, overrides=None, compiling=False):
+        super().__init__(overrides)
+        self.compiling = compiling
+        self.born = time.monotonic()
+
+    def try_submit(self, frame):
+        return None
+
+    def compiling_for_s(self):
+        return time.monotonic() - self.born if self.compiling else 0.0
+
+
+@pytest.mark.anyio
+@pytest.mark.parametrize("compiling, grace_s, steps", [
+    (True, 60.0, False),    # compiling past the wedge deadline: spared
+    (True, 1.0, True),      # ... past the grace too: a wedge after all
+    (False, 60.0, True),    # quiet and NOT compiling: a wedge, as before
+])
+async def test_wedge_detector_spares_only_its_own_compiling_encoder(
+        monkeypatch, compiling, grace_s, steps):
+    monkeypatch.setattr(data_server, "WEDGE_MIN_S", 0.0)
+    monkeypatch.setattr(data_server, "COMPILE_GRACE_S", grace_s)
+    server, encoders = make_server(
+        SELKIES_SUPERVISOR_MAX_RESTARTS="20",
+        SELKIES_WATCHDOG_FRAMES="30",     # 0.5 s watchdog -> 2 s wedge
+    )
+
+    def encoder_factory(w, h, s, overrides=None):
+        # only the device rung is quiet; what the ladder steps down to
+        # (and any other display's encoder) streams
+        if (overrides or {}).get("tpu_entropy", "device") == "device":
+            enc = QuietEncoder(overrides, compiling=compiling)
+        else:
+            enc = FakeEncoder(overrides)
+        encoders.append(enc)
+        return enc
+
+    server.encoder_factory = encoder_factory
+    ws, task = await open_client(server, {
+        "displayId": "primary", "initialClientWidth": 320,
+        "initialClientHeight": 240, "framerate": 60})
+    try:
+        assert await wait_until(lambda: "primary" in server.display_clients)
+        st = server.display_clients["primary"]
+        stepped = await wait_until(
+            lambda: "device->host" in st.ladder.transitions, timeout=4.5)
+        assert stepped is steps
+        if not steps:
+            assert st.ladder.rung == "device" and len(encoders) == 1
+            assert st.supervisor.failures_total == 0
+            assert encoders[0].compiling_for_s() > 2.0  # past the deadline
     finally:
         await close_client(ws, task)
         await server.stop()

@@ -695,7 +695,7 @@ async def test_mesh_stripe_axis_single_session_config4(tmp_path):
 @pytest.mark.anyio
 async def test_mesh_geometry_buckets(tmp_path):
     """A join at a different resolution gets its own mesh bucket instead
-    of silently falling back to a solo encoder (VERDICT r2 item 6); the
+    of silently falling back to a solo encoder; the
     fallback/bucket counters ride the stats feed."""
     server, app, encoders = make_server(
         tmp_path,
@@ -735,7 +735,7 @@ async def test_mesh_geometry_buckets(tmp_path):
 
 @pytest.mark.anyio
 async def test_mesh_h264_display_serves_wire_stripes(tmp_path):
-    """VERDICT r3 item 3: an H.264 display rides the tpu_mesh coordinator
+    """An H.264 display rides the tpu_mesh coordinator
     — the wire carries 0x04 striped Annex-B that the conformance oracle
     decodes, with no solo-encoder fallback."""
     from selkies_tpu.encoder import conformance
@@ -754,12 +754,17 @@ async def test_mesh_h264_display_serves_wire_stripes(tmp_path):
                 "displayId": "primary",
                 "initialClientWidth": 320, "initialClientHeight": 256}))
             got = []
+            # the first stripe sits behind a cold CPU compile of the mesh
+            # program: ~60 s alone, several times that when six workers
+            # compile at once; later stripes keep the short wait
+            wait = 420
             while len(got) < 4:
-                m = await asyncio.wait_for(ws.recv(), 60)
+                m = await asyncio.wait_for(ws.recv(), wait)
                 if isinstance(m, bytes):
                     f = unpack_binary(m)
                     if isinstance(f, VideoStripe):
                         got.append((m[0], f))
+                        wait = 60
             assert server.mesh_coordinator is not None
             assert server.mesh_coordinator.profile == "x264enc-striped"
             assert len(server.mesh_coordinator._attached) == 1

@@ -1,6 +1,6 @@
 """Execute the real browser WebRTC peer (web/webrtc.js) in CI.
 
-VERDICT r2 missing item 1: the from-scratch WebRTC stack had no
+the from-scratch WebRTC stack had no
 browser-side consumer. These tests run the actual shipped webrtc.js
 under tools/minijs with RTCPeerConnection/fetch stubs and drive the
 full signaling → SDP answer → ICE → data-channel input flow — the same

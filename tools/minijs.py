@@ -2,7 +2,7 @@
 client (web/*.js) in CI.
 
 Why this exists: the image ships no JS runtime (no node/deno/quickjs, no
-embeddable engine package), and VERDICT round 1 flagged that the client
+embeddable engine package), and an early review flagged that the client
 tests only regexed the source. This module parses and tree-walks the
 actual client files against Python-implemented DOM/WebCodecs stubs
 (tests/web_stubs.py), so the demux, ACK, input-mapping and dashboard

@@ -1,6 +1,6 @@
 """Rate/distortion quality gate: tpuenc-H.264 vs x264 superfast.
 
-VERDICT r3 item 4 (round-2 item 7): "matches the reference" includes what
+"matches the reference" includes what
 pixels look like at a bitrate. The reference's daily driver is pixelflux's
 x264 at preset superfast, tune zerolatency, with in-loop deblocking
 (reference gstwebrtc_app.py:609-640); tpuenc ships integer-pel ME,
