@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 from collections import deque
 from typing import Any, Callable, List, Optional, Tuple
 
@@ -77,6 +78,10 @@ class AsyncEncodeDriver:
         self.wire_fullframe = bool(wire_fullframe)
         self._metrics = metrics
         pipe.metrics = metrics
+        #: the server's FlightRecorder, attached like ``metrics``: the
+        #: driver thread writes its states to the recorder's thread track
+        #: (and the pipe its own, through ``pipe.track``)
+        self._recorder = None
         #: fault injector (server wires its own in); checked with the
         #: sync variant at the harvest site, where a stalled D2H would
         #: really block
@@ -86,8 +91,11 @@ class AsyncEncodeDriver:
         self.on_error: Optional[Callable[[BaseException], None]] = None
 
         self._cond = threading.Condition()
-        self._in_q: deque = deque()          # (driver_seq, frame)
+        self._in_q: deque = deque()          # (driver_seq, frame, t_accepted)
         self._out: deque = deque()           # (driver_seq, stripes)
+        #: driver seq -> (accepted into _in_q, taken out): the two ends of
+        #: ``submit_wait``, and where ``pipe_wait`` begins
+        self._waits: dict = {}
         #: driver_seq -> flight-recorder stage intervals harvested with
         #: the frame (pulled from the pipe at emit time, under _cond, so
         #: the event-loop pop never touches pipe state the driver thread
@@ -125,10 +133,30 @@ class AsyncEncodeDriver:
         self._metrics = m
         self.pipe.metrics = m
 
+    @property
+    def recorder(self):
+        return self._recorder
+
+    @recorder.setter
+    def recorder(self, rec) -> None:
+        # the server hands over the recorder it holds when the capture
+        # loop starts (never the one build() made: the benchmark swaps it)
+        self._recorder = rec
+        self.pipe.track = None if rec is None else self._track
+
+    def _track(self, state: str, t0: float, t1: float) -> None:
+        """One state of this driver thread, to the recorder's thread
+        track. The pipe calls it with the clock readings it already
+        takes for the frame's stages."""
+        rec = self._recorder
+        if rec is not None:
+            rec.thread_state(self._thread.name, state, t0, t1)
+
     def try_submit(self, frame) -> Optional[int]:
         """Queue one frame for the driver thread; None = dropped (queue
         full — the pipeline is not keeping up, backpressure at the edge
         instead of a stalled event loop)."""
+        t_accepted = time.monotonic()
         with self._cond:
             if self._stop:
                 return None
@@ -139,7 +167,7 @@ class AsyncEncodeDriver:
                 return None
             seq = self._seq
             self._seq += 1
-            self._in_q.append((seq, frame))
+            self._in_q.append((seq, frame, t_accepted))
             self._cond.notify_all()
             return seq
 
@@ -249,12 +277,23 @@ class AsyncEncodeDriver:
         if not results:
             return
         pop_tr = getattr(self.pipe, "pop_trace", None)
+        t_emit0 = time.monotonic()
         with self._cond:
             for pipe_seq, stripes in results:
                 seq = self._seq_map.pop(pipe_seq, pipe_seq)
+                waits = self._waits.pop(seq, None)
                 if pop_tr is not None:
                     tr = pop_tr(pipe_seq)
                     if tr:
+                        if waits is not None:
+                            # the frame's two waits on this side of the
+                            # pipe: in _in_q, then behind the pass's work
+                            # and a full pipe until its staging began
+                            first = tr.get("stage") or tr.get("dispatch")
+                            tr["submit_wait"] = waits
+                            if first is not None:
+                                tr["pipe_wait"] = (
+                                    waits[1], max(waits[1], first[0]))
                         self._trace_out[seq] = tr
                         while len(self._trace_out) > 4 * self.submit_depth:
                             self._trace_out.pop(
@@ -266,8 +305,9 @@ class AsyncEncodeDriver:
             # bounded
             horizon = results[-1][0]
             for k in [k for k in self._seq_map if k < horizon]:
-                self._seq_map.pop(k)
+                self._waits.pop(self._seq_map.pop(k), None)
             self._cond.notify_all()
+        self._track("emit", t_emit0, time.monotonic())
 
     def _harvest(self, flush_partial: bool) -> bool:
         """One non-blocking harvest pass; True if anything completed."""
@@ -295,6 +335,10 @@ class AsyncEncodeDriver:
             work = list(self._in_q)
             self._in_q.clear()
             flush_want = self._flush_req
+            if work:
+                t_taken = time.monotonic()
+                for seq, _frame, t_accepted in work:
+                    self._waits[seq] = (t_accepted, t_taken)
         # 1. dispatch every queued frame. pipe.submit may block
         # harvesting the OLDEST batch when the pipe is full — exactly
         # the overlap we want: batches 2..N keep computing while the
@@ -302,15 +346,18 @@ class AsyncEncodeDriver:
         # ITSELF (counted + reported), never the rest of the pass; a
         # frame the pipe never accepted gets no seq mapping, so its
         # loss cannot shift later results onto wrong seqs.
-        for seq, frame in work:
+        for seq, frame, _t in work:
             try:
                 pipe_seq = self.pipe.submit(frame)
             except Exception as exc:
+                self._waits.pop(seq, None)
                 self._count_error(exc)
             else:
                 if pipe_seq is not None:
                     with self._cond:
                         self._seq_map[pipe_seq] = seq
+                else:
+                    self._waits.pop(seq, None)
         try:
             # 2. harvest whatever is ready (never blocks)
             with self._cond:
@@ -355,7 +402,10 @@ class AsyncEncodeDriver:
             # sleep until new work arrives.
             waiting = (self.pipe.n_inflight > 0
                        or bool(getattr(self.pipe, "_batch_frames", None)))
+            t_sleep0 = time.monotonic()
             self._cond.wait(self.POLL_INTERVAL_S if waiting else 0.25)
+            t_sleep1 = time.monotonic()
+        self._track("sleep", t_sleep0, t_sleep1)
         return True
 
     def _cleanup(self) -> None:

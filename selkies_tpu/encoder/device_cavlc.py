@@ -54,6 +54,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.phases import phase
+
 MB = 16
 
 #: 32-bit words per packed unit (512 bits).  The worst *legal* residual
@@ -699,6 +701,7 @@ def pack_p_frame_words(mv, luma, chroma_dc, chroma_ac, update, *,
     return words, t_bits, base_words, overflow
 
 
+@phase("entropy")
 def pack_p_frame(mv, luma, chroma_dc, chroma_ac, damage, update, *,
                  mb_w: int, mb_h: int, max_stripe_bytes: int):
     """Fetchable uint8 buffer: [S, HEAD_BYTES] head + big-endian payload.

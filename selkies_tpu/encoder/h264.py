@@ -25,6 +25,7 @@ import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -791,6 +792,31 @@ class H264StripeEncoder:
         cb = np.asarray(self._ref_cb[i * sh // 2:(i + 1) * sh // 2])
         cr = np.asarray(self._ref_cr[i * sh // 2:(i + 1) * sh // 2])
         return y, cb, cr
+
+    def lower_step(self):
+        """The served P step, lowered for this encoder's geometry and
+        options: what observability/device_phases.py compiles (from the
+        cache, where the stream has run) to name a trace's operations by
+        phase. Nothing runs and no state of the encoder is touched."""
+        def like(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+        rgb = jax.ShapeDtypeStruct((self.height, self.width, 3), jnp.uint8)
+        planes = [like(p) for p in (self._prev_y, self._prev_cb,
+                                    self._prev_cr, self._ref_y,
+                                    self._ref_cb, self._ref_cr)]
+        paint = jax.ShapeDtypeStruct((self.n_stripes,), jnp.int32)
+        common = dict(pad_h=self.pad_h, pad_w=self.pad_w,
+                      n_stripes=self.n_stripes, sh=self.stripe_h,
+                      search=self.search, me=dev._me_backend())
+        if self.entropy == "device":
+            return dev.encode_frame_p_cavlc_rgb.lower(
+                rgb, *planes, paint, i32, i32,
+                max_stripe_bytes=self._cavlc_msb, **common)
+        return dev.encode_frame_p_rgb.lower(
+            rgb, *planes, paint, i32, i32, prefix=self._choose_prefix(),
+            cap_frac=self._cap_frac, **common)
 
 
 @dataclass

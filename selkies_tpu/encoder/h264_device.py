@@ -39,6 +39,7 @@ from ..ops.motion import (full_search_mc, full_search_mc_scan,
                           full_search_mv, mc_chroma, mc_luma,
                           pad_replicate)
 from ..ops.pallas_me import me_mc_stripes
+from ..ops.phases import phase
 
 MB = 16
 SEARCH = 12
@@ -369,40 +370,46 @@ def _frame_p_core(y, cb, cr, prev_y, prev_cb, prev_cr,
     cbs = _stripe_view(cb, S, sh // 2)
     crs = _stripe_view(cr, S, sh // 2)
 
-    damage = jax.vmap(
-        lambda a, b, c, d, e, f:
-        jnp.any(a != b) | jnp.any(c != d) | jnp.any(e != f)
-    )(ys, pys, cbs, pcbs, crs, pcrs)
+    # the scopes name the step's phases in the compiled program's
+    # metadata (observability/device_phases.py reads them back): metadata
+    # only, the arithmetic and the bitstream are what they were
+    with jax.named_scope("damage"):
+        damage = jax.vmap(
+            lambda a, b, c, d, e, f:
+            jnp.any(a != b) | jnp.any(c != d) | jnp.any(e != f)
+        )(ys, pys, cbs, pcbs, crs, pcrs)
 
-    update = damage | (paint != 0)
-    qps = jnp.where(paint != 0, paint_qp, qp)            # [S]
+        update = damage | (paint != 0)
+        qps = jnp.where(paint != 0, paint_qp, qp)            # [S]
 
     # ME for every stripe in ONE VMEM-resident kernel (ops/pallas_me.py),
     # then the per-stripe transform/quant/recon rides a vmap. The XLA
     # chunked search remains selectable (SELKIES_TPU_ME=xla); which
     # backend wins end to end on a directly attached chip is not
     # measured.
-    if me == "pallas":
-        mv, pred_y, pred_cb, pred_cr = me_mc_stripes(
-            ys, rys, rcbs, rcrs, search=search)
-    else:
-        fn = full_search_mc_scan if me == "scan" else full_search_mc
+    with jax.named_scope("motion"):
+        if me == "pallas":
+            mv, pred_y, pred_cb, pred_cr = me_mc_stripes(
+                ys, rys, rcbs, rcrs, search=search)
+        else:
+            fn = full_search_mc_scan if me == "scan" else full_search_mc
+            mv, pred_y, pred_cb, pred_cr = jax.vmap(
+                functools.partial(fn, mb=MB, search=search)
+            )(ys, rys, rcbs, rcrs)
+        # SAD-tied MBs re-point at each stripe's dominant motion so skip
+        # runs form (same quality, far fewer syntax bits — see
+        # _collapse_mv_ties); shared across every ME backend
         mv, pred_y, pred_cb, pred_cr = jax.vmap(
-            functools.partial(fn, mb=MB, search=search)
-        )(ys, rys, rcbs, rcrs)
-    # SAD-tied MBs re-point at each stripe's dominant motion so skip
-    # runs form (same quality, far fewer syntax bits — see
-    # _collapse_mv_ties); shared across every ME backend
-    mv, pred_y, pred_cb, pred_cr = jax.vmap(
-        functools.partial(_collapse_mv_ties, search=search)
-    )(ys, rys, rcbs, rcrs, mv, pred_y, pred_cb, pred_cr)
-    enc = jax.vmap(encode_stripe_p_pred)(
-        ys, cbs, crs, mv, pred_y, pred_cb, pred_cr, qps)
+            functools.partial(_collapse_mv_ties, search=search)
+        )(ys, rys, rcbs, rcrs, mv, pred_y, pred_cb, pred_cr)
+    with jax.named_scope("transform"):
+        enc = jax.vmap(encode_stripe_p_pred)(
+            ys, cbs, crs, mv, pred_y, pred_cb, pred_cr, qps)
 
-    sel = update[:, None, None]
-    new_ref_y = jnp.where(sel, enc.recon_y, rys).reshape(y.shape)
-    new_ref_cb = jnp.where(sel, enc.recon_cb, rcbs).reshape(cb.shape)
-    new_ref_cr = jnp.where(sel, enc.recon_cr, rcrs).reshape(cr.shape)
+        sel = update[:, None, None]
+        new_ref_y = jnp.where(sel, enc.recon_y, rys).reshape(y.shape)
+        new_ref_cb = jnp.where(sel, enc.recon_cb, rcbs).reshape(cb.shape)
+        new_ref_cr = jnp.where(sel, enc.recon_cr, rcrs).reshape(cr.shape)
 
     return enc, damage, update, new_ref_y, new_ref_cb, new_ref_cr
 
@@ -439,6 +446,7 @@ def sparse_geometry(stripe_words: int,
     return pad_words, n_cells, cap
 
 
+@phase("entropy")
 def _pack_sparse(flat16, damage, update, cap_frac: int = 4):
     """Block-sparse device pack of the level buffer (P frames).
 
@@ -722,15 +730,17 @@ def encode_frame_idr(y, cb, cr, prev_y, prev_cb, prev_cr,
     crs = _stripe_view(cr, S, sh // 2)
     qps = jnp.broadcast_to(qp, (S,))
 
-    enc = jax.vmap(encode_stripe_idr)(ys, cbs, crs, qps)
-    new_ref_y = enc.recon_y.reshape(y.shape)
-    new_ref_cb = enc.recon_cb.reshape(cb.shape)
-    new_ref_cr = enc.recon_cr.reshape(cr.shape)
+    with jax.named_scope("transform"):
+        enc = jax.vmap(encode_stripe_idr)(ys, cbs, crs, qps)
+        new_ref_y = enc.recon_y.reshape(y.shape)
+        new_ref_cb = enc.recon_cb.reshape(cb.shape)
+        new_ref_cr = enc.recon_cr.reshape(cr.shape)
     damage = jnp.ones((S,), bool)
     flat16, flat8 = _pack_levels(enc, damage, damage)
     return flat8, flat16, y, cb, cr, new_ref_y, new_ref_cb, new_ref_cr
 
 
+@phase("entropy")
 def _pack_levels(enc: StripeEncodeOut, damage, update):
     """Device-side packing of one frame's level arrays for a single fetch.
 
@@ -882,6 +892,7 @@ class StagingTicket:
             self._ticket = None
 
 
+@phase("colour")
 def prepare_planes(rgb: jnp.ndarray, pad_h: int, pad_w: int):
     """RGB (H, W, 3) → padded uint8 (Y, Cb, Cr) planes.
 

@@ -26,6 +26,7 @@ import numpy as np
 
 from ..ops.color import rgb_to_ycbcr, subsample_420
 from ..ops.dct import block_dct2, blockify
+from ..ops.phases import phase
 from ..ops.quant import ZIGZAG, quality_scaled_tables
 from . import entropy_py
 from .h264_device import StagingRing
@@ -67,20 +68,27 @@ def _encode_body(frame, prev, qy, qc, qsel, *, stripe_h: int,
     h, w, _ = frame.shape
     s = h // stripe_h
 
-    if wm_scaled is not None:
-        blended = (frame.astype(jnp.uint32) * alpha_inv.astype(jnp.uint32)
-                   + wm_scaled.astype(jnp.uint32) + 127) // 255
-        frame = blended.astype(jnp.uint8)
+    # the scopes name the step's phases in the compiled program's
+    # metadata (ops/phases.py): they change no arithmetic
+    with jax.named_scope("colour"):
+        if wm_scaled is not None:
+            blended = (frame.astype(jnp.uint32) * alpha_inv.astype(jnp.uint32)
+                       + wm_scaled.astype(jnp.uint32) + 127) // 255
+            frame = blended.astype(jnp.uint8)
 
-    diff = jnp.abs(frame.astype(jnp.int16) - prev.astype(jnp.int16))
-    damage = diff.reshape(s, stripe_h * w * 3).max(axis=1).astype(jnp.int32)
+    with jax.named_scope("damage"):
+        diff = jnp.abs(frame.astype(jnp.int16) - prev.astype(jnp.int16))
+        damage = diff.reshape(s, stripe_h * w * 3).max(axis=1) \
+            .astype(jnp.int32)
 
-    y, cb, cr = rgb_to_ycbcr(frame)
-    cb = subsample_420(cb)
-    cr = subsample_420(cr)
+    with jax.named_scope("colour"):
+        y, cb, cr = rgb_to_ycbcr(frame)
+        cb = subsample_420(cb)
+        cr = subsample_420(cr)
 
     zz = jnp.asarray(ZIGZAG)
 
+    @phase("transform")
     def component(plane, tables, rows_per_stripe):
         blocks = blockify(plane) - 128.0            # [by, bx, 8, 8]
         coeffs = block_dct2(blocks)
@@ -132,18 +140,19 @@ def _device_pipeline(pad_h: int, pad_w: int, stripe_h: int,
             frame, prev, qy, qc, qsel, stripe_h=stripe_h,
             wm_scaled=wm_scaled if watermark else None,
             alpha_inv=alpha_inv if watermark else None)
-        words, nbytes, base, ovf = packer_fn(yq, cbq, crq)
-        # One fetchable buffer per frame: 4*S words of metadata followed by
-        # the packed bitstream, so the host harvests a frame with a single
-        # D2H read whatever a read's fixed cost is (see
-        # pipeline.PipelinedJpegEncoder).
-        head = jnp.concatenate([
-            nbytes.astype(jnp.uint32),
-            base.astype(jnp.uint32),
-            ovf.astype(jnp.uint32),
-            damage.astype(jnp.uint32),
-        ])
-        packed = jnp.concatenate([head, words])
+        with jax.named_scope("entropy"):
+            words, nbytes, base, ovf = packer_fn(yq, cbq, crq)
+            # One fetchable buffer per frame: 4*S words of metadata
+            # followed by the packed bitstream, so the host harvests a
+            # frame with a single D2H read whatever a read's fixed cost is
+            # (see pipeline.PipelinedJpegEncoder).
+            head = jnp.concatenate([
+                nbytes.astype(jnp.uint32),
+                base.astype(jnp.uint32),
+                ovf.astype(jnp.uint32),
+                damage.astype(jnp.uint32),
+            ])
+            packed = jnp.concatenate([head, words])
         return packed, new_prev, yq, cbq, crq
 
     return packer, step
@@ -472,3 +481,18 @@ class JpegStripeEncoder:
         self._first_frame = True
         self._static_frames[:] = 0
         self._painted[:] = False
+
+    def lower_step(self):
+        """The served device-entropy step, lowered for this encoder's
+        geometry: what observability/device_phases.py compiles (from the
+        cache, where the stream has run) to name a trace's operations by
+        phase. Nothing runs and no state of the encoder is touched."""
+        def like(a):
+            return None if a is None else jax.ShapeDtypeStruct(a.shape,
+                                                               a.dtype)
+
+        qsel = jax.ShapeDtypeStruct((self.n_stripes,), jnp.int32)
+        return self._step.lower(
+            like(self._prev), like(self._prev), like(self._qy),
+            like(self._qc), qsel, like(self._wm_scaled),
+            like(self._alpha_inv))

@@ -47,11 +47,17 @@ class _PipelineTelemetry:
     a ``metrics`` attribute.
 
     Flight-recorder hookup (ISSUE 13): per-frame stage intervals
-    (stage/dispatch/fetch_wait/pack, absolute ``time.monotonic``
+    (stage/dispatch/in_device/fetch_wait/pack, absolute ``time.monotonic``
     ``(start, end)`` pairs) accumulate on the in-flight item and are
     published under the frame's seq at harvest; the capture loop pops
     them with :meth:`pop_trace` and folds them into that frame's
-    :class:`~selkies_tpu.observability.tracing.FrameTrace`."""
+    :class:`~selkies_tpu.observability.tracing.FrameTrace`. The same
+    clock readings go to ``track(state, t0, t1)`` when the owner (the
+    async driver) has set it: the owning thread's timeline."""
+
+    #: ``callable(state, t0, t1)`` or None: where the thread that drives
+    #: this pipe reports the states it is in (AsyncEncodeDriver sets it)
+    track = None
 
     def _init_telemetry(self) -> None:
         self._dispatch_ms: deque = deque(maxlen=256)
@@ -66,9 +72,23 @@ class _PipelineTelemetry:
         """The base encoder's first-use compile signal (runtime.CompileWatch)."""
         return self.base.compile_watch.compiling_for_s()
 
+    def _mark(self, trace: Optional[dict], state: str,
+              t0: float, t1: float) -> None:
+        """One work interval: a stage of the frame(s) it was done for,
+        and a state of the thread that did it."""
+        if trace is not None:
+            trace[state] = (t0, t1)
+        if self.track is not None:
+            self.track(state, t0, t1)
+
     def _trace_store(self, seq: int, intervals: dict) -> None:
         if not intervals:
             return
+        d, f = intervals.get("dispatch"), intervals.get("fetch_wait")
+        if d is not None and f is not None:
+            # launched -> the driver saw the result ready (or began to
+            # block for it): queued behind earlier steps and running
+            intervals["in_device"] = (d[1], max(d[1], f[0]))
         self._trace_out[seq] = intervals
         while len(self._trace_out) > 4 * max(8, getattr(self, "depth", 8)):
             self._trace_out.pop(next(iter(self._trace_out)))
@@ -91,6 +111,17 @@ class _PipelineTelemetry:
         self._fetch_wait_ms.append(ms)
         if self.metrics is not None:
             self.metrics.observe_fetch_wait(ms)
+
+    def _materialize(self, group: "_FetchGroup") -> None:
+        """Bring one fetch group's host copy in (blocks until it is
+        there): the ``fetch_wait`` of every member frame."""
+        t0 = time.monotonic()
+        group.host = np.asarray(group.arr)
+        t1 = time.monotonic()
+        group.fetch_iv = (t0, t1)
+        self._mark(None, "fetch_wait", t0, t1)
+        self._record_fetch_wait((t1 - t0) * 1000.0)
+        self.d2h_bytes_total += group.host.nbytes
 
     def _telemetry_stats(self) -> dict:
         return {
@@ -248,8 +279,7 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
 
     def _dispatch(self, frame) -> int:
         b = self.base
-        t0 = time.perf_counter()
-        tm0 = time.monotonic()
+        t0 = time.monotonic()
         ticket = None
         stage_iv = None
         if isinstance(frame, jnp.ndarray):
@@ -263,7 +293,7 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
             # slot and overlaps the in-flight frames' encode/fetch
             frame, slot = self._staging.stage(
                 b._pad(np.asarray(frame, dtype=np.uint8)))
-            stage_iv = (tm0, time.monotonic())
+            stage_iv = (t0, time.monotonic())
             ticket = StagingTicket(self._staging, slot)
             try:
                 return self._dispatch_staged(frame, ticket, t0, stage_iv)
@@ -295,14 +325,19 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
             packed=packed, yq=yq, cbq=cbq, crq=crq, ticket=ticket,
         )
         if stage_iv is not None:
-            item.trace["stage"] = stage_iv
-        item.trace["dispatch"] = (td0, time.monotonic())
+            self._mark(item.trace, "stage", *stage_iv)
+        td1 = time.monotonic()
+        self._mark(item.trace, "dispatch", td0, td1)
         self._seq += 1
         self._inflight.append(item)
         self._unfetched.append(item)
         if len(self._unfetched) >= self.fetch_group:
             self._issue_fetch()
-        self._record_dispatch((time.perf_counter() - t0) * 1000.0)
+        # starting the group's fetch is part of the launch for the thread
+        # (the frame's own ``dispatch`` stage ends at td1, as it always has)
+        t_end = time.monotonic()
+        self._mark(None, "dispatch", td1, t_end)
+        self._record_dispatch((t_end - t0) * 1000.0)
         self._advance_ready()
         return item.seq
 
@@ -351,12 +386,7 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
             if not block and not item.group.arr.is_ready():
                 return False
             if item.group.host is None:
-                t0 = time.perf_counter()
-                tm0 = time.monotonic()
-                item.group.host = np.asarray(item.group.arr)
-                item.group.fetch_iv = (tm0, time.monotonic())
-                self._record_fetch_wait((time.perf_counter() - t0) * 1000.0)
-                self.d2h_bytes_total += item.group.host.nbytes
+                self._materialize(item.group)
             if item.group.fetch_iv is not None:
                 item.trace["fetch_wait"] = item.group.fetch_iv
             stride = item.group.stride
@@ -387,10 +417,11 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
                 return False
             tm0 = time.monotonic()
             item.words_np = np.asarray(item.refetch)
+            tm1 = time.monotonic()
             # a prediction-miss second read extends the frame's fetch wait
             fw = item.trace.get("fetch_wait")
-            item.trace["fetch_wait"] = (fw[0] if fw else tm0,
-                                        time.monotonic())
+            item.trace["fetch_wait"] = (fw[0] if fw else tm0, tm1)
+            self._mark(None, "fetch_wait", tm0, tm1)
             self.d2h_bytes_total += item.words_np.nbytes
         return True
 
@@ -412,7 +443,7 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
             emit, item.yq, item.cbq, item.crq)
         out = b._assemble(emit, is_paint, scans)
         t1 = time.monotonic()
-        item.trace["pack"] = (t0, t1)
+        self._mark(item.trace, "pack", t0, t1)
         self._trace_store(item.seq, item.trace)
         self.host_entropy_ms_total += (t1 - t0) * 1000.0
         self._publish_metrics()
@@ -795,7 +826,6 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         return self._dispatch_solo(frame)
 
     def _dispatch_solo(self, frame) -> int:
-        t0 = time.perf_counter()
         ts0 = time.monotonic()
         frame, slot = self._stage(frame, self._staging)
         td0 = time.monotonic()
@@ -809,8 +839,9 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         item = _H264InFlight(seq=self._seq, pending=p,
                              ticket=StagingTicket(self._staging, slot))
         if slot is not None:
-            item.trace["stage"] = (ts0, td0)
-        item.trace["dispatch"] = (td0, time.monotonic())
+            self._mark(item.trace, "stage", ts0, td0)
+        td1 = time.monotonic()
+        self._mark(item.trace, "dispatch", td0, td1)
         self._seq += 1
         self._inflight.append(item)
         if p.is_idr:
@@ -820,7 +851,11 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             self._unfetched.append(item)
             if len(self._unfetched) >= self.fetch_group:
                 self._issue_fetch()
-        self._record_dispatch((time.perf_counter() - t0) * 1000.0)
+        # as in the JPEG pipeline: the fetch's start counts as launch for
+        # the thread, not for the frame's ``dispatch`` stage
+        t_end = time.monotonic()
+        self._mark(None, "dispatch", td1, t_end)
+        self._record_dispatch((t_end - ts0) * 1000.0)
         return item.seq
 
     def submit_batch(self, rgbs) -> List[int]:
@@ -885,7 +920,6 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         # fetch=False: this pipeline owns every transfer — the encoder
         # starting its own head copies AND _issue_fetch concatenating the
         # same heads would double-transfer the IDR-recovery path
-        t0 = time.perf_counter()
         ts0 = time.monotonic()
         rgbs, slot = self._stage(rgbs, self._staging_batch)
         td0 = time.monotonic()
@@ -895,6 +929,9 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             self._staging_batch.release(slot)
             raise
         td1 = time.monotonic()
+        if slot is not None:
+            self._mark(None, "stage", ts0, td0)
+        self._mark(None, "dispatch", td0, td1)
         # one staged buffer backs every frame of the batch: the ring slot
         # frees when the LAST of them harvests
         ticket = StagingTicket(self._staging_batch, slot,
@@ -924,7 +961,7 @@ class PipelinedH264Encoder(_PipelineTelemetry):
                 it.group_index = it.pending.batch_index
         if self._unfetched:
             self._issue_fetch()
-        self._record_dispatch((time.perf_counter() - t0) * 1000.0)
+        self._record_dispatch((time.monotonic() - ts0) * 1000.0)
 
     def _issue_fetch(self) -> None:
         group_items, self._unfetched = self._unfetched, []
@@ -958,11 +995,11 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             if not block and not p.flat16.is_ready():
                 return False
             if item.host is None:
-                t0 = time.perf_counter()
                 tm0 = time.monotonic()
                 item.host = np.asarray(p.flat16)
-                item.trace["fetch_wait"] = (tm0, time.monotonic())
-                self._record_fetch_wait((time.perf_counter() - t0) * 1000.0)
+                tm1 = time.monotonic()
+                self._mark(item.trace, "fetch_wait", tm0, tm1)
+                self._record_fetch_wait((tm1 - tm0) * 1000.0)
                 self.d2h_bytes_total += item.host.nbytes
             return True
         if item.group is None:
@@ -972,12 +1009,7 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         if not block and not item.group.arr.is_ready():
             return False
         if item.group.host is None:
-            t0 = time.perf_counter()
-            tm0 = time.monotonic()
-            item.group.host = np.asarray(item.group.arr)
-            item.group.fetch_iv = (tm0, time.monotonic())
-            self._record_fetch_wait((time.perf_counter() - t0) * 1000.0)
-            self.d2h_bytes_total += item.group.host.nbytes
+            self._materialize(item.group)
         if item.group.fetch_iv is not None:
             item.trace["fetch_wait"] = item.group.fetch_iv
         if item.group.host.ndim == 2:      # batched dispatch: (B, prefix)
@@ -1005,7 +1037,7 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             # the item is already off the deque: even a failed harvest
             # must free its staging slot, or the ring stalls forever
             self._release_ticket(item)
-        item.trace["pack"] = (t0, time.monotonic())
+        self._mark(item.trace, "pack", t0, time.monotonic())
         self._trace_store(item.seq, item.trace)
         self.frames_completed += 1
         return item.seq, out

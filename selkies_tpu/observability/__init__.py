@@ -13,13 +13,13 @@ Two surfaces (docs/observability.md):
   ``encode_only_ms``, the ``system_health`` stage breakdown, and
   tools/trace_report.py.
 
-``FrameTracer``/``StageSpan`` are the pre-recorder stamp-based API,
-kept as a compatibility shim.
+The recorder also keeps the driver threads' timeline, the device probe's
+clock pairs (:mod:`.device_probe`) and the stall watch's records
+(:mod:`.stall_watch`); :mod:`.device_phases` names a step program's
+operations by phase, on demand.
 """
 
 from .metrics import Metrics
-from .tracing import (STAGES, FlightRecorder, FrameTrace, FrameTracer,
-                      StageSpan)
+from .tracing import STAGES, FlightRecorder, FrameTrace
 
-__all__ = ["Metrics", "FlightRecorder", "FrameTrace", "STAGES",
-           "FrameTracer", "StageSpan"]
+__all__ = ["Metrics", "FlightRecorder", "FrameTrace", "STAGES"]

@@ -1,8 +1,9 @@
 """Prometheus metrics + observability HTTP endpoint.
 
 Parity with ``legacy/metrics.py:39-75``: ``fps`` gauge, ``fps_hist``
-histogram, ``gpu_utilization`` (here: TPU duty estimate), ``latency``
-gauge, and a ``webrtc_statistics`` Info — plus tpuenc-specific series
+histogram, ``latency`` gauge, and a ``webrtc_statistics`` Info (the
+reference's ``gpu_utilization`` had no source here and is gone: the device
+probe's ``device_queue_delay_ms`` says how busy the chip is) — plus tpuenc-specific series
 (encode ms, stripe bytes, backpressure state) and the flight-recorder
 stage series (docs/observability.md). Falls back to a no-op registry
 when prometheus_client is unavailable so the server never grows a hard
@@ -68,12 +69,6 @@ class Metrics:
             registry=self.registry)
         self.latency = Gauge("latency", "Latency observed by client (ms)",
                              registry=self.registry)
-        self.tpu_utilization = Gauge(
-            "tpu_utilization", "TPU encode duty cycle percent",
-            registry=self.registry)
-        self.gpu_utilization = Gauge(
-            "gpu_utilization", "Alias of tpu_utilization for dashboards "
-            "built against the reference", registry=self.registry)
         self.encode_ms = Histogram(
             "tpuenc_encode_ms", "Per-frame encode wall time (ms)",
             buckets=(1, 2, 4, 8, 16, 33, 66, 100, float("inf")),
@@ -224,7 +219,8 @@ class Metrics:
                          500, 1000, float("inf"))
         self.frame_stage_ms = Histogram(
             "frame_stage_ms", "Per-frame wall time in one pipeline stage "
-            "(capture/stage/dispatch/fetch_wait/pack/queue/send/ack)",
+            "(capture/submit_wait/pipe_wait/stage/dispatch/in_device/"
+            "fetch_wait/pack/harvest_wait/queue/send/ack)",
             ("stage", "display"), buckets=_stage_buckets,
             registry=self.registry)
         self.glass_to_glass_ms = Histogram(
@@ -243,6 +239,21 @@ class Metrics:
             "trace_dropped_total", "Frame spans closed with a dropped@/"
             "expired@ terminal mark, by the stage that lost them",
             ("stage",), registry=self.registry)
+        # device probe and stall watch (docs/observability.md)
+        self.device_queue_delay_ms = Gauge(
+            "device_queue_delay_ms", "How long a one-add probe program "
+            "waited behind the work the device already held, enqueue to "
+            "result (about four probes a second and device)",
+            ("device",), registry=self.registry)
+        self.stalls = Counter(
+            "stalls_total", "Times the process could not have the "
+            "interpreter (every thread waited) or its event loop (blocked "
+            "while threads ran) for over 40 ms",
+            ("kind",), registry=self.registry)
+        self.stall_ms = Histogram(
+            "stall_ms", "Length of those stalls",
+            ("kind",), buckets=(40, 60, 80, 100, 150, 250, 500, 1000,
+                                float("inf")), registry=self.registry)
         self.clients = Gauge("connected_clients", "WebSocket clients",
                              registry=self.registry)
         self.backpressured = Gauge(
@@ -301,11 +312,6 @@ class Metrics:
         if HAVE_PROM:
             self.latency.set(ms)
 
-    def set_tpu_utilization(self, pct: float) -> None:
-        if HAVE_PROM:
-            self.tpu_utilization.set(pct)
-            self.gpu_utilization.set(pct)
-
     def observe_encode(self, ms: float, nbytes: int) -> None:
         if HAVE_PROM:
             self.encode_ms.observe(ms)
@@ -343,6 +349,15 @@ class Metrics:
     def observe_encode_only(self, display: str, ms: float) -> None:
         if HAVE_PROM:
             self.encode_only_ms.labels(display=display).observe(ms)
+
+    def set_device_queue_delay(self, device: int, ms: float) -> None:
+        if HAVE_PROM:
+            self.device_queue_delay_ms.labels(str(device)).set(ms)
+
+    def observe_stall(self, kind: str, ms: float) -> None:
+        if HAVE_PROM:
+            self.stalls.labels(kind).inc()
+            self.stall_ms.labels(kind).observe(ms)
 
     def set_trace_open_spans(self, n: int) -> None:
         if HAVE_PROM:

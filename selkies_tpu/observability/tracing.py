@@ -46,33 +46,62 @@ Export surfaces:
   served at ``/debug/trace`` and summarized by tools/trace_report.py;
 * per-display stage summaries riding the ``system_health`` wire feed.
 
-``FrameTracer``/``StageSpan`` below are the pre-recorder API, kept as a
-compatibility shim (stamp-based spans; summaries over a list ring).
+Beside the frames' ring the recorder keeps three more, all on the same
+``time.monotonic`` clock and all written without a lock (one bounded list,
+one write index each):
+
+* the **thread track**: ``(thread, state, t0, t1)`` for every state a
+  worker thread enters (the ``tpuenc-async`` driver writes ``stage``,
+  ``dispatch``, ``fetch_wait``, ``pack``, ``emit`` and ``sleep``); time of
+  a running thread that no state covers is time it wanted to run and could
+  not (a lock, the interpreter) — readers call it ``other``;
+* the **clock pairs**: ``(device, t_enqueued, t_ready)`` of the device
+  probe (observability/device_probe.py), which pair this clock with the
+  device's in any profiler trace;
+* the **stalls**: ``(kind, t0, t1)`` from the stall watch
+  (observability/stall_watch.py), ``kind`` ``interpreter`` or ``loop``.
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from dataclasses import dataclass, field
+from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = [
-    "STAGES", "FlightRecorder", "FrameTrace", "FrameTracer", "StageSpan",
+    "STAGES", "THREAD_STATES", "STALL_KINDS", "FlightRecorder",
+    "FrameTrace", "PendingSpans",
 ]
 
-#: the eight stages of a served frame's flight, in path order.
+#: the stages of a served frame's flight, in path order. Work stages time
+#: a thread working on the frame; wait stages (``*_wait``, ``in_device``,
+#: ``queue``) time the frame sitting somewhere, and are marked where the
+#: wait ends. Together they run from capture to ACK without a hole.
 #:
-#: capture     host wall time in ``source.next_frame()``
-#: stage       H2D staging (donated ring copy / host batch stack)
-#: dispatch    device program launch (not device compute)
-#: fetch_wait  host time blocked materializing the D2H fetch
-#: pack        host-side entropy glue / stripe assembly
-#: queue       dwell in the owner's bounded send queue
-#: send        transport send (websocket write)
-#: ack         send completion -> CLIENT_FRAME_ACK (network RTT + decode)
-STAGES = ("capture", "stage", "dispatch", "fetch_wait", "pack",
+#: capture       host wall time in ``source.next_frame()``
+#: submit_wait   accepted into the driver's submit queue -> taken out
+#: pipe_wait     taken out -> its staging begins (behind the rest of the
+#:               pass's work, and in ``pipe.submit`` while the pipe is full)
+#: stage         H2D staging (donated ring copy / host batch stack)
+#: dispatch      device program launch (not device compute)
+#: in_device     dispatch done -> the driver sees the result ready or
+#:               begins to block for it (queued and running on the device)
+#: fetch_wait    host time blocked materializing the D2H fetch
+#: pack          host-side entropy glue / stripe assembly
+#: harvest_wait  packed -> the capture loop's poll() takes the frame
+#: queue         dwell in the owner's bounded send queue
+#: send          transport send (websocket write)
+#: ack           send completion -> CLIENT_FRAME_ACK (network RTT + decode)
+STAGES = ("capture", "submit_wait", "pipe_wait", "stage", "dispatch",
+          "in_device", "fetch_wait", "pack", "harvest_wait",
           "queue", "send", "ack")
+
+#: states a worker thread writes to the thread track
+THREAD_STATES = ("stage", "dispatch", "fetch_wait", "pack", "emit", "sleep")
+
+#: what a stall record says was not to be had
+STALL_KINDS = ("interpreter", "loop")
 
 
 class FrameTrace:
@@ -164,6 +193,102 @@ def _pct(sorted_vals: List[float], q: float) -> float:
     return sorted_vals[idx]
 
 
+class _Ring:
+    """A bounded list written through one increasing index, no lock (the
+    same discipline as the frames' ring: a racing writer can at worst
+    overwrite one row). Rows are tuples; ``rows()`` gives oldest first."""
+
+    __slots__ = ("capacity", "_rows", "_widx")
+
+    def __init__(self, capacity: int) -> None:
+        self.capacity = max(16, int(capacity))
+        self._rows: List[Optional[tuple]] = [None] * self.capacity
+        self._widx = 0
+
+    def append(self, row: tuple) -> None:
+        self._rows[self._widx % self.capacity] = row
+        self._widx += 1
+
+    def last(self) -> Optional[tuple]:
+        return self._rows[(self._widx - 1) % self.capacity]
+
+    def replace_last(self, row: tuple) -> None:
+        self._rows[(self._widx - 1) % self.capacity] = row
+
+    def rows(self) -> List[tuple]:
+        w, cap = self._widx, self.capacity
+        rows = self._rows[w % cap:] + self._rows[:w % cap] \
+            if w > cap else self._rows[:w]
+        return [r for r in rows if r is not None]
+
+
+class PendingSpans:
+    """One capture loop's spans between submit and harvest (ROADMAP D6:
+    this bookkeeping lived in ``_capture_loop``).
+
+    Keyed by the seq the encoder will harvest the frame under; encoders
+    whose submit returns no seq correlate first in, first out (results
+    arrive in submission order on every adapter). Both tables are capped:
+    a pipeline that accepts submits and never harvests must not grow them
+    until the watchdog fires. Every span that leaves here without being
+    taken is closed ``dropped@<stage>``, so none can leak."""
+
+    CAP = 512
+
+    def __init__(self, recorder: "FlightRecorder") -> None:
+        self._rec = recorder
+        self._by_seq: Dict[int, FrameTrace] = {}
+        self._fifo: deque = deque()
+
+    def __len__(self) -> int:
+        return len(self._by_seq) + len(self._fifo)
+
+    def add(self, seq: Optional[int], tr: FrameTrace) -> None:
+        """The encoder accepted the frame (under ``seq``, if it says)."""
+        if seq is None:
+            self._fifo.append(tr)
+            while len(self._fifo) > self.CAP:
+                self._rec.drop(self._fifo.popleft(), "submit")
+            return
+        old = self._by_seq.pop(seq, None)
+        if old is not None:
+            # seq reuse: the superseded frame's span closes, it does
+            # not silently vanish
+            self._rec.drop(old, "submit")
+        self._by_seq[seq] = tr
+        while len(self._by_seq) > self.CAP:
+            self._rec.drop(self._by_seq.pop(next(iter(self._by_seq))),
+                           "submit")
+
+    def refuse(self, tr: FrameTrace,
+               replaced_seq: Optional[int] = None) -> None:
+        """The encoder did not take the frame as a new one. A queueing
+        encoder dropped it: its span closes ``dropped@submit``. A mailbox
+        encoder (a mesh lane keeps one pending frame per session) kept
+        THIS frame and lost the one it had pending: it says under which
+        seq (``replaced_seq``), the lost frame's span closes, and this
+        one waits for the harvest in its place."""
+        if replaced_seq is None:
+            self._rec.drop(tr, "submit")
+        else:
+            self.add(replaced_seq, tr)
+
+    def take(self, seq: Optional[int]) -> Optional[FrameTrace]:
+        """The span of the frame harvested under ``seq``."""
+        tr = self._by_seq.pop(seq, None) if seq is not None else None
+        if tr is None and self._fifo:
+            tr = self._fifo.popleft()
+        return tr
+
+    def drop_all(self, stage: str) -> None:
+        """The encoder is going away with these frames inside it."""
+        for tr in self._by_seq.values():
+            self._rec.drop(tr, stage)
+        self._by_seq.clear()
+        while self._fifo:
+            self._rec.drop(self._fifo.popleft(), stage)
+
+
 class FlightRecorder:
     """Ring-buffer recorder of frame flights + open-span accounting.
 
@@ -184,10 +309,21 @@ class FlightRecorder:
 
     #: default seconds before an un-terminated span is expired
     EXPIRE_AFTER_S = 30.0
+    #: two marks of one thread state closer than this are one interval
+    #: (the driver's 2 ms beat: sleep, a look at ``is_ready``, sleep)
+    TRACK_MERGE_S = 1e-4
 
     def __init__(self, capacity: int = 4096, clock=time.monotonic) -> None:
         self.capacity = max(16, int(capacity))
         self._clock = clock
+        #: thread track (thread, state, t0, t1): a driver thread writes
+        #: a handful of rows per frame, so the ring is sized by the frames'
+        self._track = _Ring(8 * self.capacity)
+        #: clock pairs (device, t_enqueued, t_ready): four a second
+        self._pairs = _Ring(self.capacity)
+        #: stalls (kind, t0, t1), and the stacks caught during some
+        self._stalls = _Ring(1024)
+        self._stall_stacks: Dict[Tuple[str, float], str] = {}
         self._ring: List[Optional[FrameTrace]] = [None] * self.capacity
         self._widx = 0
         self._next_token = 1
@@ -204,6 +340,71 @@ class FlightRecorder:
         #: epoch anchor so trace-event timestamps are wall-clock-ish
         self._epoch_mono = clock()
         self._epoch_wall = time.time()
+
+    # -- thread track, clock pairs, stalls ----------------------------------
+
+    def thread_state(self, thread: str, state: str,
+                     t0: float, t1: float) -> None:
+        """``thread`` was in ``state`` over [t0, t1]. A mark that begins
+        where the thread's last one of the same state ended extends it."""
+        last = self._track.last()
+        if (last is not None and last[0] == thread and last[1] == state
+                and t0 - last[3] < self.TRACK_MERGE_S):
+            self._track.replace_last((thread, state, last[2], t1))
+        else:
+            self._track.append((thread, state, t0, t1))
+
+    def thread_track(self, thread: Optional[str] = None,
+                     t0: Optional[float] = None,
+                     t1: Optional[float] = None) -> List[tuple]:
+        """Rows ``(thread, state, t0, t1)`` that overlap [t0, t1], by
+        start time."""
+        return sorted(
+            (r for r in self._track.rows()
+             if (thread is None or r[0] == thread)
+             and (t0 is None or r[3] >= t0) and (t1 is None or r[2] <= t1)),
+            key=lambda r: r[2])
+
+    def clock_pair(self, device: int, t_enqueued: float,
+                   t_ready: float) -> None:
+        """One run of the device probe: enqueued at, seen ready at."""
+        self._pairs.append((device, t_enqueued, t_ready))
+
+    def clock_pairs(self, t0: Optional[float] = None,
+                    t1: Optional[float] = None) -> List[tuple]:
+        """Rows ``(device, t_enqueued, t_ready)`` seen ready in [t0, t1]."""
+        return [r for r in self._pairs.rows()
+                if (t0 is None or r[2] >= t0) and (t1 is None or r[2] <= t1)]
+
+    def stall(self, kind: str, t0: float, t1: float,
+              stack: Optional[str] = None) -> None:
+        """The process could not have ``kind`` (``interpreter``: the
+        interpreter's lock or the machine; ``loop``: the event loop)
+        over [t0, t1]; ``stack`` is every thread's, caught meanwhile."""
+        self._stalls.append((kind, t0, t1))
+        if stack:
+            self._stall_stacks[(kind, t0)] = stack
+            while len(self._stall_stacks) > 64:
+                self._stall_stacks.pop(next(iter(self._stall_stacks)))
+        m = self.metrics
+        if m is not None:
+            try:
+                m.observe_stall(kind, (t1 - t0) * 1000.0)
+            except Exception:       # metrics must never break a watcher
+                pass
+
+    def stalls(self, t0: Optional[float] = None,
+               t1: Optional[float] = None) -> List[tuple]:
+        """Rows ``(kind, t0, t1)`` begun in [t0, t1]."""
+        return [r for r in self._stalls.rows()
+                if (t0 is None or r[1] >= t0) and (t1 is None or r[1] <= t1)]
+
+    def stall_stack(self, kind: str, t0: float) -> Optional[str]:
+        return self._stall_stacks.get((kind, t0))
+
+    def pending(self) -> PendingSpans:
+        """A capture loop's table of spans between submit and harvest."""
+        return PendingSpans(self)
 
     # -- span lifecycle ----------------------------------------------------
 
@@ -428,6 +629,7 @@ class FlightRecorder:
                 "name": "process_name", "ph": "M", "pid": pid, "tid": 0,
                 "args": {"name": f"display:{display}"},
             })
+        events.extend(self._export_tracks(len(pids) + 1, last_s))
         return {
             "traceEvents": events,
             "displayTimeUnit": "ms",
@@ -437,6 +639,37 @@ class FlightRecorder:
                 "open_spans": len(self._open),
             },
         }
+
+
+    def _export_tracks(self, pid: int,
+                       last_s: Optional[float]) -> List[Dict[str, Any]]:
+        """One more process: a row per worker thread (its states), one
+        for the device probe (enqueued -> ready) and one for stalls."""
+        horizon = None if last_s is None else self._clock() - last_s
+        rows: List[Tuple[str, str, float, float]] = list(
+            self.thread_track(t0=horizon))
+        rows += [(f"device probe {dev}", "selkies_clock_probe", a, b)
+                 for dev, a, b in self.clock_pairs(t0=horizon)]
+        rows += [("stalls", kind, a, b)
+                 for kind, a, b in self.stalls()
+                 if horizon is None or b >= horizon]
+        if not rows:
+            return []
+        tids: Dict[str, int] = {}
+        events: List[Dict[str, Any]] = [{
+            "name": "process_name", "ph": "M", "pid": pid, "tid": 0,
+            "args": {"name": "threads"}}]
+        for thread, state, a, b in rows:
+            tid = tids.get(thread)
+            if tid is None:
+                tid = tids[thread] = len(tids) + 1
+                events.append({"name": "thread_name", "ph": "M", "pid": pid,
+                               "tid": tid, "args": {"name": thread}})
+            events.append({
+                "name": state, "cat": "thread", "ph": "X", "pid": pid,
+                "tid": tid, "ts": round((a - self._epoch_mono) * 1e6, 1),
+                "dur": round(max(0.0, b - a) * 1e6, 1)})
+        return events
 
 
 # ---------------------------------------------------------------------------
@@ -462,80 +695,3 @@ def capture_jax_trace(out_dir: str, duration_ms: float) -> Dict[str, Any]:
     finally:
         _JAX_TRACE_LOCK.release()
     return {"path": out_dir, "duration_ms": duration_s * 1000.0}
-
-
-# ---------------------------------------------------------------------------
-# Compatibility shim: the pre-recorder stamp-based API
-#
-# FrameTracer predates the flight recorder (it was imported by nothing
-# but its own test). The names stay importable so downstream code and
-# tests evolve instead of breaking; new call sites use FlightRecorder.
-
-
-@dataclass
-class StageSpan:
-    """Stamp-based span (compat): a dict of instant timestamps."""
-
-    frame_id: int
-    stamps: Dict[str, float] = field(default_factory=dict)
-
-    def mark(self, stage: str) -> None:
-        self.stamps[stage] = time.monotonic()
-
-    def duration_ms(self, a: str, b: str) -> Optional[float]:
-        if a in self.stamps and b in self.stamps:
-            return (self.stamps[b] - self.stamps[a]) * 1000.0
-        return None
-
-    @property
-    def total_ms(self) -> Optional[float]:
-        if not self.stamps:
-            return None
-        return (max(self.stamps.values()) - min(self.stamps.values())) * 1e3
-
-
-class FrameTracer:
-    """Compat ring of :class:`StageSpan` + percentile summaries."""
-
-    def __init__(self, capacity: int = 600):
-        self.capacity = capacity
-        self._ring: List[StageSpan] = []
-        self._open: Dict[int, StageSpan] = {}
-
-    def begin(self, frame_id: int) -> StageSpan:
-        span = StageSpan(frame_id)
-        span.mark("capture")
-        self._open[frame_id] = span
-        return span
-
-    def mark(self, frame_id: int, stage: str) -> None:
-        span = self._open.get(frame_id)
-        if span is not None:
-            span.mark(stage)
-
-    def finish(self, frame_id: int) -> Optional[StageSpan]:
-        span = self._open.pop(frame_id, None)
-        if span is None:
-            return None
-        span.mark("send")
-        self._ring.append(span)
-        if len(self._ring) > self.capacity:
-            self._ring = self._ring[-self.capacity:]
-        return span
-
-    def percentile_ms(self, a: str, b: str, pct: float = 50.0
-                      ) -> Optional[float]:
-        vals = sorted(
-            d for s in self._ring
-            if (d := s.duration_ms(a, b)) is not None)
-        if not vals:
-            return None
-        return _pct(vals, pct)
-
-    def summary(self) -> Dict[str, Optional[float]]:
-        return {
-            "p50_total_ms": self.percentile_ms("capture", "send", 50),
-            "p95_total_ms": self.percentile_ms("capture", "send", 95),
-            "p50_encode_ms": self.percentile_ms("dispatch", "harvest", 50),
-            "frames": float(len(self._ring)),
-        }

@@ -273,6 +273,9 @@ def me_mc_stripes(cur, ref, ref_cb, ref_cr, *, search: int = 12,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
+        # the operation's name in a device trace (``me_mc_stripes.<n>``):
+        # the benchmark's me_kernel_ms finds the kernel by it
+        name="me_mc_stripes",
     )(ranks, cur, ref_pad, cbp, crp)
     mv = jnp.asarray(_offsets(search))[rank_w]            # (S, nby, nbx, 2)
     return mv, py, pcb, pcr

@@ -92,6 +92,8 @@ class MeshSessionFacade:
         self._coord = coord
         self.sid = sid
         self.closed = False
+        #: see :meth:`try_submit`
+        self.replaced_seq: Optional[int] = None
 
     @property
     def slot(self) -> Optional[int]:
@@ -103,7 +105,14 @@ class MeshSessionFacade:
         return self._coord._lane_of(self.sid)
 
     def try_submit(self, frame) -> Optional[int]:
-        return self._coord._submit(self.sid, frame)
+        """The seq the frame will harvest under; None when it took the
+        place of a frame still pending (the session's mailbox holds one):
+        the OLDER frame is the one lost, and ``replaced_seq`` then says
+        under which seq this one will harvest, so that the capture loop
+        can hand the lost frame's place in its records to this one."""
+        seq, replaced = self._coord._submit(self.sid, frame)
+        self.replaced_seq = seq if replaced else None
+        return None if replaced else seq
 
     submit = try_submit
 
@@ -149,7 +158,7 @@ class _Session:
     migration only touches the lane/slot binding)."""
 
     __slots__ = ("sid", "lane", "slot", "gen", "seq", "pending", "results",
-                 "traces", "inflight", "want_key", "want_reset",
+                 "traces", "inflight", "staged", "want_key", "want_reset",
                  "migrations_pending", "coded_bytes_total", "closed")
 
     def __init__(self, sid: int, lane: "_Lane", slot: int) -> None:
@@ -167,6 +176,10 @@ class _Session:
         self.traces: Dict[int, dict] = {}
         #: frames of this session inside some lane's in-flight window
         self.inflight = 0
+        #: of those, taken out of ``pending`` and not yet in the lane's
+        #: ``inflight_q`` (the worker is making room or dispatching): they
+        #: harvest before anything submitted now
+        self.staged = 0
         self.want_key = False
         self.want_reset = False
         self.migrations_pending = 0
@@ -585,11 +598,13 @@ class MeshEncodeCoordinator:
 
     # -- facade surface ----------------------------------------------------
 
-    def _submit(self, sid: int, frame) -> Optional[int]:
+    def _submit(self, sid: int, frame) -> Tuple[Optional[int], bool]:
+        """(seq the frame will harvest under, whether it replaced a frame
+        that was still pending)."""
         with self._lock:
             sess = self._sessions.get(sid)
             if sess is None:
-                return None
+                return None, False
             dropped = sess.pending is not None
             sess.pending = frame
             # the seq THIS frame will harvest under: seq advances only at
@@ -600,9 +615,13 @@ class MeshEncodeCoordinator:
             inflight = sum(
                 1 for entry in sess.lane.inflight_q
                 for s, _slot, g in entry[1] if s is sess and g == sess.gen)
-            seq = sess.seq + inflight
+            # ... and so does a frame the worker has taken and not yet
+            # queued there: it may sit for a whole step, blocked making
+            # room, and a submit in that time used to be handed its seq
+            # (on the chip, the trace of nearly every lane frame)
+            seq = sess.seq + inflight + sess.staged
         self._kick.set()
-        return None if dropped else seq
+        return seq, dropped
 
     def _poll(self, sid: int) -> List[Tuple[int, list]]:
         with self._lock:
@@ -825,6 +844,7 @@ class MeshEncodeCoordinator:
             lane.slot_errors[slot] += 1
             lane.health.record_error(slot)
             sess.inflight = max(0, sess.inflight - 1)
+            sess.staged = max(0, sess.staged - 1)
 
     def _tick(self) -> None:
         """One scheduler tick: apply deferred resets, build each lane's
@@ -891,6 +911,7 @@ class MeshEncodeCoordinator:
                     frames[slot] = sess.pending
                     sess.pending = None
                     sess.inflight += 1
+                    sess.staged += 1
                     took.append((sess, slot, sess.gen))
                 if took or lane.inflight_q:
                     plans.append((lane, frames, took))
@@ -912,6 +933,8 @@ class MeshEncodeCoordinator:
                 with self._lock:
                     lane.inflight_q.append(
                         (pending, took, (t_disp0, time.monotonic())))
+                    for sess, _slot, _gen in took:
+                        sess.staged = max(0, sess.staged - 1)
                     depth = sum(len(ln.inflight_q) for ln in self.lanes)
                     self.inflight_batches_max = max(
                         self.inflight_batches_max, depth)
@@ -923,6 +946,7 @@ class MeshEncodeCoordinator:
                 with self._lock:
                     for sess, _slot, _gen in took:
                         sess.inflight = max(0, sess.inflight - 1)
+                        sess.staged = max(0, sess.staged - 1)
                 dispatched = True
             # opportunistic drain: only fetches that already landed are
             # taken here, so this tick's dispatch is never stalled by a

@@ -20,9 +20,16 @@ import json
 import logging
 import os
 import time
-from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Optional, Set, Tuple
+
+try:
+    # at module load, not at the first stats tick: compiling psutil's
+    # source there held the event loop for 60-85 ms, five seconds after
+    # the first client joined (the stall watch's stack, PERF.md PR 25)
+    import psutil
+except ImportError:             # the stats fall back to the load average
+    psutil = None
 
 from ..protocol.wire import (
     FrameId,
@@ -441,6 +448,10 @@ class DataStreamingServer:
         #: the per-stage Prometheus histograms. Always on — marking a
         #: trace is a few dict stores per frame.
         self.recorder = FlightRecorder(capacity=4096)
+        #: device probes started by server.main.serve (one per device):
+        #: the stats tick reads their last memory sample instead of
+        #: calling the device from the event loop
+        self.device_probes: list = []
         #: fire-and-forget helpers (ws.drop closes, failed-display
         #: teardown) — referenced so they are neither GC'd mid-flight nor
         #: left to warn "exception was never retrieved"
@@ -1451,13 +1462,14 @@ class DataStreamingServer:
             encoder.faults = faults
         st.encoder = encoder
         source = None
+        #: the recorder the server holds NOW (a harness may have swapped
+        #: the one build() made): the encoder's driver thread writes its
+        #: timeline there, and this loop's spans between submit and
+        #: harvest live in its table
         recorder = self.recorder
-        #: flight-recorder spans for frames submitted but not yet
-        #: harvested, keyed by the encoder's submit seq; encoders whose
-        #: submit() returns no seq correlate FIFO (results arrive in
-        #: submission order on every adapter)
-        pending_tr: Dict[int, Any] = {}
-        pending_fifo: deque = deque()
+        if getattr(encoder, "recorder", False) is None:
+            encoder.recorder = recorder
+        pending = recorder.pending()
         try:
             if sup is not None:
                 sup.beat()   # encoder construction counts as progress
@@ -1543,28 +1555,14 @@ class DataStreamingServer:
                                 f"encoder submit failed: {e!r}") from e
                         if not accepted:
                             # backpressure at the edge: a dropped frame
-                            # closes terminally, it never leaks a span
-                            recorder.drop(tr, "submit")
-                        elif seq is not None:
-                            # seq reuse (the mesh facade re-numbers only
-                            # at harvest): the superseded frame's span
-                            # must close, not silently vanish
-                            old = pending_tr.get(seq)
-                            if old is not None:
-                                recorder.drop(old, "submit")
-                            pending_tr[seq] = tr
-                            # hard bound: a pipeline accepting submits
-                            # but never harvesting must not grow this
-                            # map until the watchdog fires
-                            while len(pending_tr) > 512:
-                                oldest = next(iter(pending_tr))
-                                recorder.drop(pending_tr.pop(oldest),
-                                              "submit")
+                            # closes terminally, it never leaks a span. A
+                            # mesh lane's mailbox kept THIS frame and lost
+                            # the one it had pending: the facade says
+                            # under which seq, and the spans swap places
+                            pending.refuse(
+                                tr, getattr(encoder, "replaced_seq", None))
                         else:
-                            pending_fifo.append(tr)
-                            while len(pending_fifo) > 512:
-                                recorder.drop(pending_fifo.popleft(),
-                                              "submit")
+                            pending.add(seq, tr)
                         progressed = True
                 await faults.maybe_hang("fetch.hang")
                 try:
@@ -1577,14 +1575,12 @@ class DataStreamingServer:
                     # long stretch (first-use jit compile); beating after
                     # them keeps that from reading as a stall
                     sup.beat()
+                t_harvest = time.monotonic() if harvested else 0.0
                 for _seq, stripes in harvested:
-                    tr = pending_tr.pop(_seq, None)
-                    if tr is None and pending_fifo:
-                        tr = pending_fifo.popleft()
+                    tr = pending.take(_seq)
                     if tr is not None:
                         # fold in the encoder-side stage intervals
-                        # (stage/dispatch/fetch_wait/pack) harvested
-                        # with the frame
+                        # (submit_wait ... pack) harvested with the frame
                         pop_trace = getattr(encoder, "pop_trace", None)
                         if pop_trace is not None:
                             try:
@@ -1592,6 +1588,12 @@ class DataStreamingServer:
                             except Exception:
                                 logger.debug("pop_trace failed",
                                              exc_info=True)
+                        packed = tr.spans.get("pack")
+                        if packed is not None:
+                            # packed on the driver's thread -> taken by
+                            # this loop's poll()
+                            tr.mark("harvest_wait", packed[1],
+                                    max(packed[1], t_harvest))
                     if not stripes:
                         # damage gating emitted nothing: a coalesced
                         # frame, closed (not dropped, not acked)
@@ -1668,11 +1670,7 @@ class DataStreamingServer:
             # frames in flight inside the (about to be closed) encoder
             # are abandoned with it: close their spans terminally so a
             # supervised restart never leaks open spans
-            for tr in pending_tr.values():
-                recorder.drop(tr, "restart")
-            pending_tr.clear()
-            while pending_fifo:
-                recorder.drop(pending_fifo.popleft(), "restart")
+            pending.drop_all("restart")
             st.encoder = None
             close = getattr(encoder, "close", None)
             if close is not None:
@@ -2219,14 +2217,12 @@ class DataStreamingServer:
 
     def _collect_system_stats(self) -> Dict[str, Any]:
         out: Dict[str, Any] = {"type": "system_stats"}
-        try:
-            import psutil
-
+        if psutil is not None:
             out["cpu_percent"] = psutil.cpu_percent()
             mem = psutil.virtual_memory()
             out["mem_total"] = mem.total
             out["mem_used"] = mem.used
-        except ImportError:
+        else:
             la1, _, _ = os.getloadavg()
             out["load_1m"] = la1
         return out
@@ -2238,9 +2234,13 @@ class DataStreamingServer:
             import jax
 
             devs = jax.devices()
-            stats = devs[0].memory_stats() if devs else None
         except Exception:
             return None
+        # no device call on the event loop: the device probe's thread
+        # samples memory_stats() once a second (it can wait behind the
+        # queued steps), and this tick reads the last sample
+        probes = self.device_probes
+        stats = probes[0].memory if probes else None
         out = {"type": "gpu_stats", "device_count": len(devs),
                "platform": devs[0].platform if devs else "none"}
         if stats:

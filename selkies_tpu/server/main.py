@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import asyncio
 import functools
+import gc
 import logging
 import os
 import threading
@@ -81,6 +82,12 @@ class WarmUp:
             close = getattr(enc, "close", None)
             if close:
                 close()
+            # what boot made (modules, traced programs, tables) lives as
+            # long as the process: out of the collector's sight, so that a
+            # full collection in a served stream walks the stream's own
+            # objects only (one held every thread for 54 ms, PERF.md PR 25)
+            gc.collect()
+            gc.freeze()
             logger.info("encoder warm-up done")
         except BaseException as e:
             self.error = e
@@ -104,6 +111,33 @@ def build(settings: Settings) -> DataStreamingServer:
     app.data_server = server
     server.warmup = WarmUp(settings)
     return server
+
+
+def _start_watchers(server: DataStreamingServer) -> list:
+    """The stall watch, and one device probe per device the server
+    encodes on (every local device under ``tpu_mesh``, else the default
+    one). Never fatal: a server without them serves all the same."""
+    out: list = []
+    try:
+        import jax
+
+        from ..observability.device_probe import DeviceProbe
+        from ..observability.stall_watch import StallWatch
+
+        out.append(StallWatch(
+            lambda: server.recorder, loop=asyncio.get_running_loop(),
+            capture_stacks=bool(server.settings.stall_stacks.value)).start())
+        devices = jax.local_devices()
+        if not str(getattr(server.settings, "tpu_mesh", "") or ""):
+            devices = devices[:1]
+        server.device_probes = [
+            DeviceProbe(d, lambda: server.recorder,
+                        lambda: server.metrics).start() for d in devices]
+        out.extend(server.device_probes)
+    except Exception:
+        logging.getLogger("selkies_tpu").exception(
+            "observability threads not started")
+    return out
 
 
 async def serve(server: DataStreamingServer) -> int:
@@ -149,6 +183,15 @@ async def serve(server: DataStreamingServer) -> int:
         cursor_monitor = CursorMonitor(open_cursor_source(), app.send_cursor)
     except Exception as e:  # no X display etc. — stream-only mode
         logging.getLogger("selkies_tpu").warning("input plane disabled: %s", e)
+
+    # observability threads (docs/observability.md). Each looks the
+    # recorder up on the server when it writes: a harness may swap the one
+    # build() made before calling serve()
+    watchers = _start_watchers(server)
+    for probe in server.device_probes:
+        # the probe's one-add program compiles here, before a client can
+        # join (milliseconds; never persisted, so never a cache miss)
+        await asyncio.to_thread(probe.ready.wait, 30.0)
 
     tasks = [asyncio.create_task(server.run_server())]
 
@@ -218,6 +261,9 @@ async def serve(server: DataStreamingServer) -> int:
     except (KeyboardInterrupt, asyncio.CancelledError):
         pass
     finally:
+        for w in watchers:
+            w.stop()
+        server.device_probes = []
         if web_server is not None:
             await web_server.stop()
         if cursor_monitor is not None:

@@ -314,6 +314,11 @@ SETTING_DEFINITIONS: List[Spec] = [
              "captures via /debug/jax-trace on the metrics port "
              "(writes profile files to a temp dir; off by default).",
              server_only=True),
+    BoolSpec("stall_stacks", False, "Stall watch: keep every thread's "
+             "stack beside the record of each stall of over 40 ms, and log "
+             "it (taken as the stall ends, or during it where the event "
+             "loop alone is blocked; for diagnosis, off by default).",
+             server_only=True),
     StrSpec("turn_host", "", "TURN server hostname for /turn credentials.",
             legacy_env="TURN_HOST", server_only=True),
     StrSpec("turn_port", "3478", "TURN server port.",

@@ -17,8 +17,7 @@ import numpy as np
 import pytest
 
 from selkies_tpu.encoder.jpeg import StripeOutput
-from selkies_tpu.observability import (STAGES, FlightRecorder, FrameTracer,
-                                       Metrics)
+from selkies_tpu.observability import STAGES, FlightRecorder, Metrics
 from selkies_tpu.protocol import VideoStripe, unpack_binary
 from selkies_tpu.robustness import InProcessClient
 from selkies_tpu.server.app import StreamingApp
@@ -39,7 +38,7 @@ def test_metrics_render():
     m = Metrics(port=0)
     m.set_fps(60.0)
     m.set_latency(12.5)
-    m.set_tpu_utilization(45.0)
+    m.set_device_queue_delay(0, 45.0)
     m.observe_encode(8.0, 50_000)
     m.set_clients(3)
     m.set_backpressured(1)
@@ -47,8 +46,7 @@ def test_metrics_render():
     text = m.render().decode()
     assert "fps 60.0" in text
     assert "latency 12.5" in text
-    assert "tpu_utilization 45.0" in text
-    assert "gpu_utilization 45.0" in text      # reference-compatible alias
+    assert 'device_queue_delay_ms{device="0"} 45.0' in text
     assert "connected_clients 3.0" in text
     assert 'webrtc_statistics_info{bitrate="8000000"}' in text
     assert "tpuenc_encode_ms_bucket" in text
@@ -229,19 +227,8 @@ def test_mesh_submit_seq_accounts_for_inflight_window():
     assert facade.try_submit("frame") == 6    # 5 + 1 in-flight (live gen)
     # a second submit before the tick replaces the pending frame: drop
     assert facade.try_submit("frame2") is None
-
-
-def test_frame_tracer_compat_shim():
-    """The pre-recorder API stays importable and functional."""
-    tr = FrameTracer(capacity=5)
-    for fid in range(20):
-        span = tr.begin(fid)
-        span.stamps["dispatch"] = 0.001
-        span.stamps["harvest"] = 0.002 + 0.0001 * fid
-        tr.finish(fid)
-    assert tr.summary()["frames"] == 5
-    assert tr.finish(999) is None
-    assert tr.percentile_ms("dispatch", "harvest", 50) >= 1.0
+    # ... and says under which seq the replacing frame will harvest
+    assert facade.replaced_seq == 6
 
 
 # ---------------------------------------------------------------------------
@@ -492,6 +479,8 @@ def test_http_endpoint_healthz_trace_and_nonfatal_bind():
 
 
 def test_stage_names_stable():
-    """The eight-stage glossary is a wire/bench/docs contract."""
-    assert STAGES == ("capture", "stage", "dispatch", "fetch_wait",
-                      "pack", "queue", "send", "ack")
+    """The stage glossary is a wire/bench/docs contract: the eight work
+    stages, and the four waits between them (in path order)."""
+    assert STAGES == ("capture", "submit_wait", "pipe_wait", "stage",
+                      "dispatch", "in_device", "fetch_wait", "pack",
+                      "harvest_wait", "queue", "send", "ack")

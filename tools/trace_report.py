@@ -51,8 +51,8 @@ def build_frames(trace: Dict[str, Any]) -> List[Dict[str, Any]]:
     the set of X slices sharing (pid, tid, args.frame_id)."""
     frames: Dict[Any, Dict[str, Any]] = {}
     for ev in trace.get("traceEvents", []):
-        if ev.get("ph") != "X":
-            continue
+        if ev.get("ph") != "X" or ev.get("cat", "frame") != "frame":
+            continue            # the threads' rows are not frames
         args = ev.get("args", {})
         # the recorder stamps a unique span token per frame; fall back
         # to (pid, tid, frame_id) for captures from older exports
