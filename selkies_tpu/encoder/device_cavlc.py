@@ -63,6 +63,17 @@ MB = 16
 #: MB header unit is ≤ ~90 bits.  Anything larger flags overflow.
 UNIT_WORDS = 16
 
+#: The output stage (word assembly + frame compaction) comes in two sizes:
+#: the stripe capacity V = ``max_stripe_bytes // 4`` words, and V divided
+#: by this. A frame whose largest stripe fits the small one takes it; the
+#: predicate reads the frame's own ``t_bits`` (:func:`takes_low_tier`).
+#: Set from the served 1080p scroll (PERF.md, PR 26): V = 32,768 words a
+#: stripe, of which the mean stripe fills ~1,550 (6.2 kB); V // 4 = 8,192
+#: words = 32 KiB = 64 B/MB is 5x that mean and 2.4x the ~27 B/MB of
+#: streaming QPs, and the scalar-core gathers that build each output word
+#: cost what the tier holds, not what the frame produced.
+LOW_TIER_DIV = 4
+
 #: fixed per-stripe head: t_bits u32 LE, base_words u32 LE, damage, ovf,
 #: 2 pad bytes
 HEAD_BYTES = 12
@@ -413,14 +424,15 @@ def _pack_units(bits, lens, W: int):
     return words, Lb.astype(jnp.int32), unit_ovf
 
 
-def _globalize(words_unit, Lb, V: int):
-    """Concatenate each stripe's units into its bitstream words.
+def _unit_spans(words_unit, Lb):
+    """Where each unit's bits fall in its stripe's bitstream.
 
     words_unit: [S, U, W] u32; Lb: [S, U] i32 bit lengths (0 = empty
-    unit).  Returns (words_stripe [S, V] u32, t_bits [S] i32).  Same
-    analytic boundary construction as device_entropy (empty units are
-    safe: a non-boundary unit never has bits past the word its successor
-    starts in)."""
+    unit).  Returns (cs0, cs1 [S, U*W] u32 — running sums of every unit
+    word shifted to its bit offset, and of what that shift spills into
+    the next word; g0, e [S, U] i32 — the stripe words each unit starts
+    and ends in; t_bits [S] i32).  Nothing here depends on how many
+    words the output holds."""
     S, U, W = words_unit.shape
     cumb = jnp.cumsum(Lb, axis=1)
     base = cumb - Lb
@@ -436,7 +448,18 @@ def _globalize(words_unit, Lb, V: int):
                    words_unit << (32 - r3).astype(jnp.uint32))
     cs0 = jnp.cumsum(u0.reshape(S, U * W), axis=1, dtype=jnp.uint32)
     cs1 = jnp.cumsum(u1.reshape(S, U * W), axis=1, dtype=jnp.uint32)
+    return cs0, cs1, g0, e, t_bits
 
+
+def _stripe_words(cs0, cs1, g0, e, V: int, W: int):
+    """The first V words of each stripe's bitstream, [S, V] u32.
+
+    Same analytic boundary construction as device_entropy (empty units
+    are safe: a non-boundary unit never has bits past the word its
+    successor starts in).  Every output word costs one scatter slot and
+    three scalar-core gathers, so the cost is V's, whatever the stripe
+    produced: callers pick V (``pack_p_frame_words``)."""
+    S, U = g0.shape
     g0c = jnp.clip(g0, 0, V - 1)
     srows = jnp.arange(S, dtype=jnp.int32)[:, None]
     bidx = jnp.arange(U, dtype=jnp.int32)[None, :]
@@ -456,9 +479,11 @@ def _globalize(words_unit, Lb, V: int):
     word0 = s_at0 - jnp.concatenate(
         [jnp.zeros((S, 1), jnp.uint32), s_at0[:, :-1]], axis=1)
 
+    # the spill of word w-1: its last unit is lastblk one word to the
+    # right (unit 0 at w = 0), so ge there is ge_b shifted, not a gather
     lastblk1 = jnp.concatenate(
         [jnp.zeros((S, 1), jnp.int32), lastblk[:, :-1]], axis=1)
-    ge_b1 = jnp.take_along_axis(ge, lastblk1, axis=1)
+    ge_b1 = jnp.concatenate([ge[:, :1], ge_b[:, :-1]], axis=1)
     g0b1 = ge_b1 >> 16
     e1b1 = ge_b1 & 0xFFFF
     jstar1 = jnp.where(e1b1 + 1 <= w_ar, W - 1,
@@ -468,7 +493,31 @@ def _globalize(words_unit, Lb, V: int):
     word1 = s_at1 - jnp.concatenate(
         [jnp.zeros((S, 1), jnp.uint32), s_at1[:, :-1]], axis=1)
 
-    return word0 + word1, t_bits
+    return word0 + word1
+
+
+def _compact(words_stripe, wc, base_words):
+    """Stripes back-to-back at word granularity: [S, V] -> [S * V] u32,
+    stripe s's first wc[s] words at base_words[s], zeros after the last."""
+    S, V = words_stripe.shape
+    j = jnp.arange(S * V, dtype=jnp.int32)
+    sidx = jnp.clip(
+        jnp.searchsorted(base_words, j, side="right") - 1, 0, S - 1)
+    src = sidx * V + jnp.clip(j - base_words[sidx], 0, V - 1)
+    valid = j < (base_words[-1] + wc[-1])
+    return jnp.where(valid, words_stripe.reshape(-1)[src], 0)
+
+
+def low_tier_words(max_stripe_bytes: int) -> int:
+    """Words a stripe of the low output tier holds (``LOW_TIER_DIV``)."""
+    return max_stripe_bytes // 4 // LOW_TIER_DIV
+
+
+def takes_low_tier(t_bits, max_stripe_bytes: int):
+    """Does a frame with these per-stripe payload bits ([S], device or
+    host array) fit the low output tier? Stripes outside the update mask
+    pack nothing and read 0, so the maximum is over the updated ones."""
+    return t_bits.max() <= 32 * low_tier_words(max_stripe_bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -485,12 +534,18 @@ def default_max_stripe_bytes(mb_w: int, mb_h: int) -> int:
 
 
 def pack_p_frame_words(mv, luma, chroma_dc, chroma_ac, update, *,
-                       mb_w: int, mb_h: int, max_stripe_bytes: int):
+                       mb_w: int, mb_h: int, max_stripe_bytes: int,
+                       tiered: bool = True):
     """Device CAVLC over one P frame's level tensors.
 
     mv [S, n, 2] (dy, dx) int; luma [S, n, 16, 4, 4] (raster 4×4 grid);
     chroma_dc [S, n, 2, 2, 2]; chroma_ac [S, n, 2, 4, 4, 4] (position 0
     zeroed); update [S] bool — stripes outside the mask pack nothing.
+
+    The output stage runs in one of two sizes chosen by the frame's own
+    bits (``LOW_TIER_DIV``); ``tiered=False`` keeps the one capacity-sized
+    body, for a caller that runs the pack under ``jax.vmap``, where a
+    batched predicate makes ``cond`` a ``select`` that runs both.
 
     Returns (words [cap_words] u32 — per-stripe P-slice payloads (post
     slice header, MSB-first) compacted back-to-back word-aligned;
@@ -678,18 +733,29 @@ def pack_p_frame_words(mv, luma, chroma_dc, chroma_ac, update, *,
     # ---- pack + globalize + compact --------------------------------------
     words_u, Lb, unit_ovf = _pack_units(
         all_bits.reshape(S * U, SLOT), all_lens.reshape(S * U, SLOT), W)
-    words_stripe, t_bits = _globalize(
-        words_u.reshape(S, U, W), Lb.reshape(S, U), V)
-
+    cs0, cs1, g0, e, t_bits = _unit_spans(
+        words_u.reshape(S, U, W), Lb.reshape(S, U))
     wc = jnp.minimum((t_bits + 31) // 32, V)
     base_words = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(wc)[:-1].astype(jnp.int32)])
-    j = jnp.arange(cap_words, dtype=jnp.int32)
-    sidx = jnp.clip(
-        jnp.searchsorted(base_words, j, side="right") - 1, 0, S - 1)
-    src = sidx * V + jnp.clip(j - base_words[sidx], 0, V - 1)
-    valid = j < (base_words[-1] + wc[-1])
-    words = jnp.where(valid, words_stripe.reshape(-1)[src], 0)
+
+    def output_stage(v_out: int):
+        def run(cs0, cs1, g0, e, wc, base_words):
+            out = _compact(_stripe_words(cs0, cs1, g0, e, v_out, W),
+                           wc, base_words)
+            return jnp.pad(out, (0, cap_words - S * v_out))
+        return run
+
+    spans = (cs0, cs1, g0, e, wc, base_words)
+    v_lo = low_tier_words(max_stripe_bytes)
+    if tiered and v_lo:
+        # same words either way: in the low tier every stripe's wc is at
+        # most v_lo, so S * v_lo slots hold the whole frame and the rest
+        # of the buffer is the zeros the high tier would have put there
+        words = jax.lax.cond(takes_low_tier(t_bits, max_stripe_bytes),
+                             output_stage(v_lo), output_stage(V), *spans)
+    else:
+        words = output_stage(V)(*spans)
 
     # a slot may span at most 2 words (len ≤ 32); exp-Golomb header slots
     # are the only unbounded-by-table lengths and stay ≤ 31 bits for any
@@ -703,7 +769,8 @@ def pack_p_frame_words(mv, luma, chroma_dc, chroma_ac, update, *,
 
 @phase("entropy")
 def pack_p_frame(mv, luma, chroma_dc, chroma_ac, damage, update, *,
-                 mb_w: int, mb_h: int, max_stripe_bytes: int):
+                 mb_w: int, mb_h: int, max_stripe_bytes: int,
+                 tiered: bool = True):
     """Fetchable uint8 buffer: [S, HEAD_BYTES] head + big-endian payload.
 
     Head per stripe: t_bits u32 LE, base_words u32 LE, damage u8,
@@ -712,7 +779,8 @@ def pack_p_frame(mv, luma, chroma_dc, chroma_ac, damage, update, *,
     bits 8i..8i+7."""
     words, t_bits, base_words, overflow = pack_p_frame_words(
         mv, luma, chroma_dc, chroma_ac, update,
-        mb_w=mb_w, mb_h=mb_h, max_stripe_bytes=max_stripe_bytes)
+        mb_w=mb_w, mb_h=mb_h, max_stripe_bytes=max_stripe_bytes,
+        tiered=tiered)
     S = t_bits.shape[0]
 
     def le4(x):

@@ -380,6 +380,11 @@ class H264StripeEncoder:
         #: repeated growth here is the signal the degradation ladder acts
         #: on (ISSUE 2: rung device -> host -> jpeg)
         self.entropy_errors_total = 0
+        #: P frames harvested from the device-CAVLC pack, and those of
+        #: them whose bits fit its low output tier: the predicate the
+        #: device branched on, read from the same t_bits
+        self.cavlc_frames_total = 0
+        self.cavlc_low_tier_frames_total = 0
 
     def _choose_prefix(self) -> int:
         """Pick between the two compiled head sizes from the adaptive
@@ -643,6 +648,9 @@ class H264StripeEncoder:
             # device-CAVLC transfer: head + bit-exact slice payloads
             levels16 = None
             t_bits, base_words, damage, ovf = dcav.parse_cavlc_head(host, S)
+            self.cavlc_frames_total += 1
+            self.cavlc_low_tier_frames_total += bool(
+                dcav.takes_low_tier(t_bits, self._cavlc_msb))
             # mirror the device's per-stripe word clip: an overflowing
             # stripe records its unclipped t_bits but compacts at most V
             # words, and an unclipped estimate here would force a

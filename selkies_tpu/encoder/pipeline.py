@@ -766,6 +766,9 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             "frames_dropped": self.frames_dropped_total,
             "entropy_errors": getattr(self.base, "entropy_errors_total", 0),
             "entropy": getattr(self.base, "entropy", None),
+            "cavlc_frames": getattr(self.base, "cavlc_frames_total", 0),
+            "cavlc_low_tier_frames": getattr(
+                self.base, "cavlc_low_tier_frames_total", 0),
             "staging_stalls": (self._staging.stalls_total
                                + self._staging_batch.stalls_total),
             **self._telemetry_stats(),
@@ -778,6 +781,9 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             self.metrics.set_host_entropy_ms_per_frame(
                 st["host_entropy_ms_per_frame"])
             self.metrics.set_inflight_batches(st["inflight_batches"])
+            if st["cavlc_frames"]:
+                self.metrics.set_cavlc_low_tier_share(
+                    st["cavlc_low_tier_frames"] / st["cavlc_frames"])
 
     def request_keyframe(self) -> None:
         self.base.request_keyframe()

@@ -89,6 +89,11 @@ class Metrics:
             "wall time per frame (native CAVLC / overflow fallbacks; ~0 "
             "when the device entropy tiers carry steady state)",
             registry=self.registry)
+        self.cavlc_low_tier_share = Gauge(
+            "tpuenc_cavlc_low_tier_share", "Share of device-CAVLC P frames "
+            "whose largest stripe fit the pack's low output tier (the "
+            "cheap one; paint-over and busy frames take the high tier)",
+            registry=self.registry)
         # ISSUE 12: the dispatch/fetch-floor claims must stay measured —
         # the async pipeline driver keeps >=2 batches in flight, and
         # these series prove (or disprove) it per deployment
@@ -324,6 +329,10 @@ class Metrics:
     def set_host_entropy_ms_per_frame(self, ms: float) -> None:
         if HAVE_PROM:
             self.host_entropy_ms_per_frame.set(ms)
+
+    def set_cavlc_low_tier_share(self, share: float) -> None:
+        if HAVE_PROM:
+            self.cavlc_low_tier_share.set(share)
 
     def set_inflight_batches(self, n: int) -> None:
         if HAVE_PROM:

@@ -124,10 +124,13 @@ def make_h264_mesh_step(mesh: Mesh, pad_h: int, pad_w: int, stripe_h: int,
             # (their merged intra levels are not P-slice material) and
             # recover from flat16 on the host, like overflow
             upd_p = update & (idr1 == 0)
+            # ``one`` runs under jax.vmap (local_step): a per-session
+            # predicate would turn the pack's tier ``cond`` into a
+            # ``select`` and run both output sizes on every frame
             buf = dcav.pack_p_frame(
                 enc.mv, enc.luma, enc.chroma_dc, enc.chroma_ac,
                 damage, upd_p, mb_w=pad_w // MB, mb_h=stripe_h // MB,
-                max_stripe_bytes=max_stripe_bytes)
+                max_stripe_bytes=max_stripe_bytes, tiered=False)
         else:
             buf = dev._pack_sparse(flat16, damage, update,
                                    cap_frac=cap_frac)

@@ -8,16 +8,21 @@ entry gets exercised, then decodes with OpenCV/ffmpeg and compares against
 the NumpyMirror reconstruction.  Validates the hand-entered spec tables in
 native/cavlc.cpp.
 
-Mode 2 (``python tools/cavlc_fuzz.py --device [n]``): differential-fuzzes
-the ON-DEVICE CAVLC packer (encoder/device_cavlc.py) against the native
-_libselkies_cavlc.so reference over random P-frame level tensors — full
-residual surface (luma + chroma DC/AC), random MVs (skip/mvd paths),
-|level| > 127 edges and escape-overflow magnitudes.  Non-overflow stripes
-must be BIT-IDENTICAL; overflow stripes must be flagged (they take the
+Mode 2 (``python tools/cavlc_fuzz.py --device [n] [--geom=WxH]``):
+differential-fuzzes the ON-DEVICE CAVLC packer (encoder/device_cavlc.py)
+against the native _libselkies_cavlc.so reference over random P-frame level
+tensors — full residual surface (luma + chroma DC/AC), random MVs (skip/mvd
+paths), |level| > 127 edges and escape-overflow magnitudes — and random
+stripe capacities, so that frames land in the pack's low output tier, in
+its high tier and past the capacity, plus a constructed pair one bit either
+side of the tier boundary.  The tiered pack's buffer must equal the
+single-tier body's byte for byte; non-overflow stripes must be
+BIT-IDENTICAL to native; overflow stripes must be flagged (they take the
 flat16 + host fallback in the product).  tests/test_device_cavlc.py runs a
 seeded subset of this under tier 1.
 """
 
+import functools
 import os
 import sys
 import tempfile
@@ -128,20 +133,79 @@ def random_p_frame(rng, S, n_mb, density, magnitude, mv_range=12):
     return mv, luma, cdc, cac
 
 
-def check_device_seed(seed, mb_w=None, mb_h=None, S=2, qp=None,
-                      frame_num=None, max_stripe_bytes=65536):
-    """Differential: device pack + host glue vs native coder, one seed.
+@functools.lru_cache(maxsize=None)
+def _packer(mb_w, mb_h, max_stripe_bytes, tiered):
+    """The product's pack, jitted once per shape and capacity (on the
+    chip an un-jitted call would compile operation by operation)."""
+    import jax
 
-    Returns (ok, why, n_overflow).  Overflow stripes are exempt from the
-    bit-compare (the product recodes them from flat16 via the native
-    path, which IS the reference — trivially identical) but must be
-    flagged so that fallback actually engages.
-    """
+    from selkies_tpu.encoder import device_cavlc as dcav
+
+    return jax.jit(functools.partial(
+        dcav.pack_p_frame, mb_w=mb_w, mb_h=mb_h,
+        max_stripe_bytes=max_stripe_bytes, tiered=tiered))
+
+
+def device_buffer(frame, mb_w, mb_h, max_stripe_bytes, tiered=True):
+    """The fetchable buffer of one (mv, luma, cdc, cac) frame with every
+    stripe damaged and updated, as a host array."""
     import jax.numpy as jnp
 
+    every = jnp.ones(frame[0].shape[0], bool)
+    return np.asarray(_packer(mb_w, mb_h, max_stripe_bytes, tiered)(
+        *[jnp.asarray(x) for x in frame], every, every))
+
+
+def check_device_frame(mv, luma, cdc, cac, *, mb_w, mb_h, qp, frame_num,
+                       max_stripe_bytes):
+    """Differential: one frame's device pack + host glue vs native coder.
+
+    The buffer of the tiered pack (the output stage sized by the frame's
+    bits) must equal the single-tier pack's byte for byte, whichever tier
+    the frame takes; non-overflow stripes must then be bit-identical to
+    native.  Returns (ok, why, n_overflow, tier) with tier "low"/"high" —
+    the host's reading of the predicate the device branched on.  Overflow
+    stripes are exempt from the bit-compare (the product recodes them
+    from flat16 via the native path, which IS the reference — trivially
+    identical) but must be flagged so that fallback actually engages.
+    """
     from selkies_tpu.encoder import device_cavlc as dcav
     from selkies_tpu.encoder.h264 import encode_picture_nals_np
 
+    S, n_mb = mv.shape[:2]
+    buf, single = [
+        device_buffer((mv, luma, cdc, cac), mb_w, mb_h, max_stripe_bytes,
+                      tiered) for tiered in (True, False)]
+    t_bits, base_words, _, ovf = dcav.parse_cavlc_head(buf, S)
+    tier = "low" if dcav.takes_low_tier(t_bits, max_stripe_bytes) \
+        else "high"
+    n_ovf = int(ovf.sum())
+    if not np.array_equal(buf, single):
+        return False, f"{tier} tier's buffer differs from the " \
+            "single-tier body's", n_ovf, tier
+
+    ldc = np.zeros((n_mb, 4, 4), np.int32)
+    for s in range(S):
+        if ovf[s]:
+            continue
+        ref = encode_picture_nals_np(
+            mv[s], luma[s], ldc, cdc[s], cac[s], is_idr=False,
+            mb_w=mb_w, mb_h=mb_h, qp=qp, frame_num=frame_num)
+        pb, nbits = dcav.payload_slice(buf, S, base_words, t_bits, s)
+        if dcav.assemble_p_slice(pb, nbits, qp, frame_num) != ref:
+            return False, f"stripe {s} bit mismatch", n_ovf, tier
+    return True, "", n_ovf, tier
+
+
+#: per-stripe capacities the fuzz draws from: against stripes of a few
+#: hundred to ~100k bits they put frames in the low tier, in the high
+#: tier and past the capacity (the stripe-size overflow flag)
+STRIPE_BYTES = (512, 2048, 8192, 65536)
+
+
+def check_device_seed(seed, mb_w=None, mb_h=None, S=2, qp=None,
+                      frame_num=None, max_stripe_bytes=None):
+    """:func:`check_device_frame` over one seed's random frame."""
     rng = np.random.default_rng(seed)
     mb_w = mb_w if mb_w is not None else int(rng.integers(2, 7))
     mb_h = mb_h if mb_h is not None else int(rng.integers(1, 4))
@@ -152,53 +216,101 @@ def check_device_seed(seed, mb_w=None, mb_h=None, S=2, qp=None,
     # |level| > 127 (int8-sparse overflow) and escape-overflow (> ~2064)
     # edges both land regularly
     magnitude = int(rng.choice([1, 2, 8, 30, 127, 200, 2063, 2500]))
+    mv, luma, cdc, cac = random_p_frame(rng, S, mb_w * mb_h, density,
+                                        magnitude)
+    if max_stripe_bytes is None:
+        max_stripe_bytes = int(rng.choice(STRIPE_BYTES))
+    return check_device_frame(
+        mv, luma, cdc, cac, mb_w=mb_w, mb_h=mb_h, qp=qp,
+        frame_num=frame_num, max_stripe_bytes=max_stripe_bytes)
+
+
+#: one lone luma coefficient codes in 1..14 bits as it runs through these
+#: (level_code 0..13 at suffix_length 0): a knob of one bit a step
+_KNOB = [v for m in range(2, 9) for v in (m, -m)]
+
+
+def boundary_frames(seed, mb_w, mb_h, S=2):
+    """Two frames either side of the tier boundary, and the capacity that
+    puts it there: (at, over, max_stripe_bytes).  Stripe 0 of ``at`` is
+    exactly 32 * V_LO bits long (the last bit the low tier takes), stripe
+    0 of ``over`` one bit longer; every other stripe is shorter."""
+    from selkies_tpu.encoder import device_cavlc as dcav
+
+    rng = np.random.default_rng(seed)
     n_mb = mb_w * mb_h
-    mv, luma, cdc, cac = random_p_frame(rng, S, n_mb, density, magnitude)
+    mv, luma, cdc, cac = random_p_frame(rng, S, n_mb, 0.3, 8)
+    luma[1:, n_mb // 2:] = 0             # the other stripes: shorter
 
-    words, t_bits, base_words, ovf = [np.asarray(x) for x in (
-        dcav.pack_p_frame_words(
-            jnp.asarray(mv), jnp.asarray(luma), jnp.asarray(cdc),
-            jnp.asarray(cac), jnp.ones(S, bool),
-            mb_w=mb_w, mb_h=mb_h, max_stripe_bytes=max_stripe_bytes))]
-    payload = np.stack(
-        [(words >> 24) & 0xFF, (words >> 16) & 0xFF,
-         (words >> 8) & 0xFF, words & 0xFF], -1).astype(np.uint8).reshape(-1)
+    def with_knobs(steps):               # stripe 0, block 0 of three MBs
+        out = luma.copy()
+        for mb, k in enumerate(steps):
+            out[0, mb, 0] = 0
+            out[0, mb, 0, 0, 0] = _KNOB[k]
+        return out
 
-    ldc = np.zeros((n_mb, 4, 4), np.int32)
-    for s in range(S):
-        ref = encode_picture_nals_np(
-            mv[s], luma[s], ldc, cdc[s], cac[s], is_idr=False,
-            mb_w=mb_w, mb_h=mb_h, qp=qp, frame_num=frame_num)
-        if ovf[s]:
-            continue
-        start = int(base_words[s]) * 4
-        nbits = int(t_bits[s])
-        got = dcav.assemble_p_slice(
-            payload[start:start + ((nbits + 31) // 32) * 4],
-            nbits, qp, frame_num)
-        if got != ref:
-            return False, f"stripe {s} bit mismatch", int(ovf.sum())
-    return True, "", int(ovf.sum())
+    def bits_of(lu):
+        return dcav.parse_cavlc_head(
+            device_buffer((mv, lu, cdc, cac), mb_w, mb_h, 65536), S)[0]
+
+    def steps_for(extra):                # 0..39 bits over three knobs
+        return [min(13, max(0, extra - 13 * i)) for i in range(3)]
+
+    extra = int(-bits_of(with_knobs([0, 0, 0]))[0] % 32)
+    at, over = with_knobs(steps_for(extra)), with_knobs(steps_for(extra + 1))
+    t_at, t_over = bits_of(at), bits_of(over)
+    assert t_at[0] % 32 == 0 and t_over[0] == t_at[0] + 1, (t_at, t_over)
+    assert (t_at[1:] < t_at[0]).all(), t_at
+    msb = int(t_at[0]) // 2              # V_LO = msb/16 words = t_at bits
+    assert dcav.low_tier_words(msb) * 32 == t_at[0]
+    return (mv, at, cdc, cac), (mv, over, cdc, cac), msb
 
 
-def main_device(n):
-    fails, n_ovf = [], 0
-    for seed in range(n):
-        ok, why, ovf = check_device_seed(seed)
+def main_device(n, geom=None):
+    """``n`` random frames, then the constructed pair either side of the
+    tier boundary. ``geom`` (mb_w, mb_h) pins one geometry: on the chip
+    every geometry and capacity is a compile of its own."""
+    import collections
+
+    fails, n_ovf, tiers = [], 0, collections.Counter()
+
+    def note(label, result):
+        nonlocal n_ovf
+        ok, why, ovf, tier = result
         n_ovf += ovf
+        tiers[tier] += 1
         if not ok:
-            fails.append((seed, why))
-            print(f"seed {seed}: FAIL ({why})")
-    print(f"{n - len(fails)}/{n} passed ({n_ovf} overflow stripes "
-          "took the flagged fallback)")
+            fails.append((label, why))
+            print(f"{label}: FAIL ({why})")
+
+    mb_w, mb_h = geom or (None, None)
+    for seed in range(n):
+        note(f"seed {seed}", check_device_seed(seed, mb_w=mb_w, mb_h=mb_h))
+    edges = [geom] if geom else [(4, 2), (6, 3), (5, 1)]
+    for seed, (mb_w, mb_h) in enumerate(edges):
+        at, over, msb = boundary_frames(seed, mb_w, mb_h)
+        for name, frame, want in (("at", at, "low"), ("over", over, "high")):
+            result = check_device_frame(
+                *frame, mb_w=mb_w, mb_h=mb_h, qp=26, frame_num=3,
+                max_stripe_bytes=msb)
+            if result[0] and result[3] != want:
+                result = (False, f"took the {result[3]} tier") + result[2:]
+            note(f"boundary {mb_w}x{mb_h} {name}", result)
+    total = n + 2 * len(edges)
+    print(f"{total - len(fails)}/{total} passed ({n} random, "
+          f"{2 * len(edges)} at the tier boundary; {tiers['low']} low "
+          f"tier, {tiers['high']} high tier; {n_ovf} overflow stripes took "
+          "the flagged fallback)")
     return 1 if fails else 0
 
 
 def main():
-    args = [a for a in sys.argv[1:] if a != "--device"]
+    args = [a for a in sys.argv[1:] if not a.startswith("--")]
     n = int(args[0]) if args else 500
     if "--device" in sys.argv:
-        return main_device(n)
+        geom = next((tuple(int(v) for v in a[7:].split("x"))
+                     for a in sys.argv if a.startswith("--geom=")), None)
+        return main_device(n, geom)
     fails = []
     for seed in range(n):
         ok, why, _ = check_seed(seed)
