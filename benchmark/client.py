@@ -66,6 +66,7 @@ class Client:
         self.frames: List[Frame] = []       # in arrival order, all of them
         self.said: List[str] = []           # text the server sent
         self.killed: Optional[str] = None
+        self.t_settings = 0.0               # when SETTINGS was sent
         self.ws = None
         self._task: Optional[asyncio.Task] = None
         self._open: Optional[Frame] = None
@@ -80,6 +81,7 @@ class Client:
         schema = json.loads(await self.ws.recv())
         if schema.get("type") != "server_settings":
             raise RuntimeError("no server_settings from the server")
+        self.t_settings = time.monotonic()
         await self.ws.send("SETTINGS," + json.dumps({
             "displayId": self.display_id,
             "initialClientWidth": self.width,

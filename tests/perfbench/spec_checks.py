@@ -1,0 +1,184 @@
+"""What tier 1 holds ``BENCHMARK.json`` to, as functions of a spec and the
+checkout it lies in. The tests call them on the real file; the test that
+plays a later PR calls ``whole`` on a copy of the real file with a
+configuration, a cell and per-layer metrics appended, so a check that pins
+how many entries there are fails there, beside the real file's."""
+
+import json
+import os
+import re
+import shutil
+
+from benchmark import cells
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+#: the per-layer entries PR 24 was accepted with: they stay first, in order
+ACCEPTED = ["latency_p95_ms", "warmup_s", "compile_cache_misses",
+            "server_send_p50_ms", "submit_drop_pct",
+            "driver_dispatch_p50_ms", "driver_fetch_wait_p50_ms",
+            "frames_in_flight", "step_device_ms", "me_kernel_ms",
+            "me_kernel_roofline", "device_idle_pct"]
+#: the entries appended since, in the order they came; what each says is
+#: BENCHMARK.json's to state and ``metric_entry``'s to check
+APPENDED = ["driver_submit_wait_p50_ms", "driver_pipe_wait_p50_ms",
+            "driver_stage_p50_ms", "driver_in_device_p50_ms",
+            "driver_pack_p50_ms", "server_harvest_wait_p50_ms",
+            "idle_driver_stage_pct", "idle_driver_pack_pct",
+            "idle_driver_fetch_pct", "idle_driver_sleep_pct",
+            "idle_driver_other_pct", "device_queue_delay_p50_ms",
+            "phase_colour_ms", "phase_transform_ms", "phase_entropy_ms",
+            "phase_motion_ms", "interpreter_stall_max_ms",
+            "loop_stall_max_ms", "cavlc_low_tier_pct"]
+
+
+def read_spec(root):
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def scratch_checkout(tmp_path):
+    """A checkout in ``tmp_path`` that holds the real data files, as a
+    later PR finds them: it adds files and a ``BENCHMARK.json``."""
+    root = tmp_path / "checkout"
+    for sub in ("configs", "traffic", "layer_metrics"):
+        shutil.copytree(os.path.join(ROOT, "benchmark", sub),
+                        root / "benchmark" / sub)
+    return root
+
+
+def top_level(spec, root):
+    assert set(spec) == {"command", "paths", "run_seconds", "configs",
+                         "workloads", "end_to_end", "per_layer"}
+    assert isinstance(spec["run_seconds"], int) and 1 <= spec["run_seconds"] <= 51
+    assert 1 <= len(spec["paths"]) <= 16 and len(spec["command"]) <= 32
+    assert 1 <= len(spec["configs"]) <= 24 and 1 <= len(spec["workloads"]) <= 24
+    assert 1 <= len(spec["end_to_end"]) <= 16 and 1 <= len(spec["per_layer"]) <= 128
+    assert os.path.getsize(os.path.join(root, "BENCHMARK.json")) <= 64 * 1024
+    runs = 2 + 14 * 24
+    assert runs * (spec["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
+
+
+def config_entry(spec, c, root):
+    assert set(c) == {"name", "source", "file", "reduced", "why"}
+    assert NAME.match(c["name"]) and len(c["reduced"]) <= 16
+    assert any(c["file"].startswith(p + "/") for p in spec["paths"])
+    with open(os.path.join(root, c["file"])) as f:
+        body = json.load(f)
+    assert body["source"] == c["source"] and body["reduced"] == c["reduced"]
+    assert all(k in body for k in c["reduced"])
+    for text in (c["source"], c["why"]):
+        assert 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
+    assert any(w["config"] == c["name"] for w in spec["workloads"])
+
+
+def workload_entry(spec, w):
+    assert set(w) == {"name", "config", "traffic", "chips", "why"}
+    assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+    assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
+    assert w["config"] in {c["name"] for c in spec["configs"]}
+
+
+def cells_are_distinct_and_few_take_four_chips(spec):
+    pairs = [(w["config"], w["traffic"]) for w in spec["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+    four = sum(1 for w in spec["workloads"] if w["chips"] == 4)
+    assert four <= max(1, len(spec["workloads"]) // 2)
+
+
+def metric_entry(spec, m):
+    e2e = m in spec["end_to_end"]
+    keys = {"name", "unit", "better", "source"} | (
+        {"bound"} if e2e else {"layer", "moves"})
+    assert keys <= set(m) <= keys | {"workloads"}
+    assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+    assert m["better"] in ("lower", "higher")
+    assert m["source"] in (("host_clock", "device_trace") if e2e else (
+        "device_trace", "program_span", "program_counter", "host_clock"))
+    if e2e:
+        assert 0.01 <= m["bound"] <= 0.25
+    else:
+        assert m["moves"] in {e["name"] for e in spec["end_to_end"]}
+        assert "\n" not in m["layer"] and 1 <= len(m["layer"]) <= 200
+    for w in m.get("workloads", []):
+        assert w in {x["name"] for x in spec["workloads"]}
+    if m["name"].endswith("_roofline"):
+        assert m["unit"] == "%"
+
+
+def names_are_unique_and_setup_is_there(spec):
+    names = [m["name"] for m in spec["end_to_end"] + spec["per_layer"]]
+    assert len(set(names)) == len(names)
+    assert "setup_s" in names
+    for kind in ("workloads", "configs"):
+        assert len({x["name"] for x in spec[kind]}) == len(spec[kind])
+    files = [c["file"] for c in spec["configs"]]
+    assert len(set(files)) == len(files)
+
+
+def cell_finds_its_files(workload, root):
+    bench_dir = os.path.join(root, "benchmark")
+    cell = cells.load_cell(workload, root=root)
+    assert cell.config["name"] == cell.config_name
+    gen = cells.module("sources", cell.traffic["generator"], bench_dir)
+    assert hasattr(gen, "Source")
+    ref = cells.module("reference", cell.config["reference"], bench_dir)
+    assert {"undecodable", "unreadable", "bad_tiles"} <= set(
+        cell.limits()) <= {"undecodable", "unreadable", "y_outside_pct",
+                           "c_outside_pct", "bad_tiles"}
+    lo, hi = cell.config["regime"]["frames_in_flight"]
+    assert 0 < lo < hi
+    assert ref.steps(cell.config["quantiser"])[0].shape == (ref.BLOCK,) * 2
+    assert {m["name"] for m in cell.end_to_end} >= {"setup_s"}
+    assert len(cell.end_to_end) >= 2 and len(cell.per_layer) >= 1
+    assert cell.config["control"]["env"]
+    return cell
+
+
+def per_layer_metric_has_a_reader(name, root):
+    bench_dir = os.path.join(root, "benchmark")
+    spec = cells.layer_metric_spec(name, bench_dir)
+    assert callable(cells.module("readers", spec["reader"], bench_dir).read)
+    return spec
+
+
+def accepted_entries_are_untouched(spec):
+    """The twelve first entries as they were accepted and in their order,
+    every entry appended since after them and in the order it came. How
+    many follow is no business of this check: a later PR appends."""
+    names = [m["name"] for m in spec["per_layer"]]
+    assert names[:len(ACCEPTED)] == ACCEPTED
+    assert [n for n in names if n in APPENDED] == APPENDED
+    for name in APPENDED:
+        appended_entry(spec, name)
+
+
+def appended_entry(spec, name):
+    m = next(x for x in spec["per_layer"] if x["name"] == name)
+    metric_entry(spec, m)
+    # appended after the accepted entries, to a layer they already name
+    assert spec["per_layer"].index(m) >= len(ACCEPTED)
+    assert m["layer"] in {x["layer"]
+                          for x in spec["per_layer"][:len(ACCEPTED)]}
+    return m
+
+
+def whole(spec, root):
+    """Every check above, over every entry of ``spec``."""
+    top_level(spec, root)
+    for c in spec["configs"]:
+        config_entry(spec, c, root)
+    for w in spec["workloads"]:
+        workload_entry(spec, w)
+        cell_finds_its_files(w["name"], root)
+    cells_are_distinct_and_few_take_four_chips(spec)
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        metric_entry(spec, m)
+    for m in spec["per_layer"]:
+        per_layer_metric_has_a_reader(m["name"], root)
+    names_are_unique_and_setup_is_there(spec)
+    accepted_entries_are_untouched(spec)

@@ -5,56 +5,45 @@ BENCHMARK.json keeps to the contract's shape."""
 
 import json
 import os
-import re
-import shutil
 import sys
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-sys.path.insert(0, ROOT)
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+sys.path[:0] = [ROOT, HERE]
 
+import spec_checks  # noqa: E402
 from benchmark import cells  # noqa: E402
 
-SPEC = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SPEC = spec_checks.read_spec(ROOT)
 
 
 @pytest.mark.parametrize("w", [w["name"] for w in SPEC["workloads"]])
 def test_every_cell_finds_its_files(w):
-    cell = cells.load_cell(w)
-    assert cell.config["name"] == cell.config_name
-    gen = cells.module("sources", cell.traffic["generator"])
-    assert hasattr(gen, "Source")
-    ref = cells.module("reference", cell.config["reference"])
-    assert {"undecodable", "unreadable", "bad_tiles"} <= set(
-        cell.limits()) <= {"undecodable", "unreadable", "y_outside_pct",
-                           "c_outside_pct", "bad_tiles"}
-    lo, hi = cell.config["regime"]["frames_in_flight"]
-    assert 0 < lo < hi
-    assert ref.steps(cell.config["quantiser"])[0].shape == (ref.BLOCK,) * 2
-    assert {m["name"] for m in cell.end_to_end} >= {"setup_s"}
-    assert len(cell.end_to_end) >= 2 and len(cell.per_layer) >= 1
-    assert cell.config["control"]["env"]
+    spec_checks.cell_finds_its_files(w, ROOT)
 
 
 @pytest.mark.parametrize("m", [m["name"] for m in SPEC["per_layer"]])
 def test_every_per_layer_metric_has_a_reader_of_its_own(m):
-    spec = cells.layer_metric_spec(m)
-    assert callable(cells.module("readers", spec["reader"]).read)
+    spec_checks.per_layer_metric_has_a_reader(m, ROOT)
 
 
 def test_a_later_pr_adds_a_cell_a_mix_a_configuration_and_a_metric(tmp_path):
-    """Only new files and new entries: nothing that is there is edited."""
-    root = tmp_path / "checkout"
+    """Only new files and new entries: nothing that is there is edited. The
+    checkout is the real ``BENCHMARK.json`` and the real data files with a
+    later PR's appended, and every check tier 1 makes of the real file is
+    made of it: one that pins a count fails here too."""
+    root = spec_checks.scratch_checkout(tmp_path)
     bench = root / "benchmark"
-    for sub in ("configs", "traffic", "layer_metrics", "sources", "readers"):
-        (bench / sub).mkdir(parents=True)
+    for sub in ("sources", "readers"):
+        (bench / sub).mkdir()
+    before = {str(p.relative_to(root)): p.read_bytes()
+              for p in root.rglob("*") if p.is_file()}
     conf = json.load(open(os.path.join(
         ROOT, "benchmark", "configs", "ws-1080p60-jpeg.json")))
-    conf.update(name="ws-720p30-jpeg", width=1280, height=720, framerate=30)
+    conf.update(name="ws-720p30-jpeg", source="somewhere public",
+                width=1280, height=720, framerate=30)
     (bench / "configs" / "ws-720p30-jpeg.json").write_text(json.dumps(conf))
     (bench / "traffic" / "blink.json").write_text(json.dumps({
         "generator": "blink", "params": {"hz": 2}, "check_frames": 4}))
@@ -82,10 +71,27 @@ def test_a_later_pr_adds_a_cell_a_mix_a_configuration_and_a_metric(tmp_path):
             "name": name, "unit": reader_unit, "better": "lower",
             "source": "program_span", "layer": "server",
             "moves": "latency_p50_ms", "workloads": ["jpeg-720p30.blink"]})
-    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    (root / "BENCHMARK.json").write_text(json.dumps(spec, indent=1))
+
+    spec_checks.whole(spec_checks.read_spec(str(root)), str(root))
+    assert len(spec["per_layer"]) == len(SPEC["per_layer"]) + 2
+    for rel, was in before.items():
+        assert (root / rel).read_bytes() == was, rel
 
     cell = cells.load_cell("jpeg-720p30.blink", root=str(root))
     assert (cell.config["width"], cell.traffic["generator"]) == (1280, "blink")
+    # the new solo cell inherits what the solo driver, its thread track and
+    # the step's phases give: those entries name no cell, so the later PR
+    # edits none of them; only what one codec alone has names its cell
+    theirs = {m["name"] for m in cell.per_layer}
+    assert theirs >= {
+        "driver_submit_wait_p50_ms", "driver_pipe_wait_p50_ms",
+        "driver_stage_p50_ms", "driver_in_device_p50_ms",
+        "idle_driver_stage_pct", "idle_driver_pack_pct",
+        "idle_driver_fetch_pct", "idle_driver_sleep_pct",
+        "idle_driver_other_pct", "phase_colour_ms", "phase_transform_ms",
+        "phase_entropy_ms"}
+    assert not theirs & {"phase_motion_ms", "cavlc_low_tier_pct"}
     assert [m["name"] for m in cell.per_layer
             if "workloads" in m] == ["ack_p95_ms", "frames_seen"]
     gen = cells.module("sources", "blink", str(bench))
@@ -103,79 +109,60 @@ def test_a_later_pr_adds_a_cell_a_mix_a_configuration_and_a_metric(tmp_path):
         spec_m = cells.layer_metric_spec(name, str(bench))
         reader = cells.module("readers", spec_m["reader"], str(bench))
         assert reader.read(FakeRun, spec_m["args"]) == pytest.approx(want)
-    # and the cells that were there still load from the same checkout shape
-    shutil.copytree(os.path.join(ROOT, "benchmark", "traffic"),
-                    bench / "traffic", dirs_exist_ok=True)
-    shutil.copytree(os.path.join(ROOT, "benchmark", "configs"),
-                    bench / "configs", dirs_exist_ok=True)
-    assert cells.load_cell("h264-1080p60.scroll",
-                           root=str(root)).traffic_name == "scroll"
+    # and the cells that were there load from the same checkout, with the
+    # metrics they had and none of the later PR's
+    for w in SPEC["workloads"]:
+        there = cells.load_cell(w["name"], root=str(root))
+        here = cells.load_cell(w["name"])
+        assert there.traffic_name == here.traffic_name == "scroll"
+        assert there.per_layer == here.per_layer
+        assert there.end_to_end == here.end_to_end
+
+
+def test_the_accepted_entries_check_lets_the_count_grow_and_nothing_else():
+    """The accepted-entries check, handed a spec with one more per-layer
+    entry than the real file has, holds; handed one whose accepted entries
+    moved or whose appended entry changed, it fails."""
+    spec = json.loads(json.dumps(SPEC))
+    spec["per_layer"].append(dict(spec["per_layer"][-1], name="one_more"))
+    spec_checks.accepted_entries_are_untouched(spec)
+    swapped = json.loads(json.dumps(SPEC))
+    swapped["per_layer"][0], swapped["per_layer"][1] = \
+        swapped["per_layer"][1], swapped["per_layer"][0]
+    with pytest.raises(AssertionError):
+        spec_checks.accepted_entries_are_untouched(swapped)
+    gone = json.loads(json.dumps(SPEC))
+    gone["per_layer"] = [m for m in gone["per_layer"]
+                         if m["name"] != "cavlc_low_tier_pct"]
+    with pytest.raises(AssertionError):
+        spec_checks.accepted_entries_are_untouched(gone)
 
 
 # -- the contract's shape ----------------------------------------------------
 
 def test_top_level_keys_and_limits():
-    assert set(SPEC) == {"command", "paths", "run_seconds", "configs",
-                         "workloads", "end_to_end", "per_layer"}
-    assert isinstance(SPEC["run_seconds"], int) and 1 <= SPEC["run_seconds"] <= 51
-    assert 1 <= len(SPEC["paths"]) <= 16 and len(SPEC["command"]) <= 32
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
-    runs = 2 + 14 * 24
-    assert runs * (SPEC["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
+    spec_checks.top_level(SPEC, ROOT)
 
 
 @pytest.mark.parametrize("c", SPEC["configs"], ids=lambda c: c["name"])
 def test_config_entry(c):
-    assert set(c) == {"name", "source", "file", "reduced", "why"}
-    assert NAME.match(c["name"]) and len(c["reduced"]) <= 16
-    assert any(c["file"].startswith(p + "/") for p in SPEC["paths"])
-    body = json.load(open(os.path.join(ROOT, c["file"])))
-    assert body["source"] == c["source"] and body["reduced"] == c["reduced"]
-    assert all(k in body for k in c["reduced"])
-    for text in (c["source"], c["why"]):
-        assert 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
-    assert any(w["config"] == c["name"] for w in SPEC["workloads"])
+    spec_checks.config_entry(SPEC, c, ROOT)
 
 
 @pytest.mark.parametrize("w", SPEC["workloads"], ids=lambda w: w["name"])
 def test_workload_entry(w):
-    assert set(w) == {"name", "config", "traffic", "chips", "why"}
-    assert NAME.match(w["name"]) and NAME.match(w["traffic"])
-    assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
-    assert w["config"] in {c["name"] for c in SPEC["configs"]}
+    spec_checks.workload_entry(SPEC, w)
 
 
 def test_cells_are_distinct_and_few_take_four_chips():
-    pairs = [(w["config"], w["traffic"]) for w in SPEC["workloads"]]
-    assert len(set(pairs)) == len(pairs)
-    four = sum(1 for w in SPEC["workloads"] if w["chips"] == 4)
-    assert four <= max(1, len(SPEC["workloads"]) // 2)
+    spec_checks.cells_are_distinct_and_few_take_four_chips(SPEC)
 
 
 @pytest.mark.parametrize("m", SPEC["end_to_end"] + SPEC["per_layer"],
                          ids=lambda m: m["name"])
 def test_metric_entry(m):
-    e2e = m in SPEC["end_to_end"]
-    keys = {"name", "unit", "better", "source"} | (
-        {"bound"} if e2e else {"layer", "moves"})
-    assert keys <= set(m) <= keys | {"workloads"}
-    assert NAME.match(m["name"]) and UNIT.match(m["unit"])
-    assert m["better"] in ("lower", "higher")
-    assert m["source"] in (("host_clock", "device_trace") if e2e else (
-        "device_trace", "program_span", "program_counter", "host_clock"))
-    if e2e:
-        assert 0.01 <= m["bound"] <= 0.25
-    else:
-        assert m["moves"] in {e["name"] for e in SPEC["end_to_end"]}
-        assert "\n" not in m["layer"] and 1 <= len(m["layer"]) <= 200
-    for w in m.get("workloads", []):
-        assert w in {x["name"] for x in SPEC["workloads"]}
-    if m["name"].endswith("_roofline"):
-        assert m["unit"] == "%"
+    spec_checks.metric_entry(SPEC, m)
 
 
 def test_names_are_unique_and_setup_is_there():
-    names = [m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]]
-    assert len(set(names)) == len(names)
-    assert "setup_s" in names
-    assert len({w["name"] for w in SPEC["workloads"]}) == len(SPEC["workloads"])
+    spec_checks.names_are_unique_and_setup_is_there(SPEC)

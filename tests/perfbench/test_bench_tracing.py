@@ -10,70 +10,45 @@ from types import SimpleNamespace
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-sys.path.insert(0, ROOT)
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+sys.path[:0] = [ROOT, HERE]
 
+import spec_checks  # noqa: E402
 from benchmark import cells, trace  # noqa: E402
-from benchmark.readers import (clock_probe, idle_by_thread_state,  # noqa: E402
-                               stall_watch, trace_idle, trace_phase)
+from benchmark.readers import (clock_probe, encoder_share,  # noqa: E402
+                               idle_by_thread_state, stall_watch, trace_idle,
+                               trace_phase)
 from selkies_tpu.observability.tracing import FlightRecorder  # noqa: E402
 
-SPEC = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-BOTH = ["h264-1080p60.scroll", "jpeg-1080p60.scroll"]
-#: name -> (unit, source, layer, moves, reader, cells)
-NEW = {
-    "driver_submit_wait_p50_ms": ("ms", "program_span", "encode driver", "latency_p50_ms", "recorder_stage", BOTH),
-    "driver_pipe_wait_p50_ms": ("ms", "program_span", "encode driver", "latency_p50_ms", "recorder_stage", BOTH),
-    "driver_stage_p50_ms": ("ms", "program_span", "encode driver", "latency_p50_ms", "recorder_stage", BOTH),
-    "driver_in_device_p50_ms": ("ms", "program_span", "encode driver", "latency_p50_ms", "recorder_stage", BOTH),
-    "driver_pack_p50_ms": ("ms", "program_span", "encode driver", "latency_p50_ms", "recorder_stage", BOTH),
-    "server_harvest_wait_p50_ms": ("ms", "program_span", "server", "latency_p50_ms", "recorder_stage", BOTH),
-    "idle_driver_stage_pct": ("%", "device_trace", "device", "delivered_fps", "idle_by_thread_state", BOTH),
-    "idle_driver_pack_pct": ("%", "device_trace", "device", "delivered_fps", "idle_by_thread_state", BOTH),
-    "idle_driver_fetch_pct": ("%", "device_trace", "device", "delivered_fps", "idle_by_thread_state", BOTH),
-    "idle_driver_sleep_pct": ("%", "device_trace", "device", "delivered_fps", "idle_by_thread_state", BOTH),
-    "idle_driver_other_pct": ("%", "device_trace", "device", "delivered_fps", "idle_by_thread_state", BOTH),
-    "device_queue_delay_p50_ms": ("ms", "program_counter", "device", "latency_p50_ms", "clock_probe", BOTH),
-    "phase_colour_ms": ("ms", "device_trace", "device programs", "delivered_fps", "trace_phase", BOTH),
-    "phase_transform_ms": ("ms", "device_trace", "device programs", "delivered_fps", "trace_phase", BOTH),
-    "phase_entropy_ms": ("ms", "device_trace", "device programs", "delivered_fps", "trace_phase", BOTH),
-    "phase_motion_ms": ("ms", "device_trace", "device programs", "delivered_fps", "trace_phase", BOTH[:1]),
-    "interpreter_stall_max_ms": ("ms", "program_span", "server", "latency_p50_ms", "stall_watch", BOTH),
-    "loop_stall_max_ms": ("ms", "program_span", "server", "latency_p50_ms", "stall_watch", BOTH),
-}
-DEVICE_ONLY = {n for n, v in NEW.items()
-               if v[1] == "device_trace"} | {"device_queue_delay_p50_ms"}
+SPEC = spec_checks.read_spec(ROOT)
+NEW = {m["name"]: m for m in SPEC["per_layer"]
+       if m["name"] in spec_checks.APPENDED}
+DEVICE_ONLY = {n for n, m in NEW.items() if m["source"] == "device_trace"} | {
+    "device_queue_delay_p50_ms"}
 MS = 1e6
 
 
-@pytest.mark.parametrize("name", sorted(NEW))
+def listed(name, workload):
+    return any(x["name"] == name for x in cells.load_cell(workload).per_layer)
+
+
+@pytest.mark.parametrize("name", spec_checks.APPENDED)
 def test_new_metric_entry_and_its_file(name):
-    unit, source, layer, moves, reader, where = NEW[name]
-    m = next(x for x in SPEC["per_layer"] if x["name"] == name)
-    assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
-                                      "layer", "moves"}
-    assert (m["unit"], m["source"], m["layer"], m["moves"], m["better"]) == (
-        unit, source, layer, moves, "lower")
-    # appended after the accepted entries, which stay as they were
-    assert SPEC["per_layer"].index(m) >= 12
-    assert layer in {x["layer"] for x in SPEC["per_layer"][:12]}
-    for w in BOTH:
-        got = any(x["name"] == name for x in cells.load_cell(w).per_layer)
-        assert got == (w in where)
-    spec = cells.layer_metric_spec(name)
-    assert spec["reader"] == reader
-    assert callable(cells.module("readers", reader).read)
+    m = spec_checks.appended_entry(SPEC, name)
+    # a cell lists it where the entry names the cell, or names none
+    for w in SPEC["workloads"]:
+        assert listed(name, w["name"]) == (
+            w["name"] in m.get("workloads", [w["name"]]))
+    spec_checks.per_layer_metric_has_a_reader(name, ROOT)
 
 
 def test_the_accepted_entries_are_untouched():
-    names = [m["name"] for m in SPEC["per_layer"][:12]]
-    assert names == ["latency_p95_ms", "warmup_s", "compile_cache_misses",
-                     "server_send_p50_ms", "submit_drop_pct",
-                     "driver_dispatch_p50_ms", "driver_fetch_wait_p50_ms",
-                     "frames_in_flight", "step_device_ms", "me_kernel_ms",
-                     "me_kernel_roofline", "device_idle_pct"]
-    assert len(SPEC["per_layer"]) == 12 + len(NEW)
+    """What it protects: the twelve accepted entries first and in order,
+    every entry appended since after them and as it was added. What it no
+    longer blocks: the count, which a later PR raises (the same check runs on
+    a copy with entries appended, in ``test_bench_cells.py``)."""
+    spec_checks.accepted_entries_are_untouched(SPEC)
 
 
 # -- a hand-made traced run -----------------------------------------------
@@ -115,8 +90,9 @@ def traced_run(probes=10, wake_ms=(0.2, 0.0, 0.35, 0.1)):
 
 
 def shares(run):
-    spec = {n: cells.layer_metric_spec(n)["args"] for n in NEW
-            if NEW[n][4] == "idle_by_thread_state"}
+    spec = {n: cells.layer_metric_spec(n) for n in NEW}
+    spec = {n: v["args"] for n, v in spec.items()
+            if v["reader"] == "idle_by_thread_state"}
     return {n: idle_by_thread_state.read(run, a) for n, a in spec.items()}
 
 
@@ -168,6 +144,27 @@ def test_queue_delay_and_stalls_of_the_window():
     assert stall_watch.read(run, {"kind": "interpreter"}) == \
         pytest.approx(113.0)
     assert stall_watch.read(run, {"kind": "loop"}) == pytest.approx(45.0)
+
+
+@pytest.mark.parametrize("stats, want", [
+    ({"primary": {"cavlc_frames": 960, "cavlc_low_tier_frames": 958}},
+     100.0 * 958 / 960),
+    ({"d0": {"cavlc_frames": 10, "cavlc_low_tier_frames": 10},
+      "d1": {"cavlc_frames": 30, "cavlc_low_tier_frames": 0}}, 25.0),
+    ({"primary": {"cavlc_frames": 960}}, None),        # PR 25's program
+    ({"primary": {"cavlc_frames": 0, "cavlc_low_tier_frames": 0}}, None),
+    ({"d0": {"cavlc_frames": 10, "cavlc_low_tier_frames": 10},
+      "d1": {"frames": 30}}, None),
+    ({}, None),
+], ids=["share", "two-displays", "no-part", "whole-0", "one-lacks", "none"])
+def test_a_share_of_the_encoders_own_counts(stats, want):
+    spec = cells.layer_metric_spec("cavlc_low_tier_pct")
+    assert spec == {"reader": "encoder_share",
+                    "args": {"part": "cavlc_low_tier_frames",
+                             "whole": "cavlc_frames"}}
+    got = encoder_share.read(SimpleNamespace(encoder_stats=stats),
+                             spec["args"])
+    assert got == (want if want is None else pytest.approx(want))
 
 
 def test_an_operation_that_holds_others_counts_its_own_time_only():
@@ -251,7 +248,9 @@ def test_a_traced_rehearsal_prints_the_programs_own_and_no_device_metric(
     assert code == 0 and out["correct"] is True, out
     assert out["device"]["platform"] == "cpu"
     got = out["metrics"]
-    assert set(NEW) - DEVICE_ONLY - {"phase_motion_ms"} <= set(got)
+    here = {n for n in NEW if listed(n, "jpeg-1080p60.scroll")}
+    assert here - DEVICE_ONLY <= set(got)
+    assert "cavlc_low_tier_pct" not in got      # the H.264 cell's alone
     assert not (set(got) & DEVICE_ONLY)
     for name in ("driver_submit_wait_p50_ms", "driver_pipe_wait_p50_ms",
                  "driver_stage_p50_ms", "driver_in_device_p50_ms",
