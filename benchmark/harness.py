@@ -97,6 +97,10 @@ class Run:
         self.display_of_source: Dict[int, str] = {}
         self.gate_closed: Dict[str, int] = {}
         self.encoder_stats: Dict[str, Dict[str, Any]] = {}
+        #: the encoder the first display held as the window closed: what
+        #: served, solo driver or mesh lane facade (``trace_phase`` asks it
+        #: for its step's phases)
+        self.served_encoder: Any = None
         self.latencies_ms: List[float] = []  # of every change due in window
         self.metrics: Dict[str, float] = {}  # end to end, of the window
         self.loop_late_s = 0.0
@@ -273,7 +277,10 @@ class Run:
         watch.cancel()
         for did in self.displays:
             st = self.server.display_clients.get(did)
-            stats = getattr(getattr(st, "encoder", None), "stats", None)
+            encoder = getattr(st, "encoder", None)
+            if self.served_encoder is None:
+                self.served_encoder = encoder
+            stats = getattr(encoder, "stats", None)
             if stats is not None:
                 self.encoder_stats[did] = dict(stats())
 
@@ -317,11 +324,27 @@ class Run:
                            f"{self.gate_closed.get(did, 0) * 0.05:.2f} s")
         out.append(f"event loop: worst lateness of a 50 ms timer "
                    f"{self.loop_late_s * 1000:.1f} ms")
+        for kind, rows in self.stalls_by_kind().items():
+            a, b = max(rows, key=lambda r: r[1] - r[0])
+            out.append(f"stall watch: {len(rows)} stalls of kind {kind} began "
+                       f"in the window, {sum(b - a for a, b in rows):.3f} s "
+                       f"together, the longest {(b - a) * 1000:.1f} ms, "
+                       f"{a - w0:.2f} s into it")
         for did, st in self.encoder_stats.items():
             out.append(f"{did}: encoder says " + json.dumps(
                 {k: (round(v, 3) if isinstance(v, float) else v)
                  for k, v in st.items()
                  if isinstance(v, (int, float, str, bool))})[:400])
+        return out
+
+    def stalls_by_kind(self) -> Dict[str, List[Tuple[float, float]]]:
+        """The program's stall watch (PERF.md section 3): (t0, t1) of the
+        stalls that began in the window, by kind; {} where the program has
+        no stall watch or recorded none."""
+        get = getattr(self.server.recorder, "stalls", None)
+        out: Dict[str, List[Tuple[float, float]]] = {}
+        for kind, a, b in (get(*self.window) if get else ()):
+            out.setdefault(kind, []).append((a, b))
         return out
 
     async def drain(self) -> None:
@@ -501,7 +524,9 @@ class Run:
                "inflight_batches": [st.get("inflight_batches")
                                     for st in self.encoder_stats.values()],
                "band": band,
-               "refills_before_window": self.counters.get("regime_rolls")}
+               "refills_before_window": self.counters.get("regime_rolls"),
+               "stalled_s": {k: sum(b - a for a, b in v)
+                             for k, v in self.stalls_by_kind().items()}}
         out["regime"] = "not stated" if not band else (
             "expected" if band[0] <= in_flight <= band[1] else "other")
         return out

@@ -7,8 +7,18 @@ to run and could not, or ran code no state covers).
 The device's busy intervals come from the profiler trace on its own clock;
 the clock pairs (``clock_probe.align``) move them onto ``time.monotonic``,
 the thread track's. Over one thread's states and ``unmarked`` the shares
-add up to ``trace_idle``'s number. None without a trace, without a track
-for ``args.thread``, or without three probes in the traced seconds."""
+add up to ``trace_idle``'s number.
+
+Which thread drives the device is data: ``args.threads`` lists the names a
+program may give it (the solo driver's ``tpuenc-async``, a mesh lane's
+``mesh-encode``), and the first of them that left a track in the traced
+seconds is read; the run says which (an earlier line, and
+``run.driving_thread``), and says so where more than one left a track (the
+first listed is read then). ``args.thread``, one name, is read as a list of
+one. On several devices a share is the mean over the devices: one thread
+drives them all, and each device's idle intervals are laid over its track.
+None without a trace, without a track from any of the threads, or without
+three probes in the traced seconds."""
 
 from .. import trace
 from ..harness import say
@@ -47,27 +57,40 @@ def split(idle, track):
     return out
 
 
-def by_state(run, thread):
-    """{state or None: share of the traced window in %}, the mean over
-    devices; cached on the run."""
+def by_state(run, threads):
+    """{state or None: share of the traced window in %} of the first of
+    ``threads`` that left a track, the mean over devices; cached on the
+    run."""
+    threads = tuple(threads)
     cache = run.__dict__.setdefault("_idle_by_state", {})
-    if thread in cache:
-        return cache[thread]
-    cache[thread] = None
+    if threads in cache:
+        return cache[threads]
+    cache[threads] = None
     prof = run.profile
     track_of = getattr(run.server.recorder, "thread_track", None)
     offsets = clock_probe.align(run) if track_of is not None else None
     if prof is None or not offsets:
         return None
     w0, w1 = prof.window()
+    first = min(offsets.values())
+    left = [t for t in threads
+            if track_of(t, first + w0 / 1e9, first + w1 / 1e9)]
+    if not left:
+        say(f"thread track: nothing from {' or '.join(map(repr, threads))} "
+            f"in the traced seconds")
+        return None
+    thread = run.driving_thread = left[0]
+    say(f"thread track: the device's driver is read from {thread!r}"
+        + (f"; {', '.join(map(repr, left[1:]))} left a track too (the "
+           f"first listed is read)" if len(left) > 1 else ""))
     total = {}
     for dev, off in offsets.items():
         idle = [(off + a / 1e9, off + b / 1e9)
                 for a, b in idle_intervals(prof, dev)]
         track = track_of(thread, off + w0 / 1e9, off + w1 / 1e9)
         if not track:
-            say(f"thread track: nothing from {thread!r} in the traced "
-                f"seconds")
+            say(f"thread track: nothing from {thread!r} in device {dev}'s "
+                f"traced seconds")
             return None
         part = split(idle, track)
         whole = split([(off + w0 / 1e9, off + w1 / 1e9)], track)
@@ -88,17 +111,17 @@ def by_state(run, thread):
                     sorted(inside.items(), key=lambda kv: -kv[1])
                     if v > 5e-6))
     window = (w1 - w0) / 1e9 * len(offsets)
-    cache[thread] = {k: 100.0 * v / window for k, v in total.items()}
+    cache[threads] = {k: 100.0 * v / window for k, v in total.items()}
     say(f"idle by state of {thread}: " + ", ".join(
         f"{k or 'no state'} {v:.2f}%" for k, v in sorted(
-            cache[thread].items(), key=lambda kv: -kv[1])))
-    return cache[thread]
+            cache[threads].items(), key=lambda kv: -kv[1])))
+    return cache[threads]
 
 
 def read(run, args):
     if run.profile is None:
         return None
-    shares = by_state(run, args["thread"])
+    shares = by_state(run, args.get("threads") or [args["thread"]])
     if shares is None:
         return None
     if args.get("unmarked"):

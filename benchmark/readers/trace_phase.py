@@ -5,11 +5,16 @@ phase of the step (``args.phase``: ``colour``, ``damage``, ``motion``,
 The trace names operations by their HLO names (``fusion.24``); which phase
 an operation belongs to comes from the program itself, which wraps its
 phases in ``jax.named_scope`` and gives, on demand, {operation: phase} for
-the step it serves (``selkies_tpu/observability/device_phases.py``: the
-step is lowered and loaded from the compile cache, seconds). Operations are
-counted where they ran inside an execution of ``step_program``; an
-operation that holds others (a loop) counts only its own time. None without
-a trace, and where the program names no phases."""
+the step an encoder serves (``selkies_tpu/observability/device_phases.py``:
+the step is lowered and loaded from the compile cache, seconds). The
+encoder asked is the one that served: what the first display held as the
+window closed (``run.served_encoder``), a solo driver or a mesh lane's
+facade alike; nothing is built here. It is asked once the server has
+stopped and ``memory_peak_bytes`` is read: ``lower_step`` reads shapes only.
+Operations are counted where they ran inside an execution of
+``step_program``; an operation that holds others (a loop) counts only its
+own time. None without a trace, and where the served encoder offers no
+``lower_step`` or the program names no phases."""
 
 import bisect
 import re
@@ -22,14 +27,8 @@ def _phase_map(run):
         from selkies_tpu.observability import device_phases
     except ImportError:
         return None
-    server = run.server
-    enc = server.encoder_factory(run.width, run.height, server.settings)
-    try:
-        return device_phases.step_phases(enc)
-    finally:
-        close = getattr(enc, "close", None)
-        if close is not None:
-            close()
+    served = getattr(run, "served_encoder", None)
+    return None if served is None else device_phases.step_phases(served)
 
 
 def self_times(ops):
@@ -69,7 +68,9 @@ def by_phase(run):
         return None
     phases = _phase_map(run)
     if not phases:
-        say("device phases: the program names none for its step")
+        say("device phases: the encoder that served "
+            f"({type(getattr(run, 'served_encoder', None)).__name__}) names "
+            "none for its step")
         return None
     n_ops = 0
     step_ns = 0.0

@@ -35,18 +35,26 @@ APPENDED = ["driver_submit_wait_p50_ms", "driver_pipe_wait_p50_ms",
             "phase_motion_ms", "interpreter_stall_max_ms",
             "loop_stall_max_ms", "cavlc_low_tier_pct"]
 
+#: the five ways the idle split reads its thread's track (the ``args`` of the
+#: five ``idle_driver_*_pct`` files, less which threads): together they
+#: cover every idle second once
+IDLE_SPLIT = ({"states": ["stage", "dispatch"]}, {"states": ["pack", "emit"]},
+              {"states": ["fetch_wait"]}, {"states": ["sleep"]},
+              {"unmarked": True})
+
 
 def read_spec(root):
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         return json.load(f)
 
 
-def scratch_checkout(tmp_path):
-    """A checkout in ``tmp_path`` that holds the real data files, as a
-    later PR finds them: it adds files and a ``BENCHMARK.json``."""
+def scratch_checkout(tmp_path, real_root=ROOT):
+    """A checkout in ``tmp_path`` that holds the real data files (those of
+    the checkout at ``real_root``), as a later PR finds them: it adds files
+    and a ``BENCHMARK.json``."""
     root = tmp_path / "checkout"
     for sub in ("configs", "traffic", "layer_metrics"):
-        shutil.copytree(os.path.join(ROOT, "benchmark", sub),
+        shutil.copytree(os.path.join(real_root, "benchmark", sub),
                         root / "benchmark" / sub)
     return root
 

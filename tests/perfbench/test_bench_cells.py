@@ -29,11 +29,23 @@ def test_every_per_layer_metric_has_a_reader_of_its_own(m):
     spec_checks.per_layer_metric_has_a_reader(m, ROOT)
 
 
-def test_a_later_pr_adds_a_cell_a_mix_a_configuration_and_a_metric(tmp_path):
-    """Only new files and new entries: nothing that is there is edited. The
-    checkout is the real ``BENCHMARK.json`` and the real data files with a
-    later PR's appended, and every check tier 1 makes of the real file is
-    made of it: one that pins a count fails here too."""
+#: what reads the device-driving path: the solo driver's queues, the idle
+#: split by its thread's states, the step's phases. The entries name no cell
+DRIVING_PATH = {
+    "driver_submit_wait_p50_ms", "driver_pipe_wait_p50_ms",
+    "driver_stage_p50_ms", "driver_in_device_p50_ms",
+    "idle_driver_stage_pct", "idle_driver_pack_pct", "idle_driver_fetch_pct",
+    "idle_driver_sleep_pct", "idle_driver_other_pct", "phase_colour_ms",
+    "phase_transform_ms", "phase_entropy_ms"}
+LANE_CELL = "jpeg-4x720p30.lane4-blink"
+
+
+def a_later_prs_checkout(tmp_path, solo):
+    """The real ``BENCHMARK.json`` and data files with a later PR's files
+    and entries appended: a solo cell on a new configuration with a new mix
+    and two metrics of its own (where ``solo``), and a ``chips: 4``
+    lane-shaped cell on a configuration of its own with one metric on a new
+    layer ``lanes``. Returns (root, spec, the files as they were)."""
     root = spec_checks.scratch_checkout(tmp_path)
     bench = root / "benchmark"
     for sub in ("sources", "readers"):
@@ -44,37 +56,107 @@ def test_a_later_pr_adds_a_cell_a_mix_a_configuration_and_a_metric(tmp_path):
         ROOT, "benchmark", "configs", "ws-1080p60-jpeg.json")))
     conf.update(name="ws-720p30-jpeg", source="somewhere public",
                 width=1280, height=720, framerate=30)
-    (bench / "configs" / "ws-720p30-jpeg.json").write_text(json.dumps(conf))
     (bench / "traffic" / "blink.json").write_text(json.dumps({
         "generator": "blink", "params": {"hz": 2}, "check_frames": 4}))
     (bench / "sources" / "blink.py").write_text(
         "from benchmark.sources.desktop import ClockedSource\n"
         "class Source(ClockedSource):\n"
         "    def index_at(self, t):\n        return int(t * 2)\n")
-    (bench / "layer_metrics" / "ack_p95_ms.json").write_text(json.dumps({
-        "reader": "recorder_stage",
-        "args": {"stages": ["ack"], "percentile": 95}}))
-    (bench / "layer_metrics" / "frames_seen.json").write_text(json.dumps({
-        "reader": "frames_seen", "args": {}}))
-    (bench / "readers" / "frames_seen.py").write_text(
-        "def read(run, args):\n    return float(len(run.spans)) or None\n")
     spec = json.loads(json.dumps(SPEC))
+    if solo:
+        (bench / "configs" / "ws-720p30-jpeg.json").write_text(
+            json.dumps(conf))
+        (bench / "layer_metrics" / "ack_p95_ms.json").write_text(json.dumps({
+            "reader": "recorder_stage",
+            "args": {"stages": ["ack"], "percentile": 95}}))
+        (bench / "layer_metrics" / "frames_seen.json").write_text(json.dumps({
+            "reader": "frames_seen", "args": {}}))
+        (bench / "readers" / "frames_seen.py").write_text(
+            "def read(run, args):\n    return float(len(run.spans)) or None\n")
+        spec["configs"].append({
+            "name": "ws-720p30-jpeg", "source": "somewhere public",
+            "file": "benchmark/configs/ws-720p30-jpeg.json", "reduced": [],
+            "why": "a smaller screen"})
+        spec["workloads"].append({
+            "name": "jpeg-720p30.blink", "config": "ws-720p30-jpeg",
+            "traffic": "blink", "chips": 1, "why": "a cursor blinks"})
+        for name, unit in (("ack_p95_ms", "ms"), ("frames_seen", "count")):
+            spec["per_layer"].append({
+                "name": name, "unit": unit, "better": "lower",
+                "source": "program_span", "layer": "server",
+                "moves": "latency_p50_ms",
+                "workloads": ["jpeg-720p30.blink"]})
+    four = dict(conf, name="ws-4x720p30-jpeg-lane4",
+                source="somewhere else public",
+                displays=["d0", "d1", "d2", "d3"],
+                step_program="local_step")
+    four["env"] = dict(conf["env"], SELKIES_TPU_MESH="session:4")
+    (bench / "configs" / "ws-4x720p30-jpeg-lane4.json").write_text(
+        json.dumps(four))
+    (bench / "layer_metrics" / "lane_tick_p95_ms.json").write_text(
+        json.dumps({"reader": "recorder_stage",
+                    "args": {"stages": ["lane_tick"], "percentile": 95}}))
     spec["configs"].append({
-        "name": "ws-720p30-jpeg", "source": "somewhere public",
-        "file": "benchmark/configs/ws-720p30-jpeg.json", "reduced": [],
-        "why": "a smaller screen"})
+        "name": four["name"], "source": four["source"], "reduced": [],
+        "file": "benchmark/configs/ws-4x720p30-jpeg-lane4.json",
+        "why": "four sessions on one mesh lane of a four-chip host"})
     spec["workloads"].append({
-        "name": "jpeg-720p30.blink", "config": "ws-720p30-jpeg",
-        "traffic": "blink", "chips": 1, "why": "a cursor blinks"})
-    for name, reader_unit in (("ack_p95_ms", "ms"), ("frames_seen", "count")):
-        spec["per_layer"].append({
-            "name": name, "unit": reader_unit, "better": "lower",
-            "source": "program_span", "layer": "server",
-            "moves": "latency_p50_ms", "workloads": ["jpeg-720p30.blink"]})
+        "name": LANE_CELL, "config": four["name"], "traffic": "blink",
+        "chips": 4, "why": "one SPMD step over four chips each tick"})
+    spec["per_layer"].append({
+        "name": "lane_tick_p95_ms", "unit": "ms", "better": "lower",
+        "source": "program_span", "layer": "lanes",
+        "moves": "latency_p50_ms", "workloads": [LANE_CELL]})
     (root / "BENCHMARK.json").write_text(json.dumps(spec, indent=1))
+    return root, spec, before
+
+
+@pytest.mark.parametrize("solo", [False, True],
+                         ids=["one-more-cell", "two-more-cells"])
+def test_a_later_pr_adds_a_four_chip_lane_cell(tmp_path, solo):
+    """A ``chips: 4`` lane-shaped cell beside the cells that are there, and
+    beside a later solo cell too: every check tier 1 makes of the real file
+    holds (with the two cells PR 24 was accepted with: one four-chip cell of
+    three and of four). The lane cell inherits the twelve entries of the
+    device-driving path, which name no cell, and its one metric of its own
+    sits on the layer ``lanes``, which none of those entries names."""
+    root, spec, before = a_later_prs_checkout(tmp_path, solo)
+    assert len(spec["workloads"]) == len(SPEC["workloads"]) + (
+        2 if solo else 1)
+    assert [w["chips"] for w in spec["workloads"]].count(4) == \
+        [w["chips"] for w in SPEC["workloads"]].count(4) + 1
+    spec_checks.whole(spec_checks.read_spec(str(root)), str(root))
+    for rel, was in before.items():
+        assert (root / rel).read_bytes() == was, rel
+    cell = cells.load_cell(LANE_CELL, root=str(root))
+    assert cell.chips == 4 and len(cell.config["displays"]) == 4
+    theirs = {m["name"] for m in cell.per_layer}
+    assert theirs >= DRIVING_PATH
+    assert not theirs & {"phase_motion_ms", "cavlc_low_tier_pct",
+                         "me_kernel_ms", "ack_p95_ms", "frames_seen"}
+    assert [(m["name"], m["layer"]) for m in cell.per_layer
+            if "workloads" in m] == [("lane_tick_p95_ms", "lanes")]
+    assert "lanes" not in {m["layer"] for m in cell.per_layer
+                           if "workloads" not in m}
+    # and no cell that was there, or that the same PR adds, takes it
+    for w in spec["workloads"]:
+        if w["name"] != LANE_CELL:
+            other = cells.load_cell(w["name"], root=str(root))
+            assert "lane_tick_p95_ms" not in {
+                m["name"] for m in other.per_layer}
+
+
+def test_a_later_pr_adds_a_cell_a_mix_a_configuration_and_a_metric(tmp_path):
+    """Only new files and new entries: nothing that is there is edited. The
+    checkout is the real ``BENCHMARK.json`` and the real data files with a
+    later PR's appended (a solo cell, and a ``chips: 4`` lane-shaped cell
+    beside it), and every check tier 1 makes of the real file is made of it:
+    one that pins a count fails here too."""
+    root, spec, before = a_later_prs_checkout(tmp_path, solo=True)
+    bench = root / "benchmark"
 
     spec_checks.whole(spec_checks.read_spec(str(root)), str(root))
-    assert len(spec["per_layer"]) == len(SPEC["per_layer"]) + 2
+    assert len(spec["per_layer"]) == len(SPEC["per_layer"]) + 3
     for rel, was in before.items():
         assert (root / rel).read_bytes() == was, rel
 
@@ -84,14 +166,9 @@ def test_a_later_pr_adds_a_cell_a_mix_a_configuration_and_a_metric(tmp_path):
     # the step's phases give: those entries name no cell, so the later PR
     # edits none of them; only what one codec alone has names its cell
     theirs = {m["name"] for m in cell.per_layer}
-    assert theirs >= {
-        "driver_submit_wait_p50_ms", "driver_pipe_wait_p50_ms",
-        "driver_stage_p50_ms", "driver_in_device_p50_ms",
-        "idle_driver_stage_pct", "idle_driver_pack_pct",
-        "idle_driver_fetch_pct", "idle_driver_sleep_pct",
-        "idle_driver_other_pct", "phase_colour_ms", "phase_transform_ms",
-        "phase_entropy_ms"}
-    assert not theirs & {"phase_motion_ms", "cavlc_low_tier_pct"}
+    assert theirs >= DRIVING_PATH
+    assert not theirs & {"phase_motion_ms", "cavlc_low_tier_pct",
+                         "lane_tick_p95_ms"}
     assert [m["name"] for m in cell.per_layer
             if "workloads" in m] == ["ack_p95_ms", "frames_seen"]
     gen = cells.module("sources", "blink", str(bench))

@@ -49,6 +49,7 @@ def test_h264_rehearsal_reports_end_to_end_the_tail_and_the_regime(capsys):
         out["metrics"]["latency_p50_ms"]["value"]
         * out["metrics"]["delivered_fps"]["value"] / 1000.0)
     assert w["regime"] in ("expected", "other")
+    assert all(v > 0 for v in w["stalled_s"].values())     # {} on a quiet host
     assert "latency p95 over the same changes" in err and "regime:" in err
     assert list(out)[-1] == "compared"
     assert out["compared"]["unreadable"] == {"value": 0.0, "limit": 0}

@@ -89,6 +89,17 @@ def traced_run(probes=10, wake_ms=(0.2, 0.0, 0.35, 0.1)):
                              config={"step_program": "step"}))
 
 
+def no_encoder_is_built(*_a, **_kw):
+    raise AssertionError("the phase reader built an encoder of its own")
+
+
+def five_shares(run, which):
+    """The idle split's five shares of the track of ``which`` (``threads``
+    or ``thread``)."""
+    return [idle_by_thread_state.read(run, dict(which, **a))
+            for a in spec_checks.IDLE_SPLIT]
+
+
 def shares(run):
     spec = {n: cells.layer_metric_spec(n) for n in NEW}
     spec = {n: v["args"] for n, v in spec.items()
@@ -144,6 +155,14 @@ def test_queue_delay_and_stalls_of_the_window():
     assert stall_watch.read(run, {"kind": "interpreter"}) == \
         pytest.approx(113.0)
     assert stall_watch.read(run, {"kind": "loop"}) == pytest.approx(45.0)
+    # every run, traced or not, says what stood still in its window
+    from benchmark.harness import Run
+
+    assert Run.stalls_by_kind(run) == {
+        "interpreter": [(110.0, 110.06), (120.0, 120.113)],
+        "loop": [(111.0, 111.045)]}
+    run.server.recorder = object()             # a program with no stall watch
+    assert Run.stalls_by_kind(run) == {}
 
 
 @pytest.mark.parametrize("stats, want", [
@@ -187,14 +206,9 @@ def test_phases_of_a_small_step_add_up_to_the_programs_time(capsys):
         return z, y.sum()
 
     class Encoder:
-        closed = False
-
         def lower_step(self):
             return jax.jit(step).lower(
                 jax.ShapeDtypeStruct((256,), jnp.float32))
-
-        def close(self):
-            Encoder.closed = True
 
     from selkies_tpu.observability import device_phases
 
@@ -212,12 +226,11 @@ def test_phases_of_a_small_step_add_up_to_the_programs_time(capsys):
     run = traced_run()
     run.profile = trace.Profile(modules={0: mods}, ops={0: ops},
                                 host=[(trace.WINDOW_SPAN, 0.0, 2 * MS)])
-    run.width, run.height = 256, 144
-    run.server = SimpleNamespace(
-        recorder=FlightRecorder(), settings=None,
-        encoder_factory=lambda w, h, s: Encoder())
+    # the reader asks the encoder that served, wrappers and all, and builds
+    # none: this server has no factory to build one from
+    run.served_encoder = SimpleNamespace(pipe=SimpleNamespace(base=Encoder()))
+    run.server = SimpleNamespace(recorder=FlightRecorder())
     ms = trace_phase.by_phase(run)
-    assert Encoder.closed
     assert ms["_step"] == pytest.approx(len(names) * 1e-3)
     parts = {k: v for k, v in ms.items() if k != "_step"}
     assert sum(parts.values()) == pytest.approx(ms["_step"])
@@ -227,12 +240,160 @@ def test_phases_of_a_small_step_add_up_to_the_programs_time(capsys):
     assert trace_phase.read(run, {"phase": "motion"}) == 0.0
     assert trace_phase.read(run, {"phase": "colour"}) == parts["colour"]
     assert "device phases over 2 executions of step" in capsys.readouterr().err
-    # a program that names no phases, a trace without the step: None
+    # an encoder that offers no ``lower_step`` (a mesh lane's facade
+    # today), a run that kept none: None, and nothing is built
+    for served in (SimpleNamespace(sid=0, closed=False), None):
+        run = traced_run()
+        run.served_encoder = served
+        run.server.encoder_factory = no_encoder_is_built
+        assert trace_phase.read(run, {"phase": "colour"}) is None
+
+
+def short_run(codec):
+    """(the encoder of ``codec`` as the server's factory builds it, after a
+    short run and its close; the factory)."""
+    import numpy as np
+    from selkies_tpu.server.data_server import default_encoder_factory
+    from selkies_tpu.settings import Settings
+
+    settings = Settings(argv=[], env={
+        "SELKIES_ENCODER": codec, "SELKIES_TPU_STRIPE_HEIGHT": "16",
+        "SELKIES_TPU_INTERPRET": "true"})
+
+    def factory():
+        return default_encoder_factory(64, 48, settings)
+
+    served = factory()
+    rng = np.random.default_rng(28)
+    try:
+        for _ in range(3):
+            served.try_submit(rng.integers(0, 255, (48, 64, 3), np.uint8))
+        assert len(served.flush(timeout=300.0)) >= 1
+    finally:
+        served.close()
+    return served, factory
+
+
+@pytest.mark.parametrize("codec", ["jpeg", "x264enc-striped"])
+def test_the_phase_map_of_the_encoder_that_served_is_a_fresh_ones(codec):
+    from selkies_tpu.encoder.async_driver import AsyncEncodeDriver
+    from selkies_tpu.observability import device_phases
+
+    served, factory = short_run(codec)
+    assert isinstance(served, AsyncEncodeDriver)
     run = traced_run()
-    run.server.encoder_factory = lambda w, h, s: object()
-    run.width = run.height = 0
-    run.server.settings = None
-    assert trace_phase.read(run, {"phase": "colour"}) is None
+    run.served_encoder = served
+    run.server.encoder_factory = no_encoder_is_built
+    theirs = trace_phase._phase_map(run)
+    fresh = factory()
+    try:
+        assert device_phases.base_encoder(fresh) is not \
+            device_phases.base_encoder(served)
+        want = device_phases.step_phases(fresh)
+    finally:
+        fresh.close()
+    assert theirs and theirs == want
+    wanted = {"colour", "transform", "entropy"} | (
+        {"motion"} if codec != "jpeg" else set())
+    assert wanted <= set(theirs.values())
+
+
+def test_a_facade_without_lower_step_reads_no_phases_and_builds_nothing(
+        capsys):
+    """A mesh lane's facade as it is today: no ``lower_step`` behind it. The
+    three phase readers return None without raising, and no solo encoder is
+    built to stand in for the lane's."""
+    from selkies_tpu.parallel.coordinator import MeshSessionFacade
+
+    run = traced_run()
+    run.cell.config["step_program"] = "step"
+    run.served_encoder = MeshSessionFacade(SimpleNamespace(), 0)
+    run.server.encoder_factory = no_encoder_is_built
+    assert trace_phase._phase_map(run) is None
+    for phase in ("colour", "transform", "entropy"):
+        spec = cells.layer_metric_spec(f"phase_{phase}_ms")
+        assert cells.module("readers", spec["reader"]).read(
+            run, spec["args"]) is None
+    assert "MeshSessionFacade" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("args, tracks, want, says", [
+    ({"threads": ["tpuenc-async", "mesh-encode"]}, ["tpuenc-async"],
+     "tpuenc-async", None),
+    ({"threads": ["tpuenc-async", "mesh-encode"]}, ["mesh-encode"],
+     "mesh-encode", None),
+    ({"thread": "tpuenc-async"}, ["tpuenc-async"], "tpuenc-async", None),
+    ({"thread": "mesh-encode"}, ["tpuenc-async"], None, "nothing from"),
+    ({"threads": ["tpuenc-async", "mesh-encode"]},
+     ["mesh-encode", "tpuenc-async"], "tpuenc-async", "left a track too"),
+    ({"threads": ["mesh-encode", "tpuenc-async"]},
+     ["mesh-encode", "tpuenc-async"], "mesh-encode", "left a track too"),
+    ({"threads": ["tpuenc-async", "mesh-encode"]}, ["device-probe-0"],
+     None, "nothing from"),
+], ids=["solo-of-two", "lane-of-two", "one-name", "one-name-absent",
+        "both-first-listed", "both-other-order", "neither"])
+def test_the_driving_thread_is_named_by_data(capsys, args, tracks, want,
+                                              says):
+    """``threads`` lists the names the device's driver may have; the first
+    that left a track in the traced seconds is read and the run says which;
+    the old one-name ``thread`` is still read; two tracks are said."""
+    run = traced_run()
+    rows = run.server.recorder.thread_track("tpuenc-async")
+    rec = run.server.recorder = FlightRecorder()
+    for name in tracks:
+        for _th, state, a, b in rows:
+            # the second thread sleeps where the first works: its shares
+            # would differ, so the test sees whose were read
+            rec.thread_state(name, "sleep" if name != tracks[0] else state,
+                             a, b)
+    for j in range(10):
+        ready = BEGAN + (145 + 300 * j) * MS / 1e9
+        rec.clock_pair(0, ready - 0.004, ready)
+    capsys.readouterr()
+    got = idle_by_thread_state.read(run, dict(args, states=["stage"]))
+    err = capsys.readouterr().err
+    if want is None:
+        assert got is None and says in err
+        return
+    assert run.driving_thread == want
+    assert f"is read from {want!r}" in err
+    assert (says in err) if says else ("left a track too" not in err)
+    first = want == tracks[0]
+    assert got == pytest.approx(10.0 if first else 0.0, abs=1e-6)
+    assert idle_by_thread_state.read(
+        run, dict(args, states=["sleep"])) == pytest.approx(
+        10.0 if first else 30.0, abs=1e-6)
+    # the five shares of whichever thread was read add up to the idle share
+    assert sum(five_shares(run, args)) == pytest.approx(
+        trace_idle.read(run, {}), abs=1e-6)
+
+
+def test_the_idle_split_on_four_devices_is_the_mean_over_devices():
+    """A lane: one thread drives four devices. Each device's idle is laid
+    over the one track, and a share is the mean of the four."""
+    run = traced_run()
+    one = run.profile.modules[0]
+    probes = [e for e in one if e[0].startswith("jit_selkies_clock_probe")]
+    # devices 1-3 run the step for 80 ms where device 0 runs it for 60
+    longer = [(n, s, 80 * MS) if n.startswith("jit_step") else (n, s, d)
+              for n, s, d in one]
+    run.profile = trace.Profile(
+        modules={0: one, 1: longer, 2: longer, 3: longer},
+        ops={d: [] for d in range(4)}, host=run.profile.host)
+    rec = run.server.recorder
+    for dev in (1, 2, 3):
+        for _n, s, d in probes:
+            ready = BEGAN + (s + d) / 1e9
+            rec.clock_pair(dev, ready - 0.004, ready)
+    args = {"threads": ["mesh-encode", "tpuenc-async"]}
+    sleep = idle_by_thread_state.read(run, dict(args, states=["sleep"]))
+    stage = idle_by_thread_state.read(run, dict(args, states=["stage"]))
+    # sleep covers ms 60-70 after a step's start, stage 70-80: a device
+    # busy for 80 ms idles in neither
+    assert sleep == pytest.approx(10.0 / 4, abs=1e-6)
+    assert stage == pytest.approx(10.0 / 4, abs=1e-6)
+    assert sum(five_shares(run, args)) == pytest.approx(
+        trace_idle.read(run, {}), abs=1e-6)
 
 
 def test_a_traced_rehearsal_prints_the_programs_own_and_no_device_metric(
