@@ -309,11 +309,14 @@ class AsyncEncodeDriver:
             self._cond.notify_all()
         self._track("emit", t_emit0, time.monotonic())
 
-    def _harvest(self, flush_partial: bool) -> bool:
-        """One non-blocking harvest pass; True if anything completed."""
+    def _harvest(self, flush_partial: bool, wait: bool = False) -> bool:
+        """One harvest pass; True if anything completed. ``wait`` blocks
+        until the pipe's oldest frame is in (the pipe marks that
+        ``fetch_wait``): only ever asked with captures queued behind a
+        full pipe, when nothing else is left for this thread to do."""
         if self.faults is not None:
             self.faults.maybe_hang_sync(FETCH_HANG_POINT)
-        results = self.pipe.poll(flush_partial=flush_partial)
+        results = self.pipe.poll(flush_partial=flush_partial, wait=wait)
         self._emit(results)
         return bool(results)
 
@@ -327,26 +330,21 @@ class AsyncEncodeDriver:
             # single-owner discipline makes it race-free
             self._cleanup()
 
-    def _run_pass(self) -> bool:
-        """One driver pass; False when the driver is stopping."""
-        with self._cond:
-            if self._stop:
-                return False
-            work = list(self._in_q)
-            self._in_q.clear()
-            flush_want = self._flush_req
-            if work:
-                t_taken = time.monotonic()
-                for seq, _frame, t_accepted in work:
-                    self._waits[seq] = (t_accepted, t_taken)
-        # 1. dispatch every queued frame. pipe.submit may block
-        # harvesting the OLDEST batch when the pipe is full — exactly
-        # the overlap we want: batches 2..N keep computing while the
-        # driver waits on batch 1's fetch. An erroring frame costs
-        # ITSELF (counted + reported), never the rest of the pass; a
-        # frame the pipe never accepted gets no seq mapping, so its
-        # loss cannot shift later results onto wrong seqs.
-        for seq, frame, _t in work:
+    def _feed(self) -> None:
+        """Dispatch queued captures, oldest first, one for each free slot
+        of the pipe. A capture leaves ``_in_q`` only when the pipe has
+        room for it: what cannot be staged yet waits where ``try_submit``
+        counts it (and refuses the next), not in a list of this thread's
+        behind a submit that blocks. An erroring frame costs ITSELF
+        (counted + reported), never the rest of the pass; a frame the
+        pipe never accepted gets no seq mapping, so its loss cannot shift
+        later results onto wrong seqs."""
+        while self.pipe.has_room:
+            with self._cond:
+                if self._stop or not self._in_q:
+                    return
+                seq, frame, t_accepted = self._in_q.popleft()
+                self._waits[seq] = (t_accepted, time.monotonic())
             try:
                 pipe_seq = self.pipe.submit(frame)
             except Exception as exc:
@@ -358,24 +356,40 @@ class AsyncEncodeDriver:
                         self._seq_map[pipe_seq] = seq
                 else:
                     self._waits.pop(seq, None)
+
+    def _run_pass(self) -> bool:
+        """One driver pass; False when the driver is stopping."""
+        with self._cond:
+            if self._stop:
+                return False
+            flush_want = self._flush_req
+        flushing = flush_want > self._flush_ack
+        # 1. feed the device first: every free slot of the pipe takes
+        # the oldest queued capture
+        self._feed()
         try:
-            # 2. harvest whatever is ready (never blocks)
+            # 2. harvest whatever is ready. With captures still queued
+            # the pipe is full: block for its oldest frame, whose slot
+            # the next pass hands on; its successors keep the device busy
+            # while this thread packs it.
             with self._cond:
-                idle = not self._in_q
-            self._harvest(flush_partial=(
-                idle and self.flush_partial_when_idle))
+                backlog = bool(self._in_q)
+            self._harvest(
+                flush_partial=(not backlog and self.flush_partial_when_idle),
+                wait=backlog and not self.pipe.has_room)
             self._error_streak = 0
         except Exception as exc:
             # harvest failure: completed frames stay queued in the
             # pipe's ready list (surfacing next pass); the lost frame's
             # stale seq mapping is pruned at the next emit
             self._count_error(exc)
-        # 3. explicit flush: drain the pipe COMPLETELY — a mid-drain
+        # 3. explicit flush, once every capture accepted before it has
+        # been dispatched: drain the pipe COMPLETELY — a mid-drain
         # error costs its frame (counted) and the drain resumes, so
         # the ack below never strands unharvested frames behind a
         # raising one. Each failed drain removes at least the raising
         # frame, so this terminates.
-        if flush_want > self._flush_ack:
+        if flushing and not backlog:
             while True:
                 try:
                     self._emit(self.pipe.flush())
@@ -388,9 +402,10 @@ class AsyncEncodeDriver:
                         break
         with self._cond:
             self._stats_cache = dict(self.pipe.stats())
-            if flush_want > self._flush_ack:
-                # flush() returns once everything submitted either
-                # completed or was accounted as an error — never strands
+            if flushing and not backlog:
+                # flush() returns once everything submitted before it
+                # either completed or was accounted as an error — never
+                # strands (a capture accepted since is a later flush's)
                 self._flush_ack = flush_want
                 self._cond.notify_all()
             if self._stop:

@@ -22,6 +22,7 @@ from __future__ import annotations
 import logging
 import os
 import time
+from collections import deque
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
@@ -262,6 +263,16 @@ class H264StripeEncoder:
     selkies.py:2937, wire demux selkies-core.js 0x00 path).
     """
 
+    #: how many harvested frames the fetch-prefix guess remembers. A
+    #: frame's bits follow how far the content moved since the frame
+    #: before (a scroll past the ±search range codes residual for every
+    #: block), so sizes alternate, and a guess from the last frame alone
+    #: undershot one frame in four of a 1080p scroll; each undershoot's
+    #: re-read runs the pipe dry, and the burst that refills it makes the
+    #: next big frame (PERF.md, PR 29). Two seconds of frames: a prefix
+    #: too long costs microseconds, one too short a hundred milliseconds.
+    PREFIX_MEMORY_FRAMES = 64
+
     def __init__(self, width: int, height: int, *, stripe_height: int = 64,
                  qp: int = 26, paint_over_qp: int = 18,
                  paint_over_trigger_frames: int = 15,
@@ -335,23 +346,32 @@ class H264StripeEncoder:
         if entropy not in ("device", "host"):
             raise ValueError(f"entropy must be device|host, got {entropy!r}")
         self.entropy = entropy
-        #: fetch tiers: _batch_prefix must be a STABLE static prefix —
-        #: an adaptive one recompiles the (expensive) batched program on
-        #: every bucket move; undershoot falls back to the exact flat16
-        #: rows and grows it (bounded recompiles). _prefix_small serves
-        #: static/quiet content — shipping the worst-case head every
-        #: frame would cost 10-30x the D2H bytes of an idle desktop.
+        #: fetch prefix: how much of the packed buffer a P frame brings to
+        #: the host in the transfer started at dispatch. harvest keeps
+        #: _sparse_guess at the bucket of 1.5x the largest needed bytes
+        #: among the last PREFIX_MEMORY_FRAMES frames (_recent_needed),
+        #: and _choose_prefix turns it into a prefix. Where the
+        #: slice is a program of its own (dispatch on the device tier:
+        #: dev.fetch_prefix) every bucket from _prefix_small to _buf_bytes
+        #: is a tier, so the prefix holds the whole frame and harvest
+        #: re-reads nothing. Where the slice is compiled into the step
+        #: (the batch programs, the host-entropy step) a new size would
+        #: recompile it, so those keep two stable sizes: _prefix_small
+        #: for static/quiet content (the worst-case head every frame
+        #: would cost 10-30x the D2H bytes of an idle desktop) and
+        #: _batch_prefix, which an undershoot at that size grows
+        #: (bounded recompiles).
         if entropy == "device":
             self._cavlc_msb = dcav.default_max_stripe_bytes(
                 self.pad_w // MB, sh // MB)
             self._fixed_bytes = dcav.HEAD_BYTES * self.n_stripes
             self._buf_bytes = self._fixed_bytes \
                 + self.n_stripes * self._cavlc_msb
-            # CAVLC payloads run ~4-6x smaller than the sparse cells, so
-            # the fetch tiers shrink accordingly: full-damage 1080p
-            # scroll measures ~12.7 KB/frame of bitstream, so pixels/80
-            # (~26 KB at 1080p → the 32 KB bucket) leaves ~2.5x headroom
-            # before the undershoot fallback engages
+            # CAVLC payloads run ~4-6x smaller than the sparse cells. A
+            # full-damage 1080p scroll at crf 25 is 96 kB a frame
+            # (PERF.md), three times what pixels/80 (the 32 KB bucket)
+            # holds: only the batch programs still fetch that size, and
+            # fall back to the exact flat16 rows past it
             self._sparse_guess = self._bucket(self._fixed_bytes + (16 << 10))
             self._batch_prefix = self._bucket(
                 self._fixed_bytes
@@ -370,6 +390,7 @@ class H264StripeEncoder:
                 self._fixed_bytes
                 + max(96 << 10, self.pad_h * self.pad_w // 20))
         self._prefix_small = self._bucket(self._fixed_bytes + 4096)
+        self._recent_needed: deque = deque(maxlen=self.PREFIX_MEMORY_FRAMES)
 
         #: observability (ISSUE 1 satellite): host entropy wall time and
         #: D2H re-read bytes, accumulated per harvested frame so the
@@ -385,14 +406,26 @@ class H264StripeEncoder:
         #: device branched on, read from the same t_bits
         self.cavlc_frames_total = 0
         self.cavlc_low_tier_frames_total = 0
+        #: and those of them whose fetched prefix held the whole payload,
+        #: so that harvest made no undershoot re-read
+        self.prefix_hit_frames_total = 0
 
-    def _choose_prefix(self) -> int:
-        """Pick between the two compiled head sizes from the adaptive
-        estimate harvest maintains (_sparse_guess tracks ~1.5x the last
-        frame's needed bytes)."""
+    def _choose_prefix(self, every_bucket: bool = False) -> int:
+        """The next P frame's fetch prefix, from the estimate harvest
+        maintains (_sparse_guess: the bucket of 1.5x the recent frames'
+        largest needed bytes). ``every_bucket``: the slice is a program of its
+        own, so the guess itself is the prefix; else one of the two
+        sizes compiled into the step."""
         if self._sparse_guess <= self._prefix_small:
             return self._prefix_small
-        return self._batch_prefix
+        return self._sparse_guess if every_bucket else self._batch_prefix
+
+    def _prefix_tiers(self) -> List[int]:
+        """Every size ``_choose_prefix(every_bucket=True)`` can return."""
+        tiers = [self._prefix_small]
+        while tiers[-1] < self._buf_bytes:
+            tiers.append(self._bucket(2 * tiers[-1]))
+        return tiers
 
     def _bucket(self, nbytes: int) -> int:
         """Power-of-two fetch prefix (bounds distinct slice executables)."""
@@ -441,10 +474,15 @@ class H264StripeEncoder:
                     st.painted_over = True
 
         head = None
-        prefix = None if is_idr else self._choose_prefix()
-        # the executable: IDR, or P by entropy tier and fetch-prefix tier
-        program = "idr" if is_idr else ("p", self.entropy, prefix)
-        with self.compile_watch.first_use(program):
+        # the device tier's slice is dev.fetch_prefix, a program of its
+        # own: there the prefix follows the content bucket by bucket
+        cavlc = self.entropy == "device"
+        prefix = None if is_idr else self._choose_prefix(every_bucket=cavlc)
+        # the executable: IDR, the device tier's P step (with all its
+        # slice programs), or the host tier's by the prefix inside it
+        program = "idr" if is_idr else ("p", self.entropy,
+                                        None if cavlc else prefix)
+        with self.compile_watch.first_use(program) as cold:
             if is_idr:
                 (flat8, flat16, self._prev_y, self._prev_cb, self._prev_cr,
                  self._ref_y, self._ref_cb, self._ref_cr) = \
@@ -456,7 +494,7 @@ class H264StripeEncoder:
                         n_stripes=self.n_stripes, sh=self.stripe_h)
                 pending_buf = None
                 fetch_arr = flat16 if fetch else None
-            elif self.entropy == "device":
+            elif cavlc:
                 # on-device CAVLC: the fetch prefix is head + bit-exact
                 # P-slice payloads (device_cavlc.py); flat16 stays device-
                 # resident for overflow/IDR-resync re-reads
@@ -472,6 +510,11 @@ class H264StripeEncoder:
                         search=self.search,
                         max_stripe_bytes=self._cavlc_msb,
                         me=dev._me_backend())
+                if cold:
+                    # with the step, every slice program the content can
+                    # select later: none is left to compile in a stream
+                    for tier in self._prefix_tiers():
+                        dev.fetch_prefix(buf, prefix=tier)
                 head = dev.fetch_prefix(buf, prefix=prefix)
                 pending_buf = buf
                 fetch_arr = head if fetch else None
@@ -502,7 +545,7 @@ class H264StripeEncoder:
         return _H264Pending(fetch=fetch_arr, flat16=flat16, is_idr=is_idr,
                             paint=paint, qp=qp_arr, buf=pending_buf,
                             head=head,
-                            cavlc=(not is_idr and self.entropy == "device"),
+                            cavlc=(not is_idr and cavlc),
                             head_len=0 if is_idr else int(head.shape[0]))
 
     def dispatch_batch(self, rgbs, fetch: bool = True
@@ -579,13 +622,17 @@ class H264StripeEncoder:
     def _recover_undershoot(self, p: "_H264Pending", host, needed: int,
                             ovf: np.ndarray, damage: np.ndarray):
         """Prediction-miss recovery shared by the sparse and device-CAVLC
-        transfers.  Single-frame dispatches re-read the right bucket from
-        the full device buffer; batch dispatches keep no full buffer, so
-        every emitting stripe falls back to the exact flat16 rows and the
-        pinned batch prefix grows (bucketed → bounded recompiles)."""
+        transfers: the frame after content got busier (rare, and counted
+        as no prefix hit).  Single-frame dispatches re-read the right
+        bucket from the full device buffer, with the slice program of that
+        tier; the read queues behind every step already dispatched.  Batch
+        dispatches keep no full buffer, so every emitting stripe falls
+        back to the exact flat16 rows and the pinned batch prefix grows
+        (bucketed → bounded recompiles).  Either way the guess takes
+        this frame in, so the next dispatches' prefix holds its like."""
         if needed > len(host):
             if p.buf is not None:
-                full = p.buf[:self._bucket(needed)]
+                full = dev.fetch_prefix(p.buf, prefix=self._bucket(needed))
                 full.copy_to_host_async()
                 host = np.asarray(full)
                 self.d2h_refetch_bytes_total += host.nbytes
@@ -599,8 +646,10 @@ class H264StripeEncoder:
                     self._batch_prefix = min(
                         self._buf_bytes,
                         self._bucket(needed + needed // 2))
+        self._recent_needed.append(needed)
+        largest = max(self._recent_needed)
         self._sparse_guess = self._bucket(
-            max(needed + needed // 2, self._fixed_bytes + 4096))
+            max(largest + largest // 2, self._fixed_bytes + 4096))
         return host, ovf
 
     def _refetch_overflow_rows(self, p: "_H264Pending", damage, ovf):
@@ -657,6 +706,7 @@ class H264StripeEncoder:
             # full-buffer refetch exactly on busy content
             wc = np.minimum((t_bits + 31) // 32, self._cavlc_msb // 4)
             needed = self._fixed_bytes + 4 * int(base_words[-1] + wc[-1])
+            self.prefix_hit_frames_total += needed <= len(host)
             host, ovf = self._recover_undershoot(p, host, needed,
                                                  ovf, damage)
             refetch = self._refetch_overflow_rows(p, damage, ovf)

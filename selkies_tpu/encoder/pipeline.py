@@ -72,6 +72,13 @@ class _PipelineTelemetry:
         """The base encoder's first-use compile signal (runtime.CompileWatch)."""
         return self.base.compile_watch.compiling_for_s()
 
+    @property
+    def has_room(self) -> bool:
+        """True while ``submit`` would dispatch without first blocking
+        for the oldest frame: what the async driver asks before it takes
+        a capture out of its queue."""
+        return self.n_inflight < self.depth
+
     def _mark(self, trace: Optional[dict], state: str,
               t0: float, t1: float) -> None:
         """One work interval: a stage of the frame(s) it was done for,
@@ -134,17 +141,16 @@ class _PipelineTelemetry:
 
 @dataclass
 class _FetchGroup:
-    """One D2H read covering several frames' packed buffers, concatenated
-    on device: RPC-attached chips pay fixed per-transfer latency and allow
-    only a handful of concurrent reads, so frames-per-read — not bytes —
-    sets the fetch ceiling."""
+    """One D2H read and the frames it brings: several JPEG frames' packed
+    buffers concatenated on device (RPC-attached chips pay fixed
+    per-transfer latency and allow only a handful of concurrent reads, so
+    frames-per-read — not bytes — sets the fetch ceiling there), the
+    (B, prefix) heads of one H.264 batch program, or one H.264 frame's own
+    head."""
 
-    arr: Any                        # device concat, one async host copy
-    stride: int = 0                 # uniform member size, when applicable
+    arr: Any                        # device array, one async host copy
+    stride: int = 0                 # member size in a 1-D concat; 0: one
     host: Optional[np.ndarray] = None
-    #: per-member (start, length) when member sizes differ (the H.264
-    #: two-tier head prefixes); empty → uniform stride slicing
-    offsets: Tuple[Tuple[int, int], ...] = ()
     #: host-blocked interval materializing this group's copy (shared by
     #: every member frame's trace: the wait gated them all)
     fetch_iv: Optional[Tuple[float, float]] = None
@@ -464,9 +470,11 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
 
     # -- public harvest ----------------------------------------------------
 
-    def poll(self, flush_partial: bool = True
+    def poll(self, flush_partial: bool = True, wait: bool = False
              ) -> List[Tuple[int, List[StripeOutput]]]:
-        """Harvest all completed frames (non-blocking, in order).
+        """Harvest all completed frames (in order; non-blocking unless
+        ``wait``, which first blocks until the oldest frame is in: the
+        async driver's move when captures queue behind a full pipe).
 
         ``flush_partial`` (default) issues any partially filled fetch
         group so frames are never stranded when submissions pause — the
@@ -480,6 +488,8 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
         """
         if self._unfetched and flush_partial:
             self._issue_fetch()
+        if wait and self._inflight:
+            self._ready.append(self._drain_one())
         self._advance_ready()
         while self._inflight and self._advance(self._inflight[0], block=False):
             item = self._inflight.popleft()
@@ -668,7 +678,7 @@ class _H264InFlight:
     seq: int
     pending: Any                     # h264._H264Pending
     group: Any = None                # _FetchGroup (P frames)
-    group_index: int = 0
+    group_index: int = 0             # row of a batch program's heads
     host: Optional[np.ndarray] = None
     ticket: Optional[StagingTicket] = None
     #: per-frame stage intervals for the flight recorder
@@ -676,23 +686,23 @@ class _H264InFlight:
 
 
 class PipelinedH264Encoder(_PipelineTelemetry):
-    """Depth-N pipelined wrapper around H264StripeEncoder with grouped
-    sparse-buffer fetches.
+    """Depth-N pipelined wrapper around H264StripeEncoder.
 
-    Same transfer economics as PipelinedJpegEncoder: an RPC-attached
-    device pays ~25-110 ms per D2H read regardless of size, so several
-    frames' sparse level buffers (h264_device._pack_sparse) are
-    concatenated on device and fetched in ONE read. IDR frames carry the
-    full flat16 levels and fetch solo (they are rare: connect/reset/PLI).
+    Every frame's host copy starts at its dispatch, right behind the
+    frame's own step on the device queue: a P frame's fetch prefix
+    (sized by the encoder for the content, h264._choose_prefix), an IDR's
+    flat16 levels, a batch program's (B, prefix) heads in one read. On a
+    directly attached chip a read waits 0.07 ms (PERF.md); nothing is
+    concatenated across frames, so no transfer waits for the next frame's
+    dispatch and no program's shape depends on which prefixes met. In
+    steady state ``harvest`` issues no device program and waits for none.
     """
 
-    def __init__(self, base, depth: int = 8, fetch_group: int = 4,
-                 batch: int = 1,
+    def __init__(self, base, depth: int = 8, batch: int = 1,
                  batch_deadline_s: Optional[float] = None,
                  metrics=None) -> None:
         self.base = base
         self.depth = depth
-        self.fetch_group = max(1, fetch_group)
         #: transfer accounting for the d2h_bytes_per_frame /
         #: host_entropy_ms_per_frame gauges (host-entropy time and
         #: refetch bytes accumulate on the base encoder in harvest)
@@ -709,7 +719,7 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         #: (ISSUE 12 satellite): the deadline detects a PAUSED caller —
         #: no new frame within the window — not a slow one, so a stream
         #: ticking slower than batch/deadline still accumulates full
-        #: ``fetch_group`` batches instead of degrading to single-frame
+        #: batches instead of degrading to single-frame
         #: dispatches forever (worst-case frame staleness stays bounded
         #: at ``batch`` deadlines — see _batch_deadline_due).
         if batch_deadline_s is None:
@@ -719,7 +729,6 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         self._batch_last = 0.0      # last submit — re-arms the deadline
         self._batch_frames: List[Any] = []
         self._inflight: deque[_H264InFlight] = deque()
-        self._unfetched: List[_H264InFlight] = []
         self._ready: List[Tuple[int, list]] = []
         self._seq = 0
         #: donated H2D staging lanes (ISSUE 12): one ring per input shape
@@ -735,9 +744,14 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         return len(self._inflight)
 
     @property
+    def has_room(self) -> bool:
+        # frames buffered toward a batch hold a slot too (as in submit)
+        return len(self._inflight) + len(self._batch_frames) < self.depth
+
+    @property
     def inflight_batches(self) -> int:
-        """Dispatched-but-not-yet-materialized fetch units: grouped P
-        reads, batch heads, and solo IDR flat16 fetches each count once
+        """Dispatched-but-not-yet-materialized fetch units: a P frame's
+        head, a batch program's heads and an IDR's flat16 each count once
         while their host copy is outstanding (the ISSUE 12 gauge)."""
         groups = set()
         solo = 0
@@ -745,10 +759,9 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             if it.pending.is_idr:
                 if it.host is None:
                     solo += 1
-            elif it.group is not None:
-                if it.group.host is None:
-                    groups.add(id(it.group))
-        return len(groups) + solo + (1 if self._unfetched else 0)
+            elif it.group.host is None:
+                groups.add(id(it.group))
+        return len(groups) + solo
 
     def stats(self) -> dict:
         """Per-frame transfer/host-entropy gauges over the run so far.
@@ -769,6 +782,8 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             "cavlc_frames": getattr(self.base, "cavlc_frames_total", 0),
             "cavlc_low_tier_frames": getattr(
                 self.base, "cavlc_low_tier_frames_total", 0),
+            "prefix_hit_frames": getattr(
+                self.base, "prefix_hit_frames_total", 0),
             "staging_stalls": (self._staging.stalls_total
                                + self._staging_batch.stalls_total),
             **self._telemetry_stats(),
@@ -784,6 +799,8 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             if st["cavlc_frames"]:
                 self.metrics.set_cavlc_low_tier_share(
                     st["cavlc_low_tier_frames"] / st["cavlc_frames"])
+                self.metrics.set_fetch_prefix_hit_share(
+                    st["prefix_hit_frames"] / st["cavlc_frames"])
 
     def request_keyframe(self) -> None:
         self.base.request_keyframe()
@@ -836,13 +853,17 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         frame, slot = self._stage(frame, self._staging)
         td0 = time.monotonic()
         try:
-            p = self.base.dispatch(frame, fetch=False)
+            # the encoder starts the frame's own host copy (head, or an
+            # IDR's flat16) behind the step it has just enqueued
+            p = self.base.dispatch(frame)
         except Exception:
             # no ticket exists yet: free the staged slot here or it
             # leaks busy forever and the lane loses a buffer
             self._staging.release(slot)
             raise
         item = _H264InFlight(seq=self._seq, pending=p,
+                             group=None if p.is_idr
+                             else _FetchGroup(arr=p.fetch),
                              ticket=StagingTicket(self._staging, slot))
         if slot is not None:
             self._mark(item.trace, "stage", ts0, td0)
@@ -850,18 +871,7 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         self._mark(item.trace, "dispatch", td0, td1)
         self._seq += 1
         self._inflight.append(item)
-        if p.is_idr:
-            # IDR fetches flat16 solo (rare: connect/reset/PLI)
-            p.flat16.copy_to_host_async()
-        else:
-            self._unfetched.append(item)
-            if len(self._unfetched) >= self.fetch_group:
-                self._issue_fetch()
-        # as in the JPEG pipeline: the fetch's start counts as launch for
-        # the thread, not for the frame's ``dispatch`` stage
-        t_end = time.monotonic()
-        self._mark(None, "dispatch", td1, t_end)
-        self._record_dispatch((t_end - ts0) * 1000.0)
+        self._record_dispatch((td1 - ts0) * 1000.0)
         return item.seq
 
     def submit_batch(self, rgbs) -> List[int]:
@@ -897,9 +907,7 @@ class PipelinedH264Encoder(_PipelineTelemetry):
                     # silently — they are drops, visible to the ladder
                     # and health feed
                     self._count_dropped(len(frames) - i - 1)
-                    self._issue_fetch()
                     raise
-            self._issue_fetch()
             return
         if any(not isinstance(f, jnp.ndarray) for f in frames):
             # host frames: stack host-side and stage the whole batch
@@ -923,14 +931,14 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             self.metrics.inc_frames_dropped(n)
 
     def _dispatch_batch(self, rgbs) -> None:
-        # fetch=False: this pipeline owns every transfer — the encoder
-        # starting its own head copies AND _issue_fetch concatenating the
-        # same heads would double-transfer the IDR-recovery path
         ts0 = time.monotonic()
         rgbs, slot = self._stage(rgbs, self._staging_batch)
         td0 = time.monotonic()
         try:
-            pendings = self.base.dispatch_batch(rgbs, fetch=False)
+            # the encoder starts the transfers: the heads array in one
+            # read, or each frame's own where it fell back to single
+            # dispatches (IDR recovery)
+            pendings = self.base.dispatch_batch(rgbs)
         except Exception:
             self._staging_batch.release(slot)
             raise
@@ -942,7 +950,7 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         # frees when the LAST of them harvests
         ticket = StagingTicket(self._staging_batch, slot,
                                refs=len(pendings))
-        group_items = []
+        heads = None                # one group for the batch's heads
         for p in pendings:
             item = _H264InFlight(seq=self._seq, pending=p, ticket=ticket)
             # one staged buffer + one program back the whole batch, so
@@ -952,48 +960,13 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             item.trace["dispatch"] = (td0, td1)
             self._seq += 1
             self._inflight.append(item)
-            if p.is_idr:
-                p.flat16.copy_to_host_async()
-            elif p.batch_heads is not None:
-                group_items.append(item)
-            else:
-                self._unfetched.append(item)
-        if group_items:
-            arr = group_items[0].pending.batch_heads
-            arr.copy_to_host_async()
-            group = _FetchGroup(arr=arr)
-            for it in group_items:
-                it.group = group
-                it.group_index = it.pending.batch_index
-        if self._unfetched:
-            self._issue_fetch()
+            if p.batch_heads is not None:
+                if heads is None:
+                    heads = _FetchGroup(arr=p.batch_heads)
+                item.group, item.group_index = heads, p.batch_index
+            elif not p.is_idr:
+                item.group = _FetchGroup(arr=p.fetch)
         self._record_dispatch((time.monotonic() - ts0) * 1000.0)
-
-    def _issue_fetch(self) -> None:
-        group_items, self._unfetched = self._unfetched, []
-        if not group_items:
-            return
-        # the dispatch program already produced each frame's prefix slice
-        # (one fewer program per frame); members may have different sizes
-        # (two-tier head prefixes), so the group records per-member
-        # offsets instead of assuming a uniform stride
-        slices = []
-        offsets = []
-        pos = 0
-        for it in group_items:
-            s = it.pending.head if it.pending.head is not None \
-                else it.pending.buf[:self.base._batch_prefix]
-            n = int(s.shape[0])
-            slices.append(s)
-            offsets.append((pos, n))
-            pos += n
-        arr = slices[0] if len(slices) == 1 else jnp.concatenate(slices)
-        arr.copy_to_host_async()
-        group = _FetchGroup(arr=arr, offsets=tuple(offsets))
-        for i, it in enumerate(group_items):
-            it.group = group
-            it.group_index = i
-        self._note_inflight()
 
     def _advance(self, item: _H264InFlight, block: bool) -> bool:
         p = item.pending
@@ -1008,10 +981,6 @@ class PipelinedH264Encoder(_PipelineTelemetry):
                 self._record_fetch_wait((tm1 - tm0) * 1000.0)
                 self.d2h_bytes_total += item.host.nbytes
             return True
-        if item.group is None:
-            if not block:
-                return False
-            self._issue_fetch()
         if not block and not item.group.arr.is_ready():
             return False
         if item.group.host is None:
@@ -1020,13 +989,8 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             item.trace["fetch_wait"] = item.group.fetch_iv
         if item.group.host.ndim == 2:      # batched dispatch: (B, prefix)
             item.host = item.group.host[item.group_index]
-        elif item.group.offsets:
-            start, length = item.group.offsets[item.group_index]
-            item.host = item.group.host[start:start + length]
-        else:
-            stride = item.group.stride
-            item.host = item.group.host[item.group_index * stride:
-                                        (item.group_index + 1) * stride]
+        else:                              # the frame's own head
+            item.host = item.group.host
         return True
 
     @staticmethod
@@ -1070,9 +1034,13 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         ``batch * batch_deadline_s``."""
         return time.monotonic() - self._batch_last > self.batch_deadline_s
 
-    def poll(self, flush_partial: bool = True) -> List[Tuple[int, list]]:
-        """Harvest completed frames in order; see PipelinedJpegEncoder.poll
-        for the ``flush_partial`` latency/throughput trade.
+    def poll(self, flush_partial: bool = True, wait: bool = False
+             ) -> List[Tuple[int, list]]:
+        """Harvest completed frames in order. ``flush_partial`` dispatches
+        a partly filled batch at once (the low-latency choice; False
+        leaves that to the batch deadline and ``flush()``); ``wait``
+        first blocks until the oldest frame is in, as
+        PipelinedJpegEncoder.poll does.
 
         Results accumulate in ``self._ready`` and are swapped out only at
         the end: a harvest raising mid-pass must not discard the frames
@@ -1082,8 +1050,11 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             # deadline flush: frames buffered toward a batch must not wait
             # forever when the caller pauses submission
             self._flush_batch()
-        if self._unfetched and flush_partial:
-            self._issue_fetch()
+        if wait:
+            if not self._inflight:
+                self._flush_batch()     # as submit does for a full pipe
+            if self._inflight:
+                self._ready.append(self._drain_one())
         while self._inflight and self._advance(self._inflight[0],
                                                block=False):
             self._ready.append(self._harvest_item(self._inflight.popleft()))
@@ -1101,7 +1072,6 @@ class PipelinedH264Encoder(_PipelineTelemetry):
     def close(self) -> None:
         self._batch_frames.clear()
         self._inflight.clear()
-        self._unfetched.clear()
         self._ready.clear()
         self._trace_out.clear()
         # a rebuilt pipeline must never inherit phantom-busy ring slots

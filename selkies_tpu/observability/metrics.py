@@ -94,6 +94,11 @@ class Metrics:
             "whose largest stripe fit the pack's low output tier (the "
             "cheap one; paint-over and busy frames take the high tier)",
             registry=self.registry)
+        self.fetch_prefix_hit_share = Gauge(
+            "tpuenc_fetch_prefix_hit_share", "Share of device-CAVLC P "
+            "frames whose fetch prefix, sized at dispatch, held the whole "
+            "payload (no undershoot re-read at harvest)",
+            registry=self.registry)
         # ISSUE 12: the dispatch/fetch-floor claims must stay measured —
         # the async pipeline driver keeps >=2 batches in flight, and
         # these series prove (or disprove) it per deployment
@@ -333,6 +338,10 @@ class Metrics:
     def set_cavlc_low_tier_share(self, share: float) -> None:
         if HAVE_PROM:
             self.cavlc_low_tier_share.set(share)
+
+    def set_fetch_prefix_hit_share(self, share: float) -> None:
+        if HAVE_PROM:
+            self.fetch_prefix_hit_share.set(share)
 
     def set_inflight_batches(self, n: int) -> None:
         if HAVE_PROM:

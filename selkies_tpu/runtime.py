@@ -91,8 +91,9 @@ class CompileWatch:
     program's first-use XLA compile. The object that OWNS the jitted
     programs wraps each dispatch in :meth:`first_use`, naming the program
     (every static argument that selects a different executable); only a
-    program that has not yet completed a call here is timed, so a warm
-    dispatch that hangs on the device is never mistaken for a compile.
+    program that has not yet completed a call here is timed (the block is
+    handed that fact, for what belongs to a program's first use), so a
+    warm dispatch that hangs on the device is never mistaken for a compile.
     The capture loop reads :meth:`compiling_for_s` from the encoder it
     holds — one display's compile says nothing about another's — before
     it reads a quiet pipeline as a dead one (data_server._display_loop).
@@ -115,7 +116,7 @@ class CompileWatch:
                     self._since = time.monotonic()
                 self._depth += 1
         try:
-            yield
+            yield cold
             if cold:
                 with self._lock:
                     self._warm.add(program)

@@ -300,7 +300,7 @@ def default_encoder_factory(
                 base, depth=3, wire_fullframe=(profile == "x264enc"))
         return AsyncEncodeDriver(
             PipelinedH264Encoder(base, depth=max(4, 3 * batch),
-                                 fetch_group=2, batch=batch),
+                                 batch=batch),
             flush_partial_when_idle=(batch == 1),
             wire_fullframe=(profile == "x264enc"))
     base = JpegStripeEncoder(

@@ -72,9 +72,11 @@ class WarmUp:
                 flush = functools.partial(enc.flush,
                                           timeout=COMPILE_GRACE_S)
             # three frames of moving content: the first compiles the
-            # keyframe program, the next two the inter-frame program and
-            # the small fetch programs behind it — a stream's second
-            # frame must not be where a minutes-long compile lands
+            # keyframe program, the second the inter-frame program and,
+            # with it, the slice program of every fetch-prefix tier the
+            # content can select later (h264.dispatch) — a stream's
+            # second frame must not be where a minutes-long compile
+            # lands, nor its first busy frame where a small one does
             src = SyntheticSource(1920, 1080, pattern="desktop")
             for _ in range(3):
                 enc.submit(src.next_frame())

@@ -173,8 +173,7 @@ class WebRTCStreamingApp:
         if hasattr(self.encoder, "dispatch"):
             from ..encoder.pipeline import PipelinedH264Encoder
 
-            pipe = PipelinedH264Encoder(self.encoder, depth=3,
-                                        fetch_group=1)
+            pipe = PipelinedH264Encoder(self.encoder, depth=3)
 
         def _send(seq: int, stripes) -> None:
             if not stripes or not self._running:

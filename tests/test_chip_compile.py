@@ -151,6 +151,30 @@ def test_device_cavlc_pack_1080p(one_chip, no_persistent_cache, n_stripes):
         kernel=False, label=f"pack_p_frame {n_stripes}x480", t0=t0)
 
 
+#: the served encoder's fetch-prefix tiers (``H264StripeEncoder
+#: ._prefix_tiers()`` at 1920x1080, stripes of 64): 8 KiB doubling up to
+#: the packed buffer's own 17 x (12 B head + 128 KiB) bytes
+PREFIX_TIERS_1080P = [8192 << i for i in range(9)] + [S * (12 + (128 << 10))]
+
+
+@pytest.mark.parametrize("tier", range(len(PREFIX_TIERS_1080P)))
+def test_fetch_prefix_tier_1080p(one_chip, no_persistent_cache, tier):
+    """Every slice program the content can select (encoder/h264.py
+    ``dispatch``): one small compile each, all made with the P step at an
+    encoder's first P frame."""
+    from selkies_tpu.encoder import h264_device as dev
+    from selkies_tpu.encoder.h264 import H264StripeEncoder
+
+    enc = H264StripeEncoder(W, 1080, stripe_height=SH)
+    assert enc._prefix_tiers() == PREFIX_TIERS_1080P
+    prefix = PREFIX_TIERS_1080P[tier]
+    t0 = time.time()
+    compiled = dev.fetch_prefix.lower(
+        _sds(one_chip, (enc._buf_bytes,), jnp.uint8), prefix=prefix).compile()
+    _check(compiled, kernel=False, label=f"fetch_prefix {prefix}", t0=t0)
+    assert time.time() - t0 < 30.0
+
+
 def test_pallas_dct_alive_1080p(one_chip, no_persistent_cache):
     """ops/pallas_dct.py is not on the served path (the XLA DCT won); one
     compile at (1088, 1920) says whether it is still alive."""

@@ -97,7 +97,9 @@ class FakePipe(_PipelineTelemetry):
                                   jpeg=b"\xff\xd8F%d\xff\xd9" % seq,
                                   is_paintover=False)]
 
-    def poll(self, flush_partial=True):
+    def poll(self, flush_partial=True, wait=False):
+        if wait and self._inflight:
+            self._ready.append(self._drain_one(block=True))
         while self._inflight:
             got = self._drain_one(block=False)
             if got is None:

@@ -126,11 +126,12 @@ def test_idr_recovery_avoids_batch_program(monkeypatch):
     assert calls == [4]
 
 
-def test_two_tier_prefix_shrinks_for_static_content():
+def test_two_tier_prefix_shrinks_for_static_content(monkeypatch):
     """Static frames must ship the small head, not the worst-case one
     (code-review r3: a fixed large prefix costs 10-30x the D2H bytes on
-    an idle desktop). Uses a geometry large enough that the two tiers
-    are distinct buckets."""
+    an idle desktop), once the guess has forgotten the busy ones. Uses a
+    geometry large enough that the two tiers are distinct buckets."""
+    monkeypatch.setattr(H264StripeEncoder, "PREFIX_MEMORY_FRAMES", 2)
     rng = np.random.default_rng(3)
     base = rng.integers(0, 256, (256, 320, 3), np.uint8)
     frames = [np.roll(base, 5 * min(i, 2), axis=0) for i in range(8)]

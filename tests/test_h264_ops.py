@@ -150,7 +150,7 @@ def test_batched_search_over_stripes():
 
 
 def test_pipelined_h264_matches_synchronous():
-    """PipelinedH264Encoder (grouped sparse fetches) must produce the
+    """PipelinedH264Encoder (a fetch of its own per frame) must produce the
     byte-identical stream the synchronous encoder does."""
     import numpy as np
     from selkies_tpu.encoder.h264 import H264StripeEncoder
@@ -167,7 +167,7 @@ def test_pipelined_h264_matches_synchronous():
 
     a = H264StripeEncoder(160, 96, stripe_height=32, qp=24)
     b = H264StripeEncoder(160, 96, stripe_height=32, qp=24)
-    pipe = PipelinedH264Encoder(b, depth=6, fetch_group=3)
+    pipe = PipelinedH264Encoder(b, depth=6)
 
     want = []
     for t in range(8):

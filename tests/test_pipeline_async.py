@@ -13,7 +13,7 @@ Covers the tentpole's safety obligations, not its throughput claims
 * a supervisor-style restart mid-flight (close + rebuild) neither
   deadlocks nor leaks a ring slot;
 * the batch deadline re-arms per submit, so slow or paused-then-resumed
-  streams return to full ``fetch_group`` batching (the
+  streams return to full batches (the
   PipelinedH264Encoder pause-degradation edge);
 * slow-marked soak: ~10 s under ``fetch.hang`` chaos with no wedge and
   no monotonic in-flight growth.
@@ -129,17 +129,25 @@ class _StubPipe:
         self.metrics = None
         self.gate = threading.Event()
         self.gate.set()
+        self.arrive = threading.Semaphore(0)
         self._inflight: deque = deque()
         self._ready: list = []
         self._seq = 0
         self.fail_on = set(fail_on)
         self.closed = False
+        #: submits that found the pipe full (the driver makes none)
+        self.submitted_full = 0
 
     @property
     def n_inflight(self):
         return len(self._inflight)
 
+    @property
+    def has_room(self):
+        return len(self._inflight) < self.depth
+
     def submit(self, frame):
+        self.submitted_full += not self.has_room
         while len(self._inflight) >= self.depth:
             # like the real pipelines: a full submit harvests the oldest
             # into the ready list for the next poll/flush
@@ -152,12 +160,20 @@ class _StubPipe:
         self._inflight.append(seq)
         return seq
 
+    def release(self, n):
+        """Let exactly ``n`` frames arrive while the gate stays shut."""
+        self.arrive.release(n)
+
     def _drain_one(self):
-        self.gate.wait()
+        while not self.gate.is_set() and not self.arrive.acquire(
+                timeout=0.005):
+            pass
         return (self._inflight.popleft(), ["stripe"])
 
-    def poll(self, flush_partial=True):
+    def poll(self, flush_partial=True, wait=False):
         out, self._ready = self._ready, []
+        if wait and self._inflight:
+            out.append(self._drain_one())       # blocks at the gate
         while self._inflight and self.gate.is_set():
             out.append(self._drain_one())
         return out
@@ -232,6 +248,69 @@ def test_driver_flush_survives_submit_errors():
         assert errors and isinstance(errors[0], RuntimeError)
     finally:
         drv.close()
+
+
+def _wait_until(cond, timeout=5.0):
+    t_end = time.monotonic() + timeout
+    while not cond() and time.monotonic() < t_end:
+        time.sleep(0.002)
+    return cond()
+
+
+def test_no_capture_leaves_the_queue_while_the_pipe_is_full():
+    """A pipe whose oldest frame is slow to arrive: the driver fills the
+    pipe's free slots and leaves every other capture in ``_in_q`` (where
+    ``try_submit`` counts it and refuses the next), never in a submit
+    that blocks; a slot freed by the oldest frame's arrival takes exactly
+    the oldest queued capture, and frames come out in submission order."""
+    pipe = _StubPipe(depth=3)
+    pipe.gate.clear()                        # the oldest is not in yet
+    drv = AsyncEncodeDriver(pipe, submit_depth=4)
+    try:
+        assert [drv.try_submit(i) for i in range(3)] == [0, 1, 2]
+        assert _wait_until(lambda: pipe.n_inflight == 3)
+        assert [drv.try_submit(i) for i in range(3, 7)] == [3, 4, 5, 6]
+        time.sleep(0.05)                     # the driver waits, takes none
+        assert pipe.n_inflight == 3 and pipe.submitted_full == 0
+        with drv._cond:
+            assert [s for s, _f, _t in drv._in_q] == [3, 4, 5, 6]
+            assert sorted(drv._waits) == [0, 1, 2]    # only those taken out
+        assert drv.try_submit(7) is None              # refused at the edge
+        assert drv.frames_dropped_total == 1
+        assert drv.poll() == []
+
+        # the oldest arrives, and only it: one slot, one capture
+        pipe.release(1)
+        assert _wait_until(lambda: len(drv._in_q) == 3)
+        assert _wait_until(lambda: pipe.n_inflight == 3)
+        assert [s for s, _ in drv.poll()] == [0]
+        with drv._cond:
+            assert [s for s, _f, _t in drv._in_q] == [4, 5, 6]
+        assert pipe.submitted_full == 0
+
+        # flush() mid-flight: everything accepted, in submission order
+        pipe.gate.set()
+        assert [s for s, _ in drv.flush()] == [1, 2, 3, 4, 5, 6]
+        assert pipe.submitted_full == 0 and pipe.n_inflight == 0
+
+        # close() mid-flight, the thread blocked on a frame that is late:
+        # returns at once, queued captures are abandoned, nothing hangs
+        pipe.gate.clear()
+        for i in range(3):
+            assert drv.try_submit(i) is not None
+        assert _wait_until(lambda: pipe.n_inflight == 3)
+        for i in range(4):
+            assert drv.try_submit(i) is not None
+        t0 = time.monotonic()
+        drv.close()
+        assert time.monotonic() - t0 < 1.0
+        assert drv.try_submit(99) is None
+    finally:
+        pipe.gate.set()
+        drv.close()
+    drv._thread.join(timeout=10.0)
+    assert not drv._thread.is_alive()
+    assert pipe.closed and pipe.submitted_full == 0
 
 
 # ---------------------------------------------------------------------------
@@ -395,7 +474,7 @@ def test_midpass_harvest_error_preserves_completed_frames_and_tickets():
     from selkies_tpu.encoder.pipeline import PipelinedH264Encoder
 
     enc = H264StripeEncoder(128, 96, stripe_height=32)
-    pipe = PipelinedH264Encoder(enc, depth=8, fetch_group=2)
+    pipe = PipelinedH264Encoder(enc, depth=8)
     pipe.submit(_frame(96, 128, seed=0))     # warm (IDR + compiles)
     pipe.submit(_frame(96, 128, seed=1))
     pipe.flush()
@@ -411,7 +490,7 @@ def test_midpass_harvest_error_preserves_completed_frames_and_tickets():
 
     enc.harvest = harvest
     pipe.submit(_frame(96, 128, seed=2))     # seq 2
-    pipe.submit(_frame(96, 128, seed=3))     # seq 3 — one fetch group
+    pipe.submit(_frame(96, 128, seed=3))     # seq 3
     with pytest.raises(RuntimeError):
         pipe.flush()
     enc.harvest = orig
