@@ -524,7 +524,7 @@ async def serve_h264(width: int, height: int, frames: int,
         assert_healthy(b.server)
 
         # which ME backend ran, and is the compiled kernel in the step?
-        me = dev._me_backend()
+        me = dev.ME
         check(not pallas_interpret(), "Pallas interpreter mode is on")
         S, sh = base.n_stripes, base.stripe_h
         u8 = jnp.uint8

@@ -14,45 +14,6 @@ import numpy as np
 from .base import FrameSource
 
 
-class DeviceScrollSource:
-    """Device-resident scrolling frame source for encoder benchmarks.
-
-    Generates the same "scroll" workload as :class:`SyntheticSource` (every
-    stripe damaged every frame — no damage-gating shortcuts) but materializes
-    frames *on the TPU* with a tiny jitted roll, so a benchmark measures the
-    encoder instead of the host→device upload (whose cost on a directly
-    attached chip is not measured).
-    """
-
-    def __init__(self, width: int, height: int, seed: int = 0) -> None:
-        import jax
-        import jax.numpy as jnp
-
-        base = SyntheticSource(width, height, pattern="scroll", seed=seed)
-        self.width, self.height = width, height
-        self._bg = jax.device_put(base._bg)
-        self._roll = jax.jit(lambda bg, t: jnp.roll(bg, shift=-4 * t, axis=0))
-
-        def roll_batch(bg, t0, n):
-            ts = t0 + jnp.arange(n)
-            return jax.vmap(lambda t: jnp.roll(bg, shift=-4 * t, axis=0))(ts)
-
-        self._roll_batch = jax.jit(roll_batch, static_argnames=("n",))
-        self._t = 0
-
-    def next_frame(self):
-        t = self._t
-        self._t += 1
-        return self._roll(self._bg, t % self.height)
-
-    def next_batch(self, n: int):
-        """(n, H, W, 3) scrolled frames in ONE device program — a
-        per-frame roll would cost n dispatches."""
-        t = self._t
-        self._t += n
-        return self._roll_batch(self._bg, t % self.height, n)
-
-
 class SyntheticSource(FrameSource):
     PATTERNS = ("desktop", "scroll", "motion", "static", "noise")
 

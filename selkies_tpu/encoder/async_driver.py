@@ -15,12 +15,12 @@ idles waiting on the host:
 * the capture loop's ``try_submit``/``poll`` become pure queue
   operations — no device interaction ever runs on the event loop;
 * a dedicated driver thread owns the pipelined encoder
-  (:mod:`.pipeline`) and keeps >=2 batches in flight end-to-end:
-  dispatch of batch N+1 is issued while batch N's eagerly-started
+  (:mod:`.pipeline`) and keeps >=2 frames in flight end-to-end:
+  frame N+1 is dispatched while frame N's eagerly-started
   ``copy_to_host_async`` completes;
 * host frames double-buffer through the donated staging ring
   (:class:`.h264_device.StagingRing`), so H2D upload overlaps the
-  previous batch's compute and donation never serializes dispatches;
+  previous frame's compute and donation never serializes dispatches;
 * a bounded submit queue gives backpressure (frames drop at the edge,
   counted, instead of stalling every display on the loop);
 * ``flush()`` drains deterministically; ``close()`` mid-flight neither
@@ -65,16 +65,10 @@ class AsyncEncodeDriver:
     POLL_INTERVAL_S = 0.002
 
     def __init__(self, pipe, *, submit_depth: Optional[int] = None,
-                 flush_partial_when_idle: bool = True,
                  wire_fullframe: bool = False,
                  metrics=None, faults=None) -> None:
         self.pipe = pipe
         self.submit_depth = int(submit_depth or max(4, pipe.depth))
-        #: JPEG / batch=1 H.264: ship partial fetch groups as soon as the
-        #: submit queue runs dry (lowest latency). Batched H.264 keeps
-        #: False so the re-armed batch deadline — not every idle poll —
-        #: decides when a partial batch ships.
-        self.flush_partial_when_idle = bool(flush_partial_when_idle)
         self.wire_fullframe = bool(wire_fullframe)
         self._metrics = metrics
         pipe.metrics = metrics
@@ -374,8 +368,9 @@ class AsyncEncodeDriver:
             # while this thread packs it.
             with self._cond:
                 backlog = bool(self._in_q)
+            # (with the queue dry, JPEG's partly filled fetch group ships)
             self._harvest(
-                flush_partial=(not backlog and self.flush_partial_when_idle),
+                flush_partial=not backlog,
                 wait=backlog and not self.pipe.has_room)
             self._error_streak = 0
         except Exception as exc:
@@ -396,9 +391,7 @@ class AsyncEncodeDriver:
                     break
                 except Exception as exc:
                     self._count_error(exc)
-                    if (self.pipe.n_inflight == 0
-                            and not getattr(self.pipe, "_batch_frames",
-                                            None)):
+                    if self.pipe.n_inflight == 0:
                         break
         with self._cond:
             self._stats_cache = dict(self.pipe.stats())
@@ -412,11 +405,9 @@ class AsyncEncodeDriver:
                 return False
             if self._in_q or self._flush_req > self._flush_ack:
                 return True
-            # in-flight work pending: short beat, then re-poll; the
-            # batch deadline also needs the beat to fire. Otherwise
-            # sleep until new work arrives.
-            waiting = (self.pipe.n_inflight > 0
-                       or bool(getattr(self.pipe, "_batch_frames", None)))
+            # in-flight work pending: short beat, then re-poll.
+            # Otherwise sleep until new work arrives.
+            waiting = self.pipe.n_inflight > 0
             t_sleep0 = time.monotonic()
             self._cond.wait(self.POLL_INTERVAL_S if waiting else 0.25)
             t_sleep1 = time.monotonic()

@@ -4,9 +4,9 @@ Why: the H.264 path's steady state paid a per-batch D2H read of the block-sparse
 coefficient buffer, plus a per-session host CPU cost for the native CAVLC
 coder (encoder/h264.py ``_entropy_pool``).  The JPEG path already proved
 the fix (encoder/device_entropy.py): run entropy coding on device and
-fetch only the compressed bits.  A P slice's mean bitstream is ~12.7 KB
-at 1080p — far below the sparse level transfer — so packing CAVLC on
-device shrinks that transfer directly AND removes the per-session
+fetch only the compressed bits.  A full-damage 1080p scroll's P frame is
+36.5 kB of bitstream (ledger, PR 29), far below the sparse levels: CAVLC
+on device shrinks that transfer directly AND removes the per-session
 host entropy threads (the "millions of users" scaling wall).
 
 Unlike CABAC, every CAVLC context is *data-parallel*: the nC context of a

@@ -20,7 +20,6 @@ Split of work (TPU-first, SURVEY.md §7 step 6):
 from __future__ import annotations
 
 import logging
-import os
 import time
 from collections import deque
 from dataclasses import dataclass
@@ -278,7 +277,7 @@ class H264StripeEncoder:
                  paint_over_trigger_frames: int = 15,
                  search: int = 12, fullframe: bool = False,
                  cap_frac: int = 8,
-                 entropy: Optional[str] = None) -> None:
+                 entropy: str = "device") -> None:
         if width % 2 or height % 2:
             raise ValueError("frame dimensions must be even")
         if stripe_height % MB:
@@ -337,12 +336,11 @@ class H264StripeEncoder:
 
         #: entropy tier for P frames (docs/entropy.md): "device" packs
         #: bit-exact CAVLC payloads on TPU (encoder/device_cavlc.py) so
-        #: the fetch is the ~12 KB bitstream itself and steady state
-        #: needs no host entropy threads; "host" ships the block-sparse
-        #: levels and runs native CAVLC.  IDR and overflow stripes use
-        #: the host path in both modes.
-        if entropy is None:
-            entropy = os.environ.get("SELKIES_TPU_H264_ENTROPY", "device")
+        #: the fetch is the bitstream itself (36.5 kB a frame of a 1080p
+        #: scroll: ledger, PR 29) and steady state needs no host entropy
+        #: threads; "host", the degradation ladder's rung, ships the
+        #: block-sparse levels and runs native CAVLC.  IDR and overflow
+        #: stripes use the host path in both modes.
         if entropy not in ("device", "host"):
             raise ValueError(f"entropy must be device|host, got {entropy!r}")
         self.entropy = entropy
@@ -355,27 +353,20 @@ class H264StripeEncoder:
         #: dev.fetch_prefix) every bucket from _prefix_small to _buf_bytes
         #: is a tier, so the prefix holds the whole frame and harvest
         #: re-reads nothing. Where the slice is compiled into the step
-        #: (the batch programs, the host-entropy step) a new size would
-        #: recompile it, so those keep two stable sizes: _prefix_small
-        #: for static/quiet content (the worst-case head every frame
-        #: would cost 10-30x the D2H bytes of an idle desktop) and
-        #: _batch_prefix, which an undershoot at that size grows
-        #: (bounded recompiles).
+        #: (the host-entropy step) a new size would recompile it, so
+        #: that rung keeps two stable sizes: _prefix_small for
+        #: static/quiet content (the worst-case head every frame would
+        #: cost 10-30x the D2H bytes of an idle desktop) and
+        #: _host_step_prefix for busy content (a frame past it is
+        #: re-read like any other undershoot).
         if entropy == "device":
             self._cavlc_msb = dcav.default_max_stripe_bytes(
                 self.pad_w // MB, sh // MB)
             self._fixed_bytes = dcav.HEAD_BYTES * self.n_stripes
             self._buf_bytes = self._fixed_bytes \
                 + self.n_stripes * self._cavlc_msb
-            # CAVLC payloads run ~4-6x smaller than the sparse cells. A
-            # full-damage 1080p scroll at crf 25 is 96 kB a frame
-            # (PERF.md), three times what pixels/80 (the 32 KB bucket)
-            # holds: only the batch programs still fetch that size, and
-            # fall back to the exact flat16 rows past it
+            # CAVLC payloads run ~4-6x smaller than the sparse cells
             self._sparse_guess = self._bucket(self._fixed_bytes + (16 << 10))
-            self._batch_prefix = self._bucket(
-                self._fixed_bytes
-                + max(24 << 10, self.pad_h * self.pad_w // 80))
         else:
             self._cavlc_msb = 0
             self._fixed_bytes = 4 * self.n_stripes \
@@ -386,7 +377,7 @@ class H264StripeEncoder:
                 self._fixed_bytes + (64 << 10))
             # worst-case full-damage content at streaming QPs runs
             # ~1/20 of the pixel count in sparse cells (scroll source)
-            self._batch_prefix = self._bucket(
+            self._host_step_prefix = self._bucket(
                 self._fixed_bytes
                 + max(96 << 10, self.pad_h * self.pad_w // 20))
         self._prefix_small = self._bucket(self._fixed_bytes + 4096)
@@ -418,7 +409,7 @@ class H264StripeEncoder:
         sizes compiled into the step."""
         if self._sparse_guess <= self._prefix_small:
             return self._prefix_small
-        return self._sparse_guess if every_bucket else self._batch_prefix
+        return self._sparse_guess if every_bucket else self._host_step_prefix
 
     def _prefix_tiers(self) -> List[int]:
         """Every size ``_choose_prefix(every_bucket=True)`` can return."""
@@ -446,14 +437,12 @@ class H264StripeEncoder:
 
     # -- encode ------------------------------------------------------------
 
-    def dispatch(self, rgb, fetch: bool = True) -> "_H264Pending":
+    def dispatch(self, rgb) -> "_H264Pending":
         """One dense device dispatch for the whole frame (every stripe);
         pair with :meth:`harvest`. Damage detection, reference-plane
         selection, and sparse level packing all happen inside the single
-        jit program — the host's only per-frame read is the packed buffer.
-
-        ``fetch=False`` skips starting the host copy; the caller owns the
-        transfer (PipelinedH264Encoder groups several frames per read)."""
+        jit program — the host's only per-frame read is the packed buffer,
+        whose copy to the host starts here."""
         rgb = jnp.asarray(rgb)
 
         is_idr = any(st.need_idr for st in self.stripes)
@@ -473,7 +462,6 @@ class H264StripeEncoder:
                     paint[i] = 1
                     st.painted_over = True
 
-        head = None
         # the device tier's slice is dev.fetch_prefix, a program of its
         # own: there the prefix follows the content bucket by bucket
         cavlc = self.entropy == "device"
@@ -492,8 +480,7 @@ class H264StripeEncoder:
                         jnp.int32(self.qp),
                         pad_h=self.pad_h, pad_w=self.pad_w,
                         n_stripes=self.n_stripes, sh=self.stripe_h)
-                pending_buf = None
-                fetch_arr = flat16 if fetch else None
+                buf, fetch_arr = None, flat16
             elif cavlc:
                 # on-device CAVLC: the fetch prefix is head + bit-exact
                 # P-slice payloads (device_cavlc.py); flat16 stays device-
@@ -509,19 +496,17 @@ class H264StripeEncoder:
                         n_stripes=self.n_stripes, sh=self.stripe_h,
                         search=self.search,
                         max_stripe_bytes=self._cavlc_msb,
-                        me=dev._me_backend())
+                        me=dev.ME)
                 if cold:
                     # with the step, every slice program the content can
                     # select later: none is left to compile in a stream
                     for tier in self._prefix_tiers():
                         dev.fetch_prefix(buf, prefix=tier)
-                head = dev.fetch_prefix(buf, prefix=prefix)
-                pending_buf = buf
-                fetch_arr = head if fetch else None
+                fetch_arr = dev.fetch_prefix(buf, prefix=prefix)
             else:
                 # the whole per-frame program — planes, encode, pack, and
                 # the fetch-prefix slice — is ONE dispatch
-                (buf, head, flat16,
+                (buf, fetch_arr, flat16,
                  self._prev_y, self._prev_cb, self._prev_cr,
                  self._ref_y, self._ref_cb, self._ref_cr) = \
                     dev.encode_frame_p_rgb(
@@ -536,133 +521,41 @@ class H264StripeEncoder:
                         # programs, no per-bucket recompile churn; undershoot
                         # re-reads from buf
                         search=self.search, prefix=prefix,
-                        cap_frac=self._cap_frac, me=dev._me_backend())
-                pending_buf = buf
-                fetch_arr = head if fetch else None
-        if fetch_arr is not None:
-            fetch_arr.copy_to_host_async()
+                        cap_frac=self._cap_frac, me=dev.ME)
+        fetch_arr.copy_to_host_async()
         qp_arr = np.where(paint != 0, self.paint_over_qp, self.qp)
         return _H264Pending(fetch=fetch_arr, flat16=flat16, is_idr=is_idr,
-                            paint=paint, qp=qp_arr, buf=pending_buf,
-                            head=head,
+                            paint=paint, qp=qp_arr, buf=buf,
                             cavlc=(not is_idr and cavlc),
-                            head_len=0 if is_idr else int(head.shape[0]))
+                            head_len=0 if is_idr else int(fetch_arr.shape[0]))
 
-    def dispatch_batch(self, rgbs, fetch: bool = True
-                       ) -> List["_H264Pending"]:
-        """Encode B sequential frames in ONE device dispatch.
-
-        ``rgbs``: (B, H, W, 3) uint8 (device or host). The P-frame
-        reference chain rides a scan inside the program
-        (dev.encode_frame_p_batch_rgb), so the fixed per-dispatch cost
-        is paid once per batch instead of per frame. Falls back to
-        per-frame dispatch while any stripe needs an IDR."""
-        B = int(rgbs.shape[0])
-        if any(st.need_idr for st in self.stripes):
-            # keyframe recovery must not wait on a compile: the single
-            # frame programs are already built, whereas a (B-1)-shaped
-            # batch scan would compile from scratch mid-recovery
-            return [self.dispatch(rgbs[b], fetch=fetch) for b in range(B)]
-        paints = np.zeros((B, self.n_stripes), np.int8)
-        for b in range(B):
-            for i, st in enumerate(self.stripes):
-                # forecast static_frames per in-batch offset (harvest has
-                # not advanced per-stripe history for frames still inside
-                # this batch): a stripe crossing the trigger mid-batch
-                # paints at the right frame, not up to B-1 frames late.
-                # If damage lands mid-batch instead, that frame emits at
-                # paint QP (extra quality, never a stale stripe).
-                if (st.static_frames + b >= self.paint_over_trigger
-                        and not st.painted_over):
-                    paints[b, i] = 1
-                    st.painted_over = True
-        qps = np.where(paints != 0, self.paint_over_qp, self.qp)
-        prefix = self._choose_prefix()
-        with self.compile_watch.first_use(
-                ("p_batch", B, self.entropy, prefix)):
-            if self.entropy == "device":
-                (heads, flat16s, self._prev_y, self._prev_cb, self._prev_cr,
-                 self._ref_y, self._ref_cb, self._ref_cr) = \
-                    dev.encode_frame_p_batch_cavlc_rgb(
-                        jnp.asarray(rgbs),
-                        self._prev_y, self._prev_cb, self._prev_cr,
-                        self._ref_y, self._ref_cb, self._ref_cr,
-                        jnp.asarray(paints, jnp.int32),
-                        jnp.full((B,), self.qp, jnp.int32),
-                        jnp.int32(self.paint_over_qp),
-                        pad_h=self.pad_h, pad_w=self.pad_w,
-                        n_stripes=self.n_stripes, sh=self.stripe_h,
-                        search=self.search,
-                        max_stripe_bytes=self._cavlc_msb,
-                        prefix=prefix, me=dev._me_backend())
-            else:
-                (heads, flat16s, self._prev_y, self._prev_cb, self._prev_cr,
-                 self._ref_y, self._ref_cb, self._ref_cr) = \
-                    dev.encode_frame_p_batch_rgb(
-                        jnp.asarray(rgbs),
-                        self._prev_y, self._prev_cb, self._prev_cr,
-                        self._ref_y, self._ref_cb, self._ref_cr,
-                        jnp.asarray(paints, jnp.int32),
-                        jnp.full((B,), self.qp, jnp.int32),
-                        jnp.int32(self.paint_over_qp),
-                        pad_h=self.pad_h, pad_w=self.pad_w,
-                        n_stripes=self.n_stripes, sh=self.stripe_h,
-                        search=self.search, prefix=prefix,
-                        cap_frac=self._cap_frac, me=dev._me_backend())
-        if fetch:
-            heads.copy_to_host_async()
-        cache: Dict[str, np.ndarray] = {}   # shared host copy of heads
-        return [_H264Pending(
-            fetch=None, flat16=None, is_idr=False, paint=paints[b],
-            qp=qps[b], batch_heads=heads, batch_flat16=flat16s,
-            batch_index=b, head_len=prefix,
-            cavlc=(self.entropy == "device"),
-            batch_cache=cache) for b in range(B)]
-
-    def _recover_undershoot(self, p: "_H264Pending", host, needed: int,
-                            ovf: np.ndarray, damage: np.ndarray):
+    def _recover_undershoot(self, p: "_H264Pending", host, needed: int):
         """Prediction-miss recovery shared by the sparse and device-CAVLC
         transfers: the frame after content got busier (rare, and counted
-        as no prefix hit).  Single-frame dispatches re-read the right
-        bucket from the full device buffer, with the slice program of that
-        tier; the read queues behind every step already dispatched.  Batch
-        dispatches keep no full buffer, so every emitting stripe falls
-        back to the exact flat16 rows and the pinned batch prefix grows
-        (bucketed → bounded recompiles).  Either way the guess takes
-        this frame in, so the next dispatches' prefix holds its like."""
+        as no prefix hit) re-reads the right bucket from the full device
+        buffer, with the slice program of that tier; the read queues
+        behind every step already dispatched.  The guess takes this frame
+        in, so the next dispatches' prefix holds its like."""
         if needed > len(host):
-            if p.buf is not None:
-                full = dev.fetch_prefix(p.buf, prefix=self._bucket(needed))
-                full.copy_to_host_async()
-                host = np.asarray(full)
-                self.d2h_refetch_bytes_total += host.nbytes
-            else:
-                ovf = ovf | damage | (p.paint != 0)
-                if len(host) >= self._batch_prefix:
-                    # undershoot at the LARGE prefix: worst-case head
-                    # really is bigger — grow it. An undershoot at the
-                    # small tier just means the scene got busy; the
-                    # guess below re-tiers it.
-                    self._batch_prefix = min(
-                        self._buf_bytes,
-                        self._bucket(needed + needed // 2))
+            full = dev.fetch_prefix(p.buf, prefix=self._bucket(needed))
+            full.copy_to_host_async()
+            host = np.asarray(full)
+            self.d2h_refetch_bytes_total += host.nbytes
         self._recent_needed.append(needed)
         largest = max(self._recent_needed)
         self._sparse_guess = self._bucket(
             max(largest + largest // 2, self._fixed_bytes + 4096))
-        return host, ovf
+        return host
 
     def _refetch_overflow_rows(self, p: "_H264Pending", damage, ovf):
         """Exact flat16 re-reads for overflow stripes, all started before
         any blocking (rare: |level| beyond the packed range)."""
-        if p.flat16 is None and p.batch_flat16 is not None:
-            p.flat16 = p.batch_flat16[p.batch_index]
         refetch = {}
         need_rows = [i for i in range(self.n_stripes)
                      if ovf[i] and (damage[i] or p.paint[i])]
         if len(need_rows) > 2:
-            # whole-frame fallback (batch undershoot): ONE read of the
-            # exact levels instead of a per-stripe RPC each
+            # ONE read of the frame's exact levels instead of a read a
+            # stripe
             rows_host = np.asarray(p.flat16)
             self.d2h_refetch_bytes_total += rows_host.nbytes
             refetch = {i: rows_host[i] for i in need_rows}
@@ -680,13 +573,7 @@ class H264StripeEncoder:
         levels). Must be called in dispatch order. ``host`` supplies the
         already-fetched bytes when a pipeline owns the transfer."""
         if host is None:
-            if p.batch_heads is not None:
-                # one device read shared by every frame of the batch
-                if p.batch_cache.get("heads") is None:
-                    p.batch_cache["heads"] = np.asarray(p.batch_heads)
-                host = p.batch_cache["heads"][p.batch_index]
-            else:
-                host = np.asarray(p.fetch)
+            host = np.asarray(p.fetch)
         S = self.n_stripes
         t_bits = base_words = None
         if p.is_idr:
@@ -707,8 +594,7 @@ class H264StripeEncoder:
             wc = np.minimum((t_bits + 31) // 32, self._cavlc_msb // 4)
             needed = self._fixed_bytes + 4 * int(base_words[-1] + wc[-1])
             self.prefix_hit_frames_total += needed <= len(host)
-            host, ovf = self._recover_undershoot(p, host, needed,
-                                                 ovf, damage)
+            host = self._recover_undershoot(p, host, needed)
             refetch = self._refetch_overflow_rows(p, damage, ovf)
         else:
             levels16 = None
@@ -719,8 +605,7 @@ class H264StripeEncoder:
             ovf = head[:, 3] != 0
             used = np.minimum(counts, self._cap_cells) * dev.CELL
             needed = self._fixed_bytes + int(used.sum())
-            host, ovf = self._recover_undershoot(p, host, needed,
-                                                 ovf, damage)
+            host = self._recover_undershoot(p, host, needed)
             bitmaps = host[4 * S:self._fixed_bytes] \
                 .reshape(S, self._n_cells // 8)
             starts = np.concatenate(
@@ -867,7 +752,7 @@ class H264StripeEncoder:
         paint = jax.ShapeDtypeStruct((self.n_stripes,), jnp.int32)
         common = dict(pad_h=self.pad_h, pad_w=self.pad_w,
                       n_stripes=self.n_stripes, sh=self.stripe_h,
-                      search=self.search, me=dev._me_backend())
+                      search=self.search, me=dev.ME)
         if self.entropy == "device":
             return dev.encode_frame_p_cavlc_rgb.lower(
                 rgb, *planes, paint, i32, i32,
@@ -887,12 +772,7 @@ class _H264Pending:
     paint: np.ndarray
     qp: np.ndarray
     buf: object = None          # full sparse device buffer (undershoot)
-    head: object = None         # prefix slice produced inside the program
     head_len: int = 0
-    batch_heads: object = None      # (B, prefix) heads of a batch dispatch
-    batch_flat16: object = None     # (B, S, words) exact levels
-    batch_index: int = 0
-    batch_cache: Optional[Dict] = None  # shared host copy across the batch
     cavlc: bool = False             # buffer holds device-CAVLC payloads
 
 

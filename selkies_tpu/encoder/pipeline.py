@@ -64,7 +64,7 @@ class _PipelineTelemetry:
         self._fetch_wait_ms: deque = deque(maxlen=256)
         self.inflight_batches_max = 0
         #: seq -> {stage: (t_start, t_end)} for harvested frames, pruned
-        #: oldest-first so an un-popping caller (bench loops, mesh) can
+        #: oldest-first so an un-popping caller (tests, mesh) can
         #: never grow it unboundedly
         self._trace_out: "dict" = {}
 
@@ -141,12 +141,10 @@ class _PipelineTelemetry:
 
 @dataclass
 class _FetchGroup:
-    """One D2H read and the frames it brings: several JPEG frames' packed
-    buffers concatenated on device (RPC-attached chips pay fixed
-    per-transfer latency and allow only a handful of concurrent reads, so
-    frames-per-read — not bytes — sets the fetch ceiling there), the
-    (B, prefix) heads of one H.264 batch program, or one H.264 frame's own
-    head."""
+    """One D2H read and the JPEG frames it brings: their packed buffers
+    concatenated on device. A ``fetch_group`` above 1 was sized for a
+    device that paid a fixed latency per read; whether this one still
+    wants it is ROADMAP D10."""
 
     arr: Any                        # device array, one async host copy
     stride: int = 0                 # member size in a 1-D concat; 0: one
@@ -204,8 +202,7 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
         self._meta_words = META_WORDS_PER_STRIPE * base.n_stripes
         self._guess = base._packer.bucket_words(8192)
         #: D2H / host-entropy accounting (observability/metrics.py gauges
-        #: d2h_bytes_per_frame + host_entropy_ms_per_frame; bench.py
-        #: emits both so the fetch-bottleneck claim stays measured)
+        #: d2h_bytes_per_frame + host_entropy_ms_per_frame)
         self.metrics = metrics
         self.d2h_bytes_total = 0
         self.host_entropy_ms_total = 0.0
@@ -289,8 +286,8 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
         ticket = None
         stage_iv = None
         if isinstance(frame, jnp.ndarray):
-            # Device-resident frame (e.g. DeviceScrollSource): must already
-            # be padded to the encoder geometry; skips the host staging copy.
+            # Device-resident frame: must already be padded to the
+            # encoder geometry; skips the host staging copy.
             if frame.shape != (b.pad_h, b.pad_w, 3):
                 raise ValueError(
                     f"device frame must be pre-padded to {(b.pad_h, b.pad_w, 3)}")
@@ -676,9 +673,7 @@ class ThreadedEncoderAdapter:
 @dataclass
 class _H264InFlight:
     seq: int
-    pending: Any                     # h264._H264Pending
-    group: Any = None                # _FetchGroup (P frames)
-    group_index: int = 0             # row of a batch program's heads
+    pending: Any                     # h264._H264Pending: holds the fetch
     host: Optional[np.ndarray] = None
     ticket: Optional[StagingTicket] = None
     #: per-frame stage intervals for the flight recorder
@@ -690,17 +685,15 @@ class PipelinedH264Encoder(_PipelineTelemetry):
 
     Every frame's host copy starts at its dispatch, right behind the
     frame's own step on the device queue: a P frame's fetch prefix
-    (sized by the encoder for the content, h264._choose_prefix), an IDR's
-    flat16 levels, a batch program's (B, prefix) heads in one read. On a
-    directly attached chip a read waits 0.07 ms (PERF.md); nothing is
-    concatenated across frames, so no transfer waits for the next frame's
-    dispatch and no program's shape depends on which prefixes met. In
-    steady state ``harvest`` issues no device program and waits for none.
+    (sized by the encoder for the content, h264._choose_prefix) or an
+    IDR's flat16 levels. On a directly attached chip a read waits
+    0.07 ms (PERF.md); nothing is concatenated across frames, so no
+    transfer waits for the next frame's dispatch and no program's shape
+    depends on which prefixes met. In steady state ``harvest`` issues no
+    device program and waits for none.
     """
 
-    def __init__(self, base, depth: int = 8, batch: int = 1,
-                 batch_deadline_s: Optional[float] = None,
-                 metrics=None) -> None:
+    def __init__(self, base, depth: int = 8, metrics=None) -> None:
         self.base = base
         self.depth = depth
         #: transfer accounting for the d2h_bytes_per_frame /
@@ -710,33 +703,12 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         self.d2h_bytes_total = 0
         self.frames_completed = 0
         self.frames_dropped_total = 0
-        #: frames encoded per device dispatch (dev.encode_frame_p_batch_rgb)
-        #: — RPC-attached transports pay per dispatch, so batch>1 divides
-        #: that cost; PCIe deployments keep 1 (no added latency)
-        self.batch = max(1, batch)
-        #: inactivity deadline at which poll(flush_partial=False)
-        #: dispatches a partial batch anyway. RE-ARMED by every submit
-        #: (ISSUE 12 satellite): the deadline detects a PAUSED caller —
-        #: no new frame within the window — not a slow one, so a stream
-        #: ticking slower than batch/deadline still accumulates full
-        #: batches instead of degrading to single-frame
-        #: dispatches forever (worst-case frame staleness stays bounded
-        #: at ``batch`` deadlines — see _batch_deadline_due).
-        if batch_deadline_s is None:
-            batch_deadline_s = max(0.05, 2.5 * self.batch / 60.0)
-        self.batch_deadline_s = batch_deadline_s
-        self._batch_t0 = 0.0        # first frame of the forming group
-        self._batch_last = 0.0      # last submit — re-arms the deadline
-        self._batch_frames: List[Any] = []
         self._inflight: deque[_H264InFlight] = deque()
         self._ready: List[Tuple[int, list]] = []
         self._seq = 0
-        #: donated H2D staging lanes (ISSUE 12): one ring per input shape
-        #: — single frames and stacked batches ping-pong independently so
-        #: alternating paths never thrash a shared ring
+        #: donated H2D staging lane (ISSUE 12), sized so every in-flight
+        #: frame can hold a slot without stalling the ring
         self._staging = StagingRing(depth=depth + 1)
-        self._staging_batch = StagingRing(
-            depth=max(2, -(-depth // self.batch) + 1))
         self._init_telemetry()
 
     @property
@@ -744,30 +716,16 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         return len(self._inflight)
 
     @property
-    def has_room(self) -> bool:
-        # frames buffered toward a batch hold a slot too (as in submit)
-        return len(self._inflight) + len(self._batch_frames) < self.depth
-
-    @property
     def inflight_batches(self) -> int:
-        """Dispatched-but-not-yet-materialized fetch units: a P frame's
-        head, a batch program's heads and an IDR's flat16 each count once
-        while their host copy is outstanding (the ISSUE 12 gauge)."""
-        groups = set()
-        solo = 0
-        for it in self._inflight:
-            if it.pending.is_idr:
-                if it.host is None:
-                    solo += 1
-            elif it.group.host is None:
-                groups.add(id(it.group))
-        return len(groups) + solo
+        """Frames dispatched whose host copy (a P frame's head, an IDR's
+        flat16) is still outstanding (the ISSUE 12 gauge)."""
+        return sum(1 for it in self._inflight if it.host is None)
 
     def stats(self) -> dict:
         """Per-frame transfer/host-entropy gauges over the run so far.
-        D2H counts grouped head fetches, solo IDR flat16 reads, and the
-        base encoder's undershoot/overflow re-reads; entropy ms is the
-        base harvest's host coding+glue wall time."""
+        D2H counts head fetches, IDR flat16 reads, and the base encoder's
+        undershoot/overflow re-reads; entropy ms is the base harvest's
+        host coding+glue wall time."""
         n = max(1, self.frames_completed)
         d2h = self.d2h_bytes_total \
             + getattr(self.base, "d2h_refetch_bytes_total", 0)
@@ -784,8 +742,7 @@ class PipelinedH264Encoder(_PipelineTelemetry):
                 self.base, "cavlc_low_tier_frames_total", 0),
             "prefix_hit_frames": getattr(
                 self.base, "prefix_hit_frames_total", 0),
-            "staging_stalls": (self._staging.stalls_total
-                               + self._staging_batch.stalls_total),
+            "staging_stalls": self._staging.stalls_total,
             **self._telemetry_stats(),
         }
 
@@ -823,34 +780,17 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             return None
         return self.submit(frame)
 
-    def _stage(self, frame, ring: StagingRing):
-        """Host frames ride the donated staging ring; device-resident
-        frames pass through untouched."""
-        if isinstance(frame, jnp.ndarray):
-            return frame, None
-        return ring.stage(np.asarray(frame, dtype=np.uint8))
-
     def submit(self, frame) -> int:
-        while len(self._inflight) + len(self._batch_frames) >= self.depth:
-            if not self._inflight:
-                self._flush_batch()
-                continue
+        """Dispatch one frame; blocks (harvesting the oldest) if full."""
+        while len(self._inflight) >= self.depth:
             self._ready.append(self._drain_one())
-        if self.batch > 1:
-            seq = self._seq + len(self._batch_frames)
-            now = time.monotonic()
-            if not self._batch_frames:
-                self._batch_t0 = now
-            self._batch_last = now      # every submit re-arms the deadline
-            self._batch_frames.append(frame)
-            if len(self._batch_frames) >= self.batch:
-                self._flush_batch()
-            return seq
-        return self._dispatch_solo(frame)
-
-    def _dispatch_solo(self, frame) -> int:
         ts0 = time.monotonic()
-        frame, slot = self._stage(frame, self._staging)
+        slot = None
+        if not isinstance(frame, jnp.ndarray):
+            # host frames ride the donated staging ring; device-resident
+            # frames pass through untouched
+            frame, slot = self._staging.stage(
+                np.asarray(frame, dtype=np.uint8))
         td0 = time.monotonic()
         try:
             # the encoder starts the frame's own host copy (head, or an
@@ -862,8 +802,6 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             self._staging.release(slot)
             raise
         item = _H264InFlight(seq=self._seq, pending=p,
-                             group=None if p.is_idr
-                             else _FetchGroup(arr=p.fetch),
                              ticket=StagingTicket(self._staging, slot))
         if slot is not None:
             self._mark(item.trace, "stage", ts0, td0)
@@ -874,123 +812,19 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         self._record_dispatch((td1 - ts0) * 1000.0)
         return item.seq
 
-    def submit_batch(self, rgbs) -> List[int]:
-        """Submit a pre-stacked (B, H, W, 3) array as one batch — the
-        zero-extra-dispatch path when the source can produce batches
-        (device batch sources, stacked host capture)."""
-        while len(self._inflight) >= self.depth:
-            self._ready.append(self._drain_one())
-        self._flush_batch()                  # keep ordering with singles
-        first = self._seq
-        self._dispatch_batch(rgbs)
-        return list(range(first, self._seq))
-
-    def _flush_batch(self) -> None:
-        """Dispatch the accumulated frames as one batched program; its
-        heads array doubles as the fetch group (one async read per
-        batch). Partial batches go through the already-compiled
-        single-frame program — a (B-k)-shaped batch scan would compile
-        from scratch for every distinct partial size. A deadline flush
-        landing here re-arms nothing: the NEXT group's window starts at
-        its own first submit, so a resumed stream returns to full
-        batches immediately."""
-        frames, self._batch_frames = self._batch_frames, []
-        if not frames:
-            return
-        if len(frames) < self.batch:
-            for i, frame in enumerate(frames):
-                try:
-                    self._dispatch_solo(frame)
-                except Exception:
-                    # the raising frame is the caller's error to count;
-                    # the not-yet-attempted remainder must not vanish
-                    # silently — they are drops, visible to the ladder
-                    # and health feed
-                    self._count_dropped(len(frames) - i - 1)
-                    raise
-            return
-        if any(not isinstance(f, jnp.ndarray) for f in frames):
-            # host frames: stack host-side and stage the whole batch
-            # through the donated batch lane (ONE H2D upload)
-            rgbs = np.stack([np.asarray(f, dtype=np.uint8) for f in frames])
-        else:
-            rgbs = jnp.stack(frames)
-        try:
-            self._dispatch_batch(rgbs)
-        except Exception:
-            # one exception surfaces to the caller; the other B-1
-            # frames of the failed batch are accounted as drops
-            self._count_dropped(len(frames) - 1)
-            raise
-
-    def _count_dropped(self, n: int) -> None:
-        if n <= 0:
-            return
-        self.frames_dropped_total += n
-        if self.metrics is not None:
-            self.metrics.inc_frames_dropped(n)
-
-    def _dispatch_batch(self, rgbs) -> None:
-        ts0 = time.monotonic()
-        rgbs, slot = self._stage(rgbs, self._staging_batch)
-        td0 = time.monotonic()
-        try:
-            # the encoder starts the transfers: the heads array in one
-            # read, or each frame's own where it fell back to single
-            # dispatches (IDR recovery)
-            pendings = self.base.dispatch_batch(rgbs)
-        except Exception:
-            self._staging_batch.release(slot)
-            raise
-        td1 = time.monotonic()
-        if slot is not None:
-            self._mark(None, "stage", ts0, td0)
-        self._mark(None, "dispatch", td0, td1)
-        # one staged buffer backs every frame of the batch: the ring slot
-        # frees when the LAST of them harvests
-        ticket = StagingTicket(self._staging_batch, slot,
-                               refs=len(pendings))
-        heads = None                # one group for the batch's heads
-        for p in pendings:
-            item = _H264InFlight(seq=self._seq, pending=p, ticket=ticket)
-            # one staged buffer + one program back the whole batch, so
-            # every member frame was gated by the same intervals
-            if slot is not None:
-                item.trace["stage"] = (ts0, td0)
-            item.trace["dispatch"] = (td0, td1)
-            self._seq += 1
-            self._inflight.append(item)
-            if p.batch_heads is not None:
-                if heads is None:
-                    heads = _FetchGroup(arr=p.batch_heads)
-                item.group, item.group_index = heads, p.batch_index
-            elif not p.is_idr:
-                item.group = _FetchGroup(arr=p.fetch)
-        self._record_dispatch((time.monotonic() - ts0) * 1000.0)
-
     def _advance(self, item: _H264InFlight, block: bool) -> bool:
-        p = item.pending
-        if p.is_idr:
-            if not block and not p.flat16.is_ready():
-                return False
-            if item.host is None:
-                tm0 = time.monotonic()
-                item.host = np.asarray(p.flat16)
-                tm1 = time.monotonic()
-                self._mark(item.trace, "fetch_wait", tm0, tm1)
-                self._record_fetch_wait((tm1 - tm0) * 1000.0)
-                self.d2h_bytes_total += item.host.nbytes
-            return True
-        if not block and not item.group.arr.is_ready():
+        """Bring the frame's host copy in; True once it is there (blocks
+        for it only where ``block``)."""
+        arr = item.pending.fetch
+        if not block and not arr.is_ready():
             return False
-        if item.group.host is None:
-            self._materialize(item.group)
-        if item.group.fetch_iv is not None:
-            item.trace["fetch_wait"] = item.group.fetch_iv
-        if item.group.host.ndim == 2:      # batched dispatch: (B, prefix)
-            item.host = item.group.host[item.group_index]
-        else:                              # the frame's own head
-            item.host = item.group.host
+        if item.host is None:
+            tm0 = time.monotonic()
+            item.host = np.asarray(arr)
+            tm1 = time.monotonic()
+            self._mark(item.trace, "fetch_wait", tm0, tm1)
+            self._record_fetch_wait((tm1 - tm0) * 1000.0)
+            self.d2h_bytes_total += item.host.nbytes
         return True
 
     @staticmethod
@@ -1025,36 +859,18 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         self._publish_metrics()
         return seq_out
 
-    def _batch_deadline_due(self) -> bool:
-        """True when the forming group should ship incomplete: the
-        caller went quiet for a full deadline since its LAST submit.
-        Staleness stays bounded without an extra age check — every
-        inter-submit gap under the deadline means the batch fills within
-        ``(batch-1)`` such gaps, so no frame ever waits longer than
-        ``batch * batch_deadline_s``."""
-        return time.monotonic() - self._batch_last > self.batch_deadline_s
-
     def poll(self, flush_partial: bool = True, wait: bool = False
              ) -> List[Tuple[int, list]]:
-        """Harvest completed frames in order. ``flush_partial`` dispatches
-        a partly filled batch at once (the low-latency choice; False
-        leaves that to the batch deadline and ``flush()``); ``wait``
-        first blocks until the oldest frame is in, as
-        PipelinedJpegEncoder.poll does.
+        """Harvest completed frames in order. ``wait`` first blocks until
+        the oldest frame is in, as PipelinedJpegEncoder.poll does;
+        ``flush_partial`` is that pipe's (every frame here has its own
+        read, so nothing is ever held back for a group).
 
         Results accumulate in ``self._ready`` and are swapped out only at
         the end: a harvest raising mid-pass must not discard the frames
         already completed this pass (they surface on the next call)."""
-        if self._batch_frames and (flush_partial
-                                   or self._batch_deadline_due()):
-            # deadline flush: frames buffered toward a batch must not wait
-            # forever when the caller pauses submission
-            self._flush_batch()
-        if wait:
-            if not self._inflight:
-                self._flush_batch()     # as submit does for a full pipe
-            if self._inflight:
-                self._ready.append(self._drain_one())
+        if wait and self._inflight:
+            self._ready.append(self._drain_one())
         while self._inflight and self._advance(self._inflight[0],
                                                block=False):
             self._ready.append(self._harvest_item(self._inflight.popleft()))
@@ -1063,17 +879,14 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         return out
 
     def flush(self) -> List[Tuple[int, list]]:
-        self._flush_batch()
         while self._inflight:
             self._ready.append(self._drain_one())
         out, self._ready = self._ready, []
         return out
 
     def close(self) -> None:
-        self._batch_frames.clear()
         self._inflight.clear()
         self._ready.clear()
         self._trace_out.clear()
         # a rebuilt pipeline must never inherit phantom-busy ring slots
         self._staging.release_all()
-        self._staging_batch.release_all()

@@ -189,7 +189,7 @@ class MeshH264Encoder:
                  use_paint_over_quality: bool = True,
                  paint_over_trigger_frames: int = 15,
                  search: int = dev.SEARCH, me: Optional[str] = None,
-                 entropy: Optional[str] = None) -> None:
+                 entropy: str = "device") -> None:
         n_sess_ax = mesh.shape["session"]
         self.n_stripe_ax = mesh.shape["stripe"]
         if n_sessions % n_sess_ax:
@@ -214,12 +214,12 @@ class MeshH264Encoder:
         self.paint_over_trigger = int(paint_over_trigger_frames)
         self.search = search
         if me is None:
-            # the served default is the solo encoder's (SELKIES_TPU_ME,
-            # "pallas"). A run that asked for Pallas interpreter mode
-            # (tests/conftest.py) gets the XLA search instead: the
-            # interpreter's expansion of the kernel under vmap+shard_map
-            # compiles for minutes on the CPU test mesh.
-            me = "xla" if pallas_interpret() else dev._me_backend()
+            # the served default is the solo encoder's (dev.ME). A run
+            # that asked for Pallas interpreter mode (tests/conftest.py)
+            # gets the XLA search instead: the interpreter's expansion
+            # of the kernel under vmap+shard_map compiles for minutes on
+            # the CPU test mesh.
+            me = "xla" if pallas_interpret() else dev.ME
         self.me = me
 
         n = (stripe_h // MB) * (self.pad_w // MB)
@@ -234,9 +234,6 @@ class MeshH264Encoder:
         #: entropy tier (docs/entropy.md): "device" packs CAVLC shard-
         #: local so steady state needs no host entropy threads; "host"
         #: ships sparse levels (the pre-ISSUE-1 path)
-        import os
-        if entropy is None:
-            entropy = os.environ.get("SELKIES_TPU_H264_ENTROPY", "device")
         if entropy not in ("device", "host"):
             raise ValueError(f"entropy must be device|host, got {entropy!r}")
         self.entropy = entropy

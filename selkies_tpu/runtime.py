@@ -1,12 +1,11 @@
 """Process-wide device/runtime policy shared by every entry point.
 
-One place for the three decisions that the server, ``chip_smoke.py`` and
-the bench scripts must make the same way:
+One place for the two decisions that the server, ``chip_smoke.py`` and
+the benchmark must make the same way:
 
   * where JAX's persistent compilation cache lives;
   * whether Pallas kernels run compiled or in the interpreter (something
-    a test run *asks for*, never something the program falls into);
-  * which device a measurement path is allowed to run on.
+    a test run *asks for*, never something the program falls into).
 """
 
 from __future__ import annotations
@@ -15,7 +14,7 @@ import contextlib
 import os
 import threading
 import time
-from typing import Dict, Hashable, Optional
+from typing import Hashable, Optional
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -57,24 +56,6 @@ def pallas_interpret() -> bool:
     is compiled for the attached device, and fails loudly where there is
     none to compile for."""
     return os.environ.get(INTERPRET_ENV, "").strip().lower() in _TRUE
-
-
-def require_tpu() -> Dict[str, object]:
-    """Gate for measurement paths: return the device as JAX reports it
-    (what every printed result names) on a TPU, exit non-zero anywhere
-    else. A number from a CPU run is never a device number, so the bench
-    scripts refuse to produce one."""
-    import jax
-
-    devs = jax.devices()
-    info = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-            "count": len(devs)}
-    if info["platform"] != "tpu":
-        raise SystemExit(
-            f"no TPU: jax reports platform={info['platform']!r} "
-            f"kind={info['kind']!r} count={info['count']} — this "
-            "measurement path does not fall back to another backend")
-    return info
 
 
 #: how long one first-use compile may hold an encoder's dispatch before a

@@ -252,8 +252,8 @@ def default_encoder_factory(
     rung change takes effect as a supervised pipeline restart.
 
     Device-entropy tiers ride the async pipeline driver (ISSUE 12,
-    docs/pipeline.md): a dedicated thread keeps >=2 batches in flight —
-    dispatch of batch N+1 overlapped with batch N's D2H fetch — so the
+    docs/pipeline.md): a dedicated thread keeps >=2 frames in flight —
+    dispatch of frame N+1 overlapped with frame N's D2H fetch — so the
     capture loop's submit/poll never touch the device and the served
     encode latency tracks the chip, not the dispatch/fetch floor. Host
     rungs keep the threaded adapter (their encode is synchronous by
@@ -264,17 +264,10 @@ def default_encoder_factory(
                                     PipelinedJpegEncoder,
                                     ThreadedEncoderAdapter)
 
-    #: frames encoded per device dispatch; >1 amortizes the fixed
-    #: dispatch cost at a latency cost (the re-armed batch deadline still
-    #: bounds staleness either way). The default of 1 and what a larger
-    #: batch buys have not been re-measured on a directly attached chip.
-    batch = max(1, int(os.environ.get("SELKIES_TPU_ASYNC_BATCH", "1")))
-
     ov = overrides or {}
     profile = ov.get("encoder", settings.encoder)
-    #: None → the encoder's own default (H.264 honors the
-    #: SELKIES_TPU_H264_ENTROPY env tier selection; JPEG is device)
-    entropy = ov.get("tpu_entropy")
+    #: the ladder's rung: "host" only through its override
+    entropy = ov.get("tpu_entropy") or "device"
     if profile in ("x264enc", "x264enc-striped"):
         from ..encoder.h264 import H264StripeEncoder
 
@@ -299,9 +292,7 @@ def default_encoder_factory(
             return ThreadedEncoderAdapter(
                 base, depth=3, wire_fullframe=(profile == "x264enc"))
         return AsyncEncodeDriver(
-            PipelinedH264Encoder(base, depth=max(4, 3 * batch),
-                                 batch=batch),
-            flush_partial_when_idle=(batch == 1),
+            PipelinedH264Encoder(base, depth=4),
             wire_fullframe=(profile == "x264enc"))
     base = JpegStripeEncoder(
         width,
@@ -314,7 +305,7 @@ def default_encoder_factory(
         use_paint_over_quality=ov.get(
             "use_paint_over_quality",
             settings.use_paint_over_quality.value),
-        entropy=entropy or "device",
+        entropy=entropy,
         watermark_path=str(settings.watermark_path),
         watermark_location=int(settings.watermark_location),
     )

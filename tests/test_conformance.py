@@ -295,7 +295,7 @@ def test_deblock_enabled_slice_header_decodes():
         enc = H264StripeEncoder(W, H, stripe_height=64, qp=44)
         out = []
         for t, f in enumerate((f0, f1)):
-            p = enc.dispatch(f, fetch=True)
+            p = enc.dispatch(f)
             host = np.asarray(p.fetch)
             if p.is_idr:
                 stripes = enc.harvest(p, host=host)

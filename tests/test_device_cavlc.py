@@ -146,7 +146,7 @@ def test_host_low_tier_count_equals_the_device_predicate(monkeypatch):
     w, h, sh = 256, 128, 64
     enc = H264StripeEncoder(w, h, stripe_height=sh, qp=26, search=4,
                             paint_over_trigger_frames=2, entropy="device")
-    pipe = PipelinedH264Encoder(enc, depth=2, batch=1)
+    pipe = PipelinedH264Encoder(enc, depth=2)
     msb = dcav.default_max_stripe_bytes(enc.pad_w // 16, sh // 16)
     heads = []
     parse = dcav.parse_cavlc_head

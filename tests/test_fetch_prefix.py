@@ -210,8 +210,12 @@ def test_no_program_is_left_to_compile_after_the_first_p_frame(monkeypatch):
 @pytest.mark.parametrize("entropy", ["device", "host"])
 def test_only_the_own_slice_program_gets_every_bucket(entropy):
     enc = H264StripeEncoder(W, H, stripe_height=STRIPE, entropy=entropy)
-    enc._sparse_guess = enc._bucket(enc._batch_prefix * 2)
-    assert enc._choose_prefix() == enc._batch_prefix
+    if entropy == "host":
+        enc._sparse_guess = enc._bucket(enc._host_step_prefix * 2)
+        assert enc._choose_prefix() == enc._host_step_prefix
+    else:                   # the size compiled into a step is that rung's
+        enc._sparse_guess = enc._bucket(enc._prefix_small * 8)
+        assert not hasattr(enc, "_host_step_prefix")
     assert enc._choose_prefix(every_bucket=True) == enc._sparse_guess
     enc._sparse_guess = enc._prefix_small
     assert enc._choose_prefix() == enc._choose_prefix(every_bucket=True) \

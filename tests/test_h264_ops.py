@@ -149,14 +149,20 @@ def test_batched_search_over_stripes():
     assert (np.asarray(best) == 0).all()
 
 
-def test_pipelined_h264_matches_synchronous():
-    """PipelinedH264Encoder (a fetch of its own per frame) must produce the
-    byte-identical stream the synchronous encoder does."""
-    import numpy as np
+@pytest.mark.parametrize("sequence", ["plain", "keyframes", "undershoot",
+                                      "flush_in_flight"])
+@pytest.mark.parametrize("rung", ["device", "host"])
+def test_pipelined_h264_matches_synchronous(rung, sequence):
+    """Each ladder rung's encoder behind the wrapper the factory gives it
+    (``PipelinedH264Encoder(depth=4)``: a fetch of its own per frame;
+    ``ThreadedEncoderAdapter(depth=3)``) must produce the byte-identical
+    stream the synchronous ``encode_frame`` does: over a plain run, with
+    keyframes requested mid-stream, with a prefix that holds no payload
+    (every P frame takes the undershoot re-read), and across a ``flush``
+    with three frames in flight."""
     from selkies_tpu.encoder.h264 import H264StripeEncoder
-    from selkies_tpu.encoder.pipeline import PipelinedH264Encoder
-
-    rng = np.random.default_rng(5)
+    from selkies_tpu.encoder.pipeline import (PipelinedH264Encoder,
+                                              ThreadedEncoderAdapter)
 
     def frame(t, h=96, w=160):
         yy, xx = np.mgrid[0:h, 0:w].astype(np.float32)
@@ -165,25 +171,81 @@ def test_pipelined_h264_matches_synchronous():
                     0, 255).astype(np.uint8)
         return f
 
-    a = H264StripeEncoder(160, 96, stripe_height=32, qp=24)
-    b = H264StripeEncoder(160, 96, stripe_height=32, qp=24)
-    pipe = PipelinedH264Encoder(b, depth=6)
+    n = 8
+    key_at = (3, 6) if sequence == "keyframes" else ()
+    a = H264StripeEncoder(160, 96, stripe_height=32, qp=24, entropy=rung)
+    b = H264StripeEncoder(160, 96, stripe_height=32, qp=24, entropy=rung)
+    if sequence == "undershoot":
+        # the head and nothing of the payload, whatever the content
+        b._choose_prefix = lambda every_bucket=False: b._fixed_bytes
+    pipe = (PipelinedH264Encoder(b, depth=4) if rung == "device"
+            else ThreadedEncoderAdapter(b, depth=3))
 
     want = []
-    for t in range(8):
+    for t in range(n):
+        if t in key_at:
+            a.request_keyframe()
         want.append([(s.y_start, s.is_key, s.annexb)
                      for s in a.encode_frame(frame(t))])
     got_frames = {}
-    for t in range(8):
-        pipe.submit(frame(t))
-        for seq, stripes in pipe.poll():
-            got_frames[seq] = stripes
-    for seq, stripes in pipe.flush():
-        got_frames[seq] = stripes
-    assert len(got_frames) == 8
-    for t in range(8):
+    try:
+        for t in range(n):
+            if t in key_at:
+                if rung == "host":
+                    # the adapter's worker takes a request with whichever
+                    # frame it dispatches next: name that frame
+                    got_frames.update(pipe.flush())
+                pipe.request_keyframe()     # device: frames in flight
+            pipe.submit(frame(t))
+            if sequence == "flush_in_flight" and t < 3:
+                if t == 2:
+                    flushed = pipe.flush()
+                    assert [seq for seq, _ in flushed] == [0, 1, 2]
+                    got_frames.update(flushed)
+                continue                    # no poll: three in flight
+            got_frames.update(pipe.poll())
+        got_frames.update(pipe.flush())
+    finally:
+        pipe.close()
+    assert sorted(got_frames) == list(range(n))
+    for t in range(n):
         got = [(s.y_start, s.is_key, s.annexb) for s in got_frames[t]]
         assert got == want[t], f"frame {t} diverged"
+    for t in key_at:
+        assert all(s.is_key for s in got_frames[t]) and got_frames[t]
+    if sequence == "undershoot":
+        assert b.d2h_refetch_bytes_total > a.d2h_refetch_bytes_total == 0
+        if rung == "device":
+            assert b.prefix_hit_frames_total == 0
+            assert b.cavlc_frames_total == n - 1
+
+
+def test_every_frame_program_has_a_caller():
+    """Every ``jax.jit``-wrapped name of encoder/h264_device.py is used
+    by code under selkies_tpu/ (its own definition and docstrings do not
+    count): a program nothing dispatches is deleted, not kept compiled
+    in the reader's head."""
+    import ast
+    import pathlib
+
+    import jax
+
+    import selkies_tpu
+    from selkies_tpu.encoder import h264_device as dev
+
+    jitted = {name for name, obj in vars(dev).items()
+              if isinstance(obj, type(jax.jit(lambda: 0)))
+              and getattr(obj, "__module__", None) == dev.__name__}
+    assert {"encode_frame_idr_rgb", "encode_frame_p_cavlc_rgb",
+            "encode_frame_p_rgb", "fetch_prefix"} <= jitted
+    used = set()
+    for path in pathlib.Path(selkies_tpu.__file__).parent.rglob("*.py"):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Name):
+                used.add(node.id)
+            elif isinstance(node, ast.Attribute):
+                used.add(node.attr)
+    assert sorted(jitted - used) == []
 
 
 def test_sparse_pack_roundtrip_exact():

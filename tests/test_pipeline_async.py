@@ -1,7 +1,7 @@
 """Async pipeline driver + donated staging ring (ISSUE 12).
 
 Covers the tentpole's safety obligations, not its throughput claims
-(bench.py measures those on the real chip):
+(benchmark/run.py measures those on the real chip):
 
 * in-flight depth is bounded — a full pipeline backpressures at the
   submit edge instead of growing without limit;
@@ -12,9 +12,6 @@ Covers the tentpole's safety obligations, not its throughput claims
   allocation instead;
 * a supervisor-style restart mid-flight (close + rebuild) neither
   deadlocks nor leaks a ring slot;
-* the batch deadline re-arms per submit, so slow or paused-then-resumed
-  streams return to full batches (the
-  PipelinedH264Encoder pause-degradation edge);
 * slow-marked soak: ~10 s under ``fetch.hang`` chaos with no wedge and
   no monotonic in-flight growth.
 """
@@ -33,9 +30,8 @@ from selkies_tpu.encoder.h264_device import StagingRing
 from selkies_tpu.robustness import FaultInjector
 
 
-#: geometries match test_h264_batch (128x96, stripe 32, batch 3) and
-#: test_jpeg_encoder (160x128, stripe 64): in a full tier-1 run the jit
-#: executables are already compiled and these tests ride the cache
+#: geometries match test_h264_batch (128x96, stripe 32) and
+#: test_jpeg_encoder (160x128, stripe 64)
 def _frame(h=128, w=160, seed=0):
     return np.random.RandomState(seed).randint(0, 255, (h, w, 3), np.uint8)
 
@@ -326,6 +322,81 @@ def _jpeg_driver(**kw):
     return AsyncEncodeDriver(pipe, **kw), pipe
 
 
+def test_the_driver_reads_a_pipe_only_through_its_interface():
+    """What the driver may ask of a pipe is ``depth``, ``has_room``,
+    ``n_inflight``, ``submit``, ``poll``, ``flush``, ``stats``,
+    ``pop_trace``, ``close`` and the few optional public names it probes
+    with getattr: never a private field of its neighbour."""
+    touched = []
+
+    class _Sealed(_StubPipe):
+        def __getattr__(self, name):        # only names _StubPipe lacks
+            if name.startswith("_"):
+                touched.append(name)
+                raise AssertionError(f"driver read pipe.{name}")
+            raise AttributeError(name)
+
+    drv = AsyncEncodeDriver(_Sealed(depth=2), submit_depth=4)
+    try:
+        for _ in range(4):
+            assert drv.try_submit(object()) is not None
+        got = []
+        deadline = time.monotonic() + 10.0
+        while len(got) < 4 and time.monotonic() < deadline:
+            got += drv.poll()               # an idle pass: sleep branch
+            time.sleep(0.01)
+        assert drv.try_submit(object()) is not None
+        got += drv.flush(timeout=10.0)
+        assert [seq for seq, _ in got] == list(range(5))
+        assert drv._thread.is_alive()
+    finally:
+        drv.close()
+        drv._thread.join(timeout=5.0)
+    assert drv.pipe.closed
+    assert touched == []
+
+
+@pytest.mark.parametrize("name, value", [
+    ("SELKIES_TPU_ASYNC_BATCH", "3"),
+    ("SELKIES_TPU_H264_ENTROPY", "host"),
+    ("SELKIES_TPU_ME", "scan"),
+])
+def test_no_environment_variable_selects_an_encode_path(
+        monkeypatch, name, value):
+    """The served H.264 path is one: the driver over a depth-4 pipe over
+    the device rung's encoder, dispatching the Pallas search. The
+    ladder's ``tpu_entropy`` override is the only way off it."""
+    from selkies_tpu.encoder import h264_device as dev
+    from selkies_tpu.encoder.pipeline import PipelinedH264Encoder
+    from selkies_tpu.server.data_server import default_encoder_factory
+    from selkies_tpu.settings import Settings
+
+    monkeypatch.setenv(name, value)
+    settings = Settings(argv=[], env={"SELKIES_ENCODER": "x264enc-striped"})
+    drv = default_encoder_factory(128, 96, settings)
+    try:
+        assert isinstance(drv, AsyncEncodeDriver)
+        assert isinstance(drv.pipe, PipelinedH264Encoder)
+        assert drv.pipe.depth == 4
+        base = drv.pipe.base
+        assert base.entropy == "device"
+
+        class _Seen(Exception):
+            pass
+
+        def spy(*args, me, **kwargs):       # no compile: the static
+            raise _Seen(me)                 # argument is all we ask
+
+        monkeypatch.setattr(dev, "encode_frame_p_cavlc_rgb", spy)
+        for st in base.stripes:
+            st.need_idr = False             # straight to a P dispatch
+        with pytest.raises(_Seen) as seen:
+            base.dispatch(_frame(96, 128))
+        assert seen.value.args == ("pallas",)
+    finally:
+        drv.close()
+
+
 def test_driver_streams_real_jpeg_and_reports_gauges():
     drv, pipe = _jpeg_driver()
     try:
@@ -376,93 +447,6 @@ def test_restart_midflight_releases_ring_and_recovers():
         assert pipe2._staging.in_use == 0
     finally:
         drv2.close()
-
-
-# ---------------------------------------------------------------------------
-# batch deadline re-arm (the pause-degradation edge)
-
-
-def test_deadline_flush_rearms_group_for_resumed_stream():
-    from selkies_tpu.encoder.h264 import H264StripeEncoder
-    from selkies_tpu.encoder.pipeline import PipelinedH264Encoder
-
-    enc = H264StripeEncoder(128, 96, stripe_height=32)
-    calls = {"solo": 0, "batch": 0}
-    orig_d, orig_db = enc.dispatch, enc.dispatch_batch
-
-    def d(frame, fetch=True):
-        calls["solo"] += 1
-        return orig_d(frame, fetch=fetch)
-
-    def db(rgbs, fetch=True):
-        calls["batch"] += 1
-        return orig_db(rgbs, fetch=fetch)
-
-    enc.dispatch, enc.dispatch_batch = d, db
-    pipe = PipelinedH264Encoder(enc, depth=12, batch=3,
-                                batch_deadline_s=0.15)
-    for i in range(4):                      # warm: IDR + compiles
-        pipe.submit(_frame(96, 128, seed=i))
-    pipe.flush()
-    calls["solo"] = calls["batch"] = 0
-
-    # a stream ticking slower than deadline/batch still forms full
-    # batches: the deadline re-arms on every submit (pause detection),
-    # it does not run down from the group's first frame
-    for i in range(9):
-        pipe.submit(_frame(96, 128, seed=i))
-        time.sleep(0.05)                    # 0.05 < 0.15 — still live
-        pipe.poll(flush_partial=False)
-    pipe.flush()
-    assert calls["batch"] == 3
-    assert calls["solo"] == 0
-
-    # a PAUSE flushes the partial group (liveness)...
-    calls["solo"] = calls["batch"] = 0
-    pipe.submit(_frame(96, 128, seed=100))
-    deadline = time.monotonic() + 10.0
-    while not calls["solo"] and time.monotonic() < deadline:
-        time.sleep(0.03)
-        pipe.poll(flush_partial=False)
-    assert calls["solo"] == 1               # partial shipped solo
-    pipe.flush()
-
-    # ...and the RESUMED stream returns to full batching immediately
-    calls["solo"] = calls["batch"] = 0
-    for i in range(6):
-        pipe.submit(_frame(96, 128, seed=i))
-        pipe.poll(flush_partial=False)
-    pipe.flush()
-    assert calls["batch"] == 2
-    assert calls["solo"] == 0
-
-
-def test_staleness_bounded_under_sub_deadline_cadence():
-    """Frame staleness is intrinsically bounded at batch * deadline:
-    every inter-submit gap under the deadline means the batch fills
-    within (batch - 1) such gaps — a steadily ticking stream's frames
-    always ship, batched, within the bound."""
-    from selkies_tpu.encoder.h264 import H264StripeEncoder
-    from selkies_tpu.encoder.pipeline import PipelinedH264Encoder
-
-    enc = H264StripeEncoder(128, 96, stripe_height=32)
-    pipe = PipelinedH264Encoder(enc, depth=12, batch=3,
-                                batch_deadline_s=0.08)
-    for i in range(6):                       # warm solo + batch programs
-        pipe.submit(_frame(96, 128, seed=i))
-    pipe.flush()
-    t0 = time.monotonic()
-    shipped_at = None
-    for i in range(12):
-        pipe.submit(_frame(96, 128, seed=i))
-        time.sleep(0.04)                     # < deadline: never a pause
-        if pipe.poll(flush_partial=False):
-            shipped_at = time.monotonic() - t0
-            break
-    # the first full batch ships well inside batch * deadline worth of
-    # submit gaps (plus device time), never stranded
-    assert shipped_at is not None
-    pipe.flush()
 
 
 def test_midpass_harvest_error_preserves_completed_frames_and_tickets():
