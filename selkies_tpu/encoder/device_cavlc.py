@@ -63,16 +63,26 @@ MB = 16
 #: MB header unit is ≤ ~90 bits.  Anything larger flags overflow.
 UNIT_WORDS = 16
 
-#: The output stage (word assembly + frame compaction) comes in two sizes:
-#: the stripe capacity V = ``max_stripe_bytes // 4`` words, and V divided
-#: by this. A frame whose largest stripe fits the small one takes it; the
-#: predicate reads the frame's own ``t_bits`` (:func:`takes_low_tier`).
-#: Set from the served 1080p scroll (PERF.md, PR 26): V = 32,768 words a
-#: stripe, of which the mean stripe fills ~1,550 (6.2 kB); V // 4 = 8,192
-#: words = 32 KiB = 64 B/MB is 5x that mean and 2.4x the ~27 B/MB of
-#: streaming QPs, and the scalar-core gathers that build each output word
-#: cost what the tier holds, not what the frame produced.
-LOW_TIER_DIV = 4
+#: The output stage (word assembly + frame compaction) comes in a ladder of
+#: sizes (:func:`tier_words`): the stripe capacity V = ``max_stripe_bytes
+#: // 4`` words, then V / 4, V / 16, ... for as long as a rung still holds
+#: ``TIER_FLOOR_BYTES_PER_MB`` bytes for each macroblock of a stripe. A
+#: frame runs the smallest rung that its largest stripe fits, read from its
+#: own ``t_bits`` (:func:`tier_index`): the scalar-core gathers that build
+#: each output word cost what the rung holds, not what the frame produced.
+TIER_RATIO = 4
+
+#: Set from the served 1080p scroll (PERF.md, PR 33): stripes of 480
+#: macroblocks, V = 32,768 words (273 B/MB). Its frames are 30-36 kB: the
+#: mean stripe fills 450-530 words (4 B/MB), and the largest stripe of a
+#: frame, which picks the rung, 860-880 in the median. The rungs there are
+#: V, V / 4 = 32 KiB (68 B/MB: the frames after a standstill of the
+#: machine, and the ~27 B/MB of streaming QPs) and V / 16 = 8 KiB = 2,048
+#: words (17 B/MB): 99.0-99.7% of a window's P frames fit it, 87% would
+#: fit half of it, and V / 64 (4.3 B/MB) holds the mean stripe only. The
+#: floor is in bytes per macroblock so that a stripe of another size (the
+#: full-frame profile: 8,160 macroblocks) gets its rungs from the same rule.
+TIER_FLOOR_BYTES_PER_MB = 16
 
 #: fixed per-stripe head: t_bits u32 LE, base_words u32 LE, damage, ovf,
 #: 2 pad bytes
@@ -508,16 +518,23 @@ def _compact(words_stripe, wc, base_words):
     return jnp.where(valid, words_stripe.reshape(-1)[src], 0)
 
 
-def low_tier_words(max_stripe_bytes: int) -> int:
-    """Words a stripe of the low output tier holds (``LOW_TIER_DIV``)."""
-    return max_stripe_bytes // 4 // LOW_TIER_DIV
+def tier_words(max_stripe_bytes: int, n_mb: int) -> Tuple[int, ...]:
+    """The output stage's ladder for stripes of ``n_mb`` macroblocks: words
+    a stripe, from the capacity down (``TIER_RATIO``,
+    ``TIER_FLOOR_BYTES_PER_MB``)."""
+    rungs = [max_stripe_bytes // 4]
+    while 4 * (rungs[-1] // TIER_RATIO) >= TIER_FLOOR_BYTES_PER_MB * n_mb:
+        rungs.append(rungs[-1] // TIER_RATIO)
+    return tuple(rungs)
 
 
-def takes_low_tier(t_bits, max_stripe_bytes: int):
-    """Does a frame with these per-stripe payload bits ([S], device or
-    host array) fit the low output tier? Stripes outside the update mask
-    pack nothing and read 0, so the maximum is over the updated ones."""
-    return t_bits.max() <= 32 * low_tier_words(max_stripe_bytes)
+def tier_index(t_bits, rungs: Tuple[int, ...]):
+    """The rung of ``rungs`` a frame with these per-stripe payload bits
+    ([S], device or host array) takes: how many of the smaller rungs its
+    largest stripe fits under, so 0 is the capacity and ``len(rungs) - 1``
+    the low tier. Stripes outside the update mask pack nothing and read
+    0, so the maximum is over the updated ones."""
+    return (t_bits.max() <= 32 * np.asarray(rungs[1:], np.int32)).sum()
 
 
 # ---------------------------------------------------------------------------
@@ -542,10 +559,10 @@ def pack_p_frame_words(mv, luma, chroma_dc, chroma_ac, update, *,
     chroma_dc [S, n, 2, 2, 2]; chroma_ac [S, n, 2, 4, 4, 4] (position 0
     zeroed); update [S] bool — stripes outside the mask pack nothing.
 
-    The output stage runs in one of two sizes chosen by the frame's own
-    bits (``LOW_TIER_DIV``); ``tiered=False`` keeps the one capacity-sized
+    The output stage runs at the rung of :func:`tier_words` that the
+    frame's own bits pick; ``tiered=False`` keeps the one capacity-sized
     body, for a caller that runs the pack under ``jax.vmap``, where a
-    batched predicate makes ``cond`` a ``select`` that runs both.
+    batched index makes ``switch`` a ``select`` that runs every branch.
 
     Returns (words [cap_words] u32 — per-stripe P-slice payloads (post
     slice header, MSB-first) compacted back-to-back word-aligned;
@@ -746,16 +763,13 @@ def pack_p_frame_words(mv, luma, chroma_dc, chroma_ac, update, *,
             return jnp.pad(out, (0, cap_words - S * v_out))
         return run
 
-    spans = (cs0, cs1, g0, e, wc, base_words)
-    v_lo = low_tier_words(max_stripe_bytes)
-    if tiered and v_lo:
-        # same words either way: in the low tier every stripe's wc is at
-        # most v_lo, so S * v_lo slots hold the whole frame and the rest
-        # of the buffer is the zeros the high tier would have put there
-        words = jax.lax.cond(takes_low_tier(t_bits, max_stripe_bytes),
-                             output_stage(v_lo), output_stage(V), *spans)
-    else:
-        words = output_stage(V)(*spans)
+    # same words on every rung: a frame takes a rung only if every
+    # stripe's wc fits it, so S * v_out slots hold the whole frame and the
+    # rest of the buffer is the zeros the capacity-sized body puts there
+    rungs = tier_words(max_stripe_bytes, n) if tiered else (V,)
+    words = jax.lax.switch(
+        tier_index(t_bits, rungs), [output_stage(v) for v in rungs],
+        cs0, cs1, g0, e, wc, base_words)
 
     # a slot may span at most 2 words (len ≤ 32); exp-Golomb header slots
     # are the only unbounded-by-table lengths and stay ≤ 31 bits for any

@@ -393,10 +393,14 @@ class H264StripeEncoder:
         #: on (ISSUE 2: rung device -> host -> jpeg)
         self.entropy_errors_total = 0
         #: P frames harvested from the device-CAVLC pack, and those of
-        #: them whose bits fit its low output tier: the predicate the
-        #: device branched on, read from the same t_bits
+        #: them that ran the lowest rung of its output stage: the index
+        #: the device branched on, read from the same t_bits. And what the
+        #: stage paid for against what it carried: output words of the
+        #: rungs the frames took, and payload words that landed in them
         self.cavlc_frames_total = 0
         self.cavlc_low_tier_frames_total = 0
+        self.cavlc_tier_words_total = 0
+        self.cavlc_payload_words_total = 0
         #: and those of them whose fetched prefix held the whole payload,
         #: so that harvest made no undershoot re-read
         self.prefix_hit_frames_total = 0
@@ -575,6 +579,8 @@ class H264StripeEncoder:
         if host is None:
             host = np.asarray(p.fetch)
         S = self.n_stripes
+        mb_w = self.pad_w // MB
+        mb_h = self.stripe_h // MB
         t_bits = base_words = None
         if p.is_idr:
             levels16 = host
@@ -584,14 +590,17 @@ class H264StripeEncoder:
             # device-CAVLC transfer: head + bit-exact slice payloads
             levels16 = None
             t_bits, base_words, damage, ovf = dcav.parse_cavlc_head(host, S)
-            self.cavlc_frames_total += 1
-            self.cavlc_low_tier_frames_total += bool(
-                dcav.takes_low_tier(t_bits, self._cavlc_msb))
             # mirror the device's per-stripe word clip: an overflowing
             # stripe records its unclipped t_bits but compacts at most V
             # words, and an unclipped estimate here would force a
             # full-buffer refetch exactly on busy content
             wc = np.minimum((t_bits + 31) // 32, self._cavlc_msb // 4)
+            rungs = dcav.tier_words(self._cavlc_msb, mb_w * mb_h)
+            rung = int(dcav.tier_index(t_bits, rungs))
+            self.cavlc_frames_total += 1
+            self.cavlc_low_tier_frames_total += rung == len(rungs) - 1
+            self.cavlc_tier_words_total += S * rungs[rung]
+            self.cavlc_payload_words_total += int(wc.sum())
             needed = self._fixed_bytes + 4 * int(base_words[-1] + wc[-1])
             self.prefix_hit_frames_total += needed <= len(host)
             host = self._recover_undershoot(p, host, needed)
@@ -613,8 +622,6 @@ class H264StripeEncoder:
             refetch = self._refetch_overflow_rows(p, damage, ovf)
 
         out: List[H264Stripe] = []
-        mb_w = self.pad_w // MB
-        mb_h = self.stripe_h // MB
         jobs: List[tuple] = []
         for i, st in enumerate(self.stripes):
             if p.is_idr:

@@ -740,6 +740,10 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             "cavlc_frames": getattr(self.base, "cavlc_frames_total", 0),
             "cavlc_low_tier_frames": getattr(
                 self.base, "cavlc_low_tier_frames_total", 0),
+            "cavlc_payload_words": getattr(
+                self.base, "cavlc_payload_words_total", 0),
+            "cavlc_tier_words": getattr(
+                self.base, "cavlc_tier_words_total", 0),
             "prefix_hit_frames": getattr(
                 self.base, "prefix_hit_frames_total", 0),
             "staging_stalls": self._staging.stalls_total,
@@ -756,6 +760,8 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             if st["cavlc_frames"]:
                 self.metrics.set_cavlc_low_tier_share(
                     st["cavlc_low_tier_frames"] / st["cavlc_frames"])
+                self.metrics.set_cavlc_tier_fill_share(
+                    st["cavlc_payload_words"] / st["cavlc_tier_words"])
                 self.metrics.set_fetch_prefix_hit_share(
                     st["prefix_hit_frames"] / st["cavlc_frames"])
 

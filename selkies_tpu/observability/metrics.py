@@ -91,8 +91,14 @@ class Metrics:
             registry=self.registry)
         self.cavlc_low_tier_share = Gauge(
             "tpuenc_cavlc_low_tier_share", "Share of device-CAVLC P frames "
-            "whose largest stripe fit the pack's low output tier (the "
-            "cheap one; paint-over and busy frames take the high tier)",
+            "whose largest stripe fit the lowest rung of the pack's "
+            "output stage (the cheapest; frames after a stall, paint-over "
+            "and busy frames take a higher one)",
+            registry=self.registry)
+        self.cavlc_tier_fill_share = Gauge(
+            "tpuenc_cavlc_tier_fill_share", "Share of the output words the "
+            "device-CAVLC pack paid for (stripes x the rung each P frame "
+            "took) that carried payload",
             registry=self.registry)
         self.fetch_prefix_hit_share = Gauge(
             "tpuenc_fetch_prefix_hit_share", "Share of device-CAVLC P "
@@ -338,6 +344,10 @@ class Metrics:
     def set_cavlc_low_tier_share(self, share: float) -> None:
         if HAVE_PROM:
             self.cavlc_low_tier_share.set(share)
+
+    def set_cavlc_tier_fill_share(self, share: float) -> None:
+        if HAVE_PROM:
+            self.cavlc_tier_fill_share.set(share)
 
     def set_fetch_prefix_hit_share(self, share: float) -> None:
         if HAVE_PROM:

@@ -29,39 +29,81 @@ def _fuzz():
 # the whole seeded sweep (distinct geometries cost a CPU recompile each)
 GEOM = dict(mb_w=4, mb_h=2, S=2)
 
-#: (seed, max_stripe_bytes, the output tier the frame has to take). At 64 KiB
-#: a stripe of 8 macroblocks cannot leave the low tier (its limit is 128 kbit);
-#: at 2 KiB the limit is 4,096 bits and the capacity 16,384, so the same
-#: content lands under the limit (9, 14, 25), over it (0-3, 8) and past the
-#: capacity, where the stripe is flagged and the rest stays exact (5, 7, 30)
-CASES = [(seed, 65536, "low") for seed in range(12)] \
-    + [(seed, 2048, "high") for seed in (0, 1, 2, 3, 8, 5, 7, 30)] \
-    + [(seed, 2048, "low") for seed in (9, 14, 25)]
+#: (seed, max_stripe_bytes, the rung of the output ladder the frame has to
+#: take: 0 is the capacity). A stripe of 8 macroblocks gets ladders of five
+#: (64 KiB: 524,288 / 131,072 / 32,768 / 8,192 / 2,048 bits), four (8 KiB:
+#: 65,536 down to 1,024) and three (2 KiB: 16,384 / 4,096 / 1,024), and the
+#: same content lands on every rung that its bits can reach (no stripe of 8
+#: macroblocks holds the 131,072 bits that rung 0 of 64 KiB starts at) and
+#: past the capacity, where the stripe is flagged and the rest stays exact
+#: (2 KiB: 5, 7, 30)
+CASES = [(seed, 65536, rung) for seed, rung in enumerate(
+    (3, 3, 2, 3, 1, 2, 2, 1, 2, 3, 2, 2))] \
+    + [(25, 65536, 4), (16, 65536, 1)] \
+    + [(seed, 8192, rung) for seed, rung in (
+        (5, 0), (10, 0), (0, 1), (8, 1), (9, 2), (14, 2), (25, 3))] \
+    + [(seed, 2048, 0) for seed in (0, 1, 2, 3, 8, 5, 7, 30)] \
+    + [(9, 2048, 1), (14, 2048, 1), (25, 2048, 2)]
 
 
-@pytest.mark.parametrize("seed,max_stripe_bytes,tier", CASES)
-def test_device_pack_matches_native(seed, max_stripe_bytes, tier):
+@pytest.mark.parametrize("seed,max_stripe_bytes,rung", CASES)
+def test_device_pack_matches_native(seed, max_stripe_bytes, rung):
     """Bit-exact against native/cavlc.cpp, and the tiered pack's buffer
-    byte-identical to the single-tier body's, whichever tier it takes."""
+    byte-identical to the single-tier body's, whichever rung it takes."""
     fuzz = _fuzz()
     ok, why, _, took = fuzz.check_device_seed(
         seed, max_stripe_bytes=max_stripe_bytes, **GEOM)
     assert ok, why
-    assert took == tier
+    assert took == rung
 
 
-@pytest.mark.parametrize("side,tier", [("at", "low"), ("over", "high")])
-def test_device_pack_tier_boundary(side, tier):
-    """A stripe of exactly 32 * V_LO bits is the last the low tier takes;
-    one bit more goes to the high tier. Both stay bit-exact and
-    byte-identical to the single-tier body."""
+def test_cases_take_every_rung_at_two_capacities():
+    """The sweep above leaves no rung of the served shape (a ladder of
+    three or more: capacity, middle, low tier) untaken."""
+    from selkies_tpu.encoder import device_cavlc as dcav
+
+    n_mb = GEOM["mb_w"] * GEOM["mb_h"]
+    for msb, reachable in ((65536, (1, 2, 3, 4)), (8192, (0, 1, 2, 3)),
+                           (2048, (0, 1, 2))):
+        assert len(dcav.tier_words(msb, n_mb)) == reachable[-1] + 1
+        assert {r for _, m, r in CASES if m == msb} == set(reachable)
+
+
+@pytest.mark.parametrize("mb_w,mb_h,rungs", [
+    (120, 4, (32768, 8192, 2048)),         # the served 1080p stripe
+    (120, 68, (524288, 131072, 32768)),    # the full-frame profile
+    (240, 4, (65536, 16384, 4096)),        # a 4K stripe
+])
+def test_default_capacity_gets_three_rungs(mb_w, mb_h, rungs):
+    """The rungs follow the capacity and the stripe's macroblocks alone:
+    a default capacity (256-512 B/MB) ends at 16-32 B/MB."""
+    from selkies_tpu.encoder import device_cavlc as dcav
+
+    msb = dcav.default_max_stripe_bytes(mb_w, mb_h)
+    assert dcav.tier_words(msb, mb_w * mb_h) == rungs
+
+
+@pytest.mark.parametrize("side", ["at", "over"])
+@pytest.mark.parametrize("rung,density", [
+    (0, 0.3), (1, 0.3), (2, 0.3), (3, 0.3), (2, 0.06)])
+def test_device_pack_tier_boundary(rung, density, side):
+    """A stripe of exactly 32 x a rung's words is the last that rung
+    takes; one bit more goes to the rung above (past rung 0, the capacity,
+    the stripe is flagged). At density 0.3 the ladder goes on below the
+    boundary; at 0.06 the rung is the low tier of a ladder of three. All
+    stay bit-exact and byte-identical to the single-tier body."""
+    from selkies_tpu.encoder import device_cavlc as dcav
+
     fuzz = _fuzz()
-    at, over, msb = fuzz.boundary_frames(0, GEOM["mb_w"], GEOM["mb_h"])
+    at, over, msb = fuzz.boundary_frames(
+        0, GEOM["mb_w"], GEOM["mb_h"], rung=rung, density=density)
+    ladder = dcav.tier_words(msb, GEOM["mb_w"] * GEOM["mb_h"])
+    assert len(ladder) == (3 if density < 0.1 else rung + 2), ladder
     ok, why, n_ovf, took = fuzz.check_device_frame(
         *(at if side == "at" else over), mb_w=GEOM["mb_w"],
         mb_h=GEOM["mb_h"], qp=26, frame_num=3, max_stripe_bytes=msb)
     assert ok, why
-    assert (took, n_ovf) == (tier, 0)
+    assert (took, n_ovf) == fuzz.boundary_wants(rung)[side]
 
 
 @pytest.mark.parametrize("tiered", [True, False])
@@ -105,8 +147,8 @@ def test_device_pack_overflow_levels_flagged_and_rest_exact(tiered):
 def test_pack_under_vmap_equals_solo_pack():
     """The mesh lanes run the pack under ``jax.vmap`` with the single-tier
     body (parallel/mesh_h264.py): every lane's buffer must be the solo
-    tiered pack's, byte for byte, for a low-tier and a high-tier frame in
-    one batch."""
+    tiered pack's, byte for byte, for a frame of each rung of the ladder
+    in one batch."""
     import functools
 
     import jax
@@ -118,27 +160,33 @@ def test_pack_under_vmap_equals_solo_pack():
     mb_w, mb_h, S, msb = GEOM["mb_w"], GEOM["mb_h"], GEOM["S"], 2048
     frames = [fuzz.random_p_frame(np.random.default_rng(seed), S,
                                   mb_w * mb_h, density, 8)
-              for seed, density in ((1, 0.02), (2, 0.6))]
+              for seed, density in ((1, 0.0), (1, 0.02), (2, 0.6))]
     ones = jnp.ones((len(frames), S), bool)
     stacked = [jnp.asarray(np.stack(x)) for x in zip(*frames)]
     lane = functools.partial(dcav.pack_p_frame, mb_w=mb_w, mb_h=mb_h,
                              max_stripe_bytes=msb, tiered=False)
     lanes = np.asarray(jax.jit(jax.vmap(lane))(*stacked, ones, ones))
-    tiers = set()
+    rungs = dcav.tier_words(msb, mb_w * mb_h)
+    took = set()
     for k, frame in enumerate(frames):
         solo = fuzz.device_buffer(frame, mb_w, mb_h, msb)
         np.testing.assert_array_equal(lanes[k], solo)
-        tiers.add(bool(dcav.takes_low_tier(
-            dcav.parse_cavlc_head(solo, S)[0], msb)))
-    assert tiers == {True, False}
+        took.add(int(dcav.tier_index(
+            dcav.parse_cavlc_head(solo, S)[0], rungs)))
+    assert took == {0, 1, 2} == set(range(len(rungs)))
 
 
 def test_host_low_tier_count_equals_the_device_predicate(monkeypatch):
     """``cavlc_low_tier_frames`` / ``cavlc_frames`` in the pipeline's
     stats: the host counts, from the head it fetched, the P frames whose
-    largest stripe fit the device's low output tier. Held against the
-    device's predicate written out, over a sequence with busy frames, a
-    static stretch with its paint-over frame, and quiet frames."""
+    largest stripe fit the lowest rung of the device's output ladder, and
+    adds up the words of the rungs the frames took and the payload words
+    they carried. Held against the device's index rule written out (and
+    run as the device runs it), over a sequence that opens with an IDR
+    and has busy frames, a static stretch with its paint-over frame,
+    quiet frames and one between."""
+    import jax.numpy as jnp
+
     from selkies_tpu.encoder import device_cavlc as dcav
     from selkies_tpu.encoder.h264 import H264StripeEncoder
     from selkies_tpu.encoder.pipeline import PipelinedH264Encoder
@@ -163,16 +211,29 @@ def test_host_low_tier_count_equals_the_device_predicate(monkeypatch):
     yy, xx = np.mgrid[0:h, 0:w]
     smooth = [np.stack([(xx + 2 * t) % 255, yy % 255, (xx + yy) % 255],
                        -1).astype(np.uint8) for t in range(3)]
-    for frame in [noise[0], noise[1]] + [noise[1]] * 4 + smooth:
+    patched = smooth[2].copy()           # a few busy macroblocks: the
+    patched[:32, :64] = noise[0][:32, :64]           # middle rung
+    frames = [noise[0], noise[1]] + [noise[1]] * 4 + smooth + [patched]
+    for frame in frames:
         pipe.submit(frame)
         pipe.poll()
     pipe.flush()
     st = pipe.stats()
-    limit = 32 * (msb // 4 // dcav.LOW_TIER_DIV)
-    low = [int(t.max()) <= limit for t in heads]
-    assert st["cavlc_frames"] == len(heads) >= 8
-    assert st["cavlc_low_tier_frames"] == sum(low)
-    assert 0 < sum(low) < len(low), [int(t.max()) for t in heads]
+    V = msb // 4
+    rungs = (V, V // 4, V // 16)         # 64 B/MB and 16 B/MB of 64 MBs
+    assert dcav.tier_words(msb, 64) == rungs
+    took = [sum(int(t.max()) <= 32 * v for v in rungs[1:]) for t in heads]
+    assert took == [int(dcav.tier_index(jnp.asarray(t, jnp.int32), rungs))
+                    for t in heads]
+    # the IDR went the host coder's way and is in neither count
+    assert st["cavlc_frames"] == len(heads) == len(frames) - 1
+    assert st["cavlc_low_tier_frames"] == took.count(2)
+    assert st["cavlc_tier_words"] == sum(
+        enc.n_stripes * rungs[k] for k in took)
+    assert st["cavlc_payload_words"] == sum(
+        int(np.minimum((t + 31) // 32, V).sum()) for t in heads)
+    assert 0 < st["cavlc_payload_words"] < st["cavlc_tier_words"]
+    assert len(set(took)) == 3, str([int(t.max()) for t in heads])
 
 
 def test_update_mask_packs_nothing():

@@ -59,11 +59,13 @@ def test_metrics_d2h_and_host_entropy_gauges():
     m.set_d2h_bytes_per_frame(12700.0)
     m.set_host_entropy_ms_per_frame(0.4)
     m.set_cavlc_low_tier_share(0.75)
+    m.set_cavlc_tier_fill_share(0.25)
     m.set_fetch_prefix_hit_share(0.5)
     text = m.render().decode()
     assert "tpuenc_d2h_bytes_per_frame 12700.0" in text
     assert "tpuenc_host_entropy_ms_per_frame 0.4" in text
     assert "tpuenc_cavlc_low_tier_share 0.75" in text
+    assert "tpuenc_cavlc_tier_fill_share 0.25" in text
     assert "tpuenc_fetch_prefix_hit_share 0.5" in text
 
 

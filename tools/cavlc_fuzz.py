@@ -13,9 +13,9 @@ differential-fuzzes the ON-DEVICE CAVLC packer (encoder/device_cavlc.py)
 against the native _libselkies_cavlc.so reference over random P-frame level
 tensors — full residual surface (luma + chroma DC/AC), random MVs (skip/mvd
 paths), |level| > 127 edges and escape-overflow magnitudes — and random
-stripe capacities, so that frames land in the pack's low output tier, in
-its high tier and past the capacity, plus a constructed pair one bit either
-side of the tier boundary.  The tiered pack's buffer must equal the
+stripe capacities, so that frames land on every rung of the pack's output
+ladder and past the capacity, plus quiet frames (the low rungs, a desktop's
+own) and a constructed pair one bit either side of every rung boundary.  The tiered pack's buffer must equal the
 single-tier body's byte for byte; non-overflow stripes must be
 BIT-IDENTICAL to native; overflow stripes must be flagged (they take the
 flat16 + host fallback in the product).  tests/test_device_cavlc.py runs a
@@ -161,10 +161,11 @@ def check_device_frame(mv, luma, cdc, cac, *, mb_w, mb_h, qp, frame_num,
     """Differential: one frame's device pack + host glue vs native coder.
 
     The buffer of the tiered pack (the output stage sized by the frame's
-    bits) must equal the single-tier pack's byte for byte, whichever tier
+    bits) must equal the single-tier pack's byte for byte, whichever rung
     the frame takes; non-overflow stripes must then be bit-identical to
-    native.  Returns (ok, why, n_overflow, tier) with tier "low"/"high" —
-    the host's reading of the predicate the device branched on.  Overflow
+    native.  Returns (ok, why, n_overflow, rung) with rung the host's
+    reading of the index the device branched on: 0 is the capacity-sized
+    stage, ``len(tier_words(...)) - 1`` the low tier.  Overflow
     stripes are exempt from the bit-compare (the product recodes them
     from flat16 via the native path, which IS the reference — trivially
     identical) but must be flagged so that fallback actually engages.
@@ -177,12 +178,12 @@ def check_device_frame(mv, luma, cdc, cac, *, mb_w, mb_h, qp, frame_num,
         device_buffer((mv, luma, cdc, cac), mb_w, mb_h, max_stripe_bytes,
                       tiered) for tiered in (True, False)]
     t_bits, base_words, _, ovf = dcav.parse_cavlc_head(buf, S)
-    tier = "low" if dcav.takes_low_tier(t_bits, max_stripe_bytes) \
-        else "high"
+    rung = int(dcav.tier_index(
+        t_bits, dcav.tier_words(max_stripe_bytes, n_mb)))
     n_ovf = int(ovf.sum())
     if not np.array_equal(buf, single):
-        return False, f"{tier} tier's buffer differs from the " \
-            "single-tier body's", n_ovf, tier
+        return False, f"rung {rung}'s buffer differs from the " \
+            "single-tier body's", n_ovf, rung
 
     ldc = np.zeros((n_mb, 4, 4), np.int32)
     for s in range(S):
@@ -193,33 +194,45 @@ def check_device_frame(mv, luma, cdc, cac, *, mb_w, mb_h, qp, frame_num,
             mb_w=mb_w, mb_h=mb_h, qp=qp, frame_num=frame_num)
         pb, nbits = dcav.payload_slice(buf, S, base_words, t_bits, s)
         if dcav.assemble_p_slice(pb, nbits, qp, frame_num) != ref:
-            return False, f"stripe {s} bit mismatch", n_ovf, tier
-    return True, "", n_ovf, tier
+            return False, f"stripe {s} bit mismatch", n_ovf, rung
+    return True, "", n_ovf, rung
 
 
-#: per-stripe capacities the fuzz draws from: against stripes of a few
-#: hundred to ~100k bits they put frames in the low tier, in the high
-#: tier and past the capacity (the stripe-size overflow flag)
-STRIPE_BYTES = (512, 2048, 8192, 65536)
+#: per-stripe capacities the fuzz draws from, in bytes per macroblock (the
+#: served stripe has 273): against stripes of ~10 to ~1,600 B/MB they put
+#: frames on every rung of ladders of two to five (the floor is 16 B/MB)
+#: and past the capacity (the stripe-size overflow flag)
+STRIPE_BYTES_PER_MB = (64, 256, 1024, 8192)
+
+#: and no capacity past the served one: ``_stripe_words`` carries a
+#: unit's first and last word in 15 bits each, so a stripe past word
+#: 32,767 of a larger capacity comes out wrong and unflagged (PERF.md
+#: section 7, PR 33: found by this fuzz at 120x4 on the chip)
+MAX_STRIPE_BYTES = 4 << 15
 
 
 def check_device_seed(seed, mb_w=None, mb_h=None, S=2, qp=None,
-                      frame_num=None, max_stripe_bytes=None):
-    """:func:`check_device_frame` over one seed's random frame."""
+                      frame_num=None, max_stripe_bytes=None, density=None):
+    """:func:`check_device_frame` over one seed's random frame
+    (``density``: the share of coefficients that are not zero, where the
+    seed's own draw of 2-90% is not wanted)."""
     rng = np.random.default_rng(seed)
     mb_w = mb_w if mb_w is not None else int(rng.integers(2, 7))
     mb_h = mb_h if mb_h is not None else int(rng.integers(1, 4))
     qp = qp if qp is not None else int(rng.integers(10, 48))
     frame_num = frame_num if frame_num is not None else int(
         rng.integers(1, 16))
-    density = rng.uniform(0.02, 0.9)
+    drawn = rng.uniform(0.02, 0.9)
+    density = drawn if density is None else density
     # |level| > 127 (int8-sparse overflow) and escape-overflow (> ~2064)
     # edges both land regularly
     magnitude = int(rng.choice([1, 2, 8, 30, 127, 200, 2063, 2500]))
     mv, luma, cdc, cac = random_p_frame(rng, S, mb_w * mb_h, density,
                                         magnitude)
     if max_stripe_bytes is None:
-        max_stripe_bytes = int(rng.choice(STRIPE_BYTES))
+        max_stripe_bytes = min(
+            int(rng.choice(STRIPE_BYTES_PER_MB)) * mb_w * mb_h,
+            MAX_STRIPE_BYTES)
     return check_device_frame(
         mv, luma, cdc, cac, mb_w=mb_w, mb_h=mb_h, qp=qp,
         frame_num=frame_num, max_stripe_bytes=max_stripe_bytes)
@@ -230,16 +243,21 @@ def check_device_seed(seed, mb_w=None, mb_h=None, S=2, qp=None,
 _KNOB = [v for m in range(2, 9) for v in (m, -m)]
 
 
-def boundary_frames(seed, mb_w, mb_h, S=2):
-    """Two frames either side of the tier boundary, and the capacity that
+def boundary_frames(seed, mb_w, mb_h, S=2, rung=1, density=0.3):
+    """Two frames either side of a rung boundary, and the capacity that
     puts it there: (at, over, max_stripe_bytes).  Stripe 0 of ``at`` is
-    exactly 32 * V_LO bits long (the last bit the low tier takes), stripe
-    0 of ``over`` one bit longer; every other stripe is shorter."""
+    exactly as many bits long as rung ``rung`` of the capacity's ladder
+    holds (the last bit that rung takes), stripe 0 of ``over`` one bit
+    longer (the rung above; past rung 0, the capacity, the stripe is
+    flagged); every other stripe is shorter.  ``density`` sets how many
+    bytes per macroblock the boundary lies at, so how many rungs the
+    ladder has below it (0.3: ~130 B/MB, one rung more; 0.06: ~45 B/MB,
+    none: the boundary of the low tier)."""
     from selkies_tpu.encoder import device_cavlc as dcav
 
     rng = np.random.default_rng(seed)
     n_mb = mb_w * mb_h
-    mv, luma, cdc, cac = random_p_frame(rng, S, n_mb, 0.3, 8)
+    mv, luma, cdc, cac = random_p_frame(rng, S, n_mb, density, 8)
     luma[1:, n_mb // 2:] = 0             # the other stripes: shorter
 
     def with_knobs(steps):               # stripe 0, block 0 of three MBs
@@ -250,8 +268,8 @@ def boundary_frames(seed, mb_w, mb_h, S=2):
         return out
 
     def bits_of(lu):
-        return dcav.parse_cavlc_head(
-            device_buffer((mv, lu, cdc, cac), mb_w, mb_h, 65536), S)[0]
+        return dcav.parse_cavlc_head(device_buffer(
+            (mv, lu, cdc, cac), mb_w, mb_h, MAX_STRIPE_BYTES), S)[0]
 
     def steps_for(extra):                # 0..39 bits over three knobs
         return [min(13, max(0, extra - 13 * i)) for i in range(3)]
@@ -261,24 +279,40 @@ def boundary_frames(seed, mb_w, mb_h, S=2):
     t_at, t_over = bits_of(at), bits_of(over)
     assert t_at[0] % 32 == 0 and t_over[0] == t_at[0] + 1, (t_at, t_over)
     assert (t_at[1:] < t_at[0]).all(), t_at
-    msb = int(t_at[0]) // 2              # V_LO = msb/16 words = t_at bits
-    assert dcav.low_tier_words(msb) * 32 == t_at[0]
+    msb = int(t_at[0]) // 8 * dcav.TIER_RATIO ** rung
+    assert dcav.tier_words(msb, n_mb)[rung] * 32 == t_at[0]
     return (mv, at, cdc, cac), (mv, over, cdc, cac), msb
 
 
+def boundary_wants(rung):
+    """What the pair of :func:`boundary_frames` has to read: {side: (rung
+    taken, stripes flagged)}."""
+    return {"at": (rung, 0),
+            "over": (max(rung - 1, 0), int(rung == 0))}
+
+
+#: (rung, density) of the pairs main_device constructs: the boundaries of a
+#: ladder of three, the served shape: its capacity, V / 4 with V / 16 below
+#: it, and V / 16 as the low tier
+BOUNDARIES = ((0, 0.3), (1, 0.3), (2, 0.06))
+
+
 def main_device(n, geom=None):
-    """``n`` random frames, then the constructed pair either side of the
-    tier boundary. ``geom`` (mb_w, mb_h) pins one geometry: on the chip
-    every geometry and capacity is a compile of its own."""
+    """``n`` random frames, a quarter as many quiet ones, then the
+    constructed pairs either side of every rung boundary. ``geom`` (mb_w,
+    mb_h) pins one geometry: on the chip every geometry and capacity is a
+    compile of its own."""
     import collections
 
-    fails, n_ovf, tiers = [], 0, collections.Counter()
+    from selkies_tpu.encoder import device_cavlc as dcav
+
+    fails, n_ovf, rungs = [], 0, collections.Counter()
 
     def note(label, result):
         nonlocal n_ovf
-        ok, why, ovf, tier = result
+        ok, why, ovf, rung = result
         n_ovf += ovf
-        tiers[tier] += 1
+        rungs[rung] += 1
         if not ok:
             fails.append((label, why))
             print(f"{label}: FAIL ({why})")
@@ -286,21 +320,35 @@ def main_device(n, geom=None):
     mb_w, mb_h = geom or (None, None)
     for seed in range(n):
         note(f"seed {seed}", check_device_seed(seed, mb_w=mb_w, mb_h=mb_h))
+    # quiet frames (0.2-3% of the coefficients: 2-30 B/MB, a streaming
+    # desktop's), which the draw above all but never makes: the low rungs
+    n_quiet = n // 4
+    for seed in range(n_quiet):
+        note(f"quiet seed {seed}", check_device_seed(
+            seed, mb_w=mb_w, mb_h=mb_h, density=0.002 * (1 + seed % 16)))
     edges = [geom] if geom else [(4, 2), (6, 3), (5, 1)]
+    n_edge = 0
     for seed, (mb_w, mb_h) in enumerate(edges):
-        at, over, msb = boundary_frames(seed, mb_w, mb_h)
-        for name, frame, want in (("at", at, "low"), ("over", over, "high")):
-            result = check_device_frame(
-                *frame, mb_w=mb_w, mb_h=mb_h, qp=26, frame_num=3,
-                max_stripe_bytes=msb)
-            if result[0] and result[3] != want:
-                result = (False, f"took the {result[3]} tier") + result[2:]
-            note(f"boundary {mb_w}x{mb_h} {name}", result)
-    total = n + 2 * len(edges)
-    print(f"{total - len(fails)}/{total} passed ({n} random, "
-          f"{2 * len(edges)} at the tier boundary; {tiers['low']} low "
-          f"tier, {tiers['high']} high tier; {n_ovf} overflow stripes took "
-          "the flagged fallback)")
+        for rung, density in BOUNDARIES:
+            at, over, msb = boundary_frames(seed, mb_w, mb_h, rung=rung,
+                                            density=density)
+            ladder = dcav.tier_words(msb, mb_w * mb_h)
+            wants = boundary_wants(rung)
+            for name, frame in (("at", at), ("over", over)):
+                result = check_device_frame(
+                    *frame, mb_w=mb_w, mb_h=mb_h, qp=26, frame_num=3,
+                    max_stripe_bytes=msb)
+                if result[0] and (result[3], result[2]) != wants[name]:
+                    result = (False, f"took rung {result[3]} of {ladder} "
+                              f"with {result[2]} stripes flagged") + result[2:]
+                note(f"boundary {mb_w}x{mb_h} rung {rung} of {ladder} {name}",
+                     result)
+                n_edge += 1
+    total = n + n_quiet + n_edge
+    took = ", ".join(f"{rungs[k]} rung {k}" for k in sorted(rungs))
+    print(f"{total - len(fails)}/{total} passed ({n} random, {n_quiet} "
+          f"quiet, {n_edge} at the rung boundaries; {took}, rung 0 the "
+          f"capacity; {n_ovf} overflow stripes took the flagged fallback)")
     return 1 if fails else 0
 
 
