@@ -15,9 +15,12 @@ can pay.
 
 from __future__ import annotations
 
+import logging
 import re
 import threading
 from typing import Any, Dict, Optional
+
+logger = logging.getLogger("selkies_tpu.observability.device_phases")
 
 #: the scopes the step programs use, in the order a frame passes them
 PHASES = ("colour", "damage", "motion", "transform", "entropy")
@@ -26,12 +29,21 @@ OTHER = "other"
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _OPERAND = re.compile(r"%([\w.\-]+)")
+#: a scope path where a lowered module's text has it: a named location,
+#: ``loc("jit(step)/colour/mul"(#loc3))``, and inside a ``shard_map`` the
+#: path from there on, ``loc("vmap(colour)/mul"...)``; a file's location
+#: (``loc("/a/colour/b.py":27:18)``) is none
+_LOC_NAME = re.compile(r'loc\("([^"]*)"(?!:)')
 
 
 def phase_of_op_name(op_name: str) -> Optional[str]:
     """``jit(step)/jit(main)/colour/mul`` -> ``colour``: the outermost
-    component of the scope path that is a phase."""
+    component of the scope path that is a phase. A scope opened inside a
+    function that runs under ``jax.vmap`` (a mesh lane's step runs the
+    solo encode body so) reads ``vmap(colour)``."""
     for part in op_name.split("/"):
+        while part.startswith("vmap(") and part.endswith(")"):
+            part = part[5:-1]
         if part in PHASES:
             return part
     return None
@@ -89,14 +101,55 @@ def base_encoder(encoder) -> Any:
     return encoder
 
 
+def phases_named(text: str) -> set:
+    """The phases that any scope path in ``text`` names: a lowered
+    module's text with its locations, or an optimised module's with its
+    ``op_name`` metadata (the operations inside fusions included)."""
+    paths = _OP_NAME.findall(text) + _LOC_NAME.findall(text)
+    return {p for p in map(phase_of_op_name, paths) if p is not None}
+
+
+def _compiled_text(lower) -> str:
+    """The optimised HLO of ``lower()``, with scope names that are the
+    program's own. The persistent compile cache leaves metadata out of its
+    key, and scopes are metadata: after an edit that only renames or adds
+    scopes it hands back the executable of the tree that compiled the
+    step first, which names that tree's phases (PR 35 read a lane's
+    ``entropy`` as 0.0 ms so). Where the lowered program names a phase
+    that nothing in the loaded executable does, the step is compiled once
+    more under a key that holds the metadata: a cold compile, kept in the
+    cache under that key for the next reader of the same tree."""
+    lowered = lower()
+    text = lowered.compile().as_text()
+    missing = phases_named(lowered.as_text(debug_info=True)) \
+        - phases_named(text)
+    if not missing:
+        return text
+    logger.warning(
+        "the loaded step names no %s, which its source does: another "
+        "tree's executable from the compile cache; compiling it again "
+        "with the metadata in the key", sorted(missing))
+    import jax
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    # the lowering and its executable are memoised in the process too
+    jax.clear_caches()
+    jax.config.update(flag, True)
+    try:
+        return lower().compile().as_text()
+    finally:
+        jax.config.update(flag, before)
+
+
 def step_phases(encoder, timeout_s: float = 120.0
                 ) -> Optional[Dict[str, str]]:
     """The phase map of the step program ``encoder`` serves with (anything
     :func:`base_encoder` finds a ``lower_step()`` behind). The compile runs
     on a thread of its own and is given ``timeout_s``: from the cache it
     loads in seconds, and a caller after a served run must not sit through
-    a cold compile of minutes if the key should differ. None then, and
-    where the encoder has no ``lower_step``."""
+    a cold compile of minutes if the key should differ, or if the cache
+    held another tree's scope names (:func:`_compiled_text`). None then,
+    and where the encoder has no ``lower_step``."""
     base = base_encoder(encoder)
     lower = getattr(base, "lower_step", None)
     if lower is None:
@@ -105,7 +158,7 @@ def step_phases(encoder, timeout_s: float = 120.0
 
     def work() -> None:
         try:
-            box["map"] = phase_map(lower().compile().as_text())
+            box["map"] = phase_map(_compiled_text(lower))
         except BaseException as e:
             box["error"] = e
 

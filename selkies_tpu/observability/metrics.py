@@ -236,7 +236,7 @@ class Metrics:
         self.frame_stage_ms = Histogram(
             "frame_stage_ms", "Per-frame wall time in one pipeline stage "
             "(capture/submit_wait/pipe_wait/stage/dispatch/in_device/"
-            "fetch_wait/pack/harvest_wait/queue/send/ack)",
+            "fetch_wait/pack/lane_step/harvest_wait/queue/send/ack)",
             ("stage", "display"), buckets=_stage_buckets,
             registry=self.registry)
         self.glass_to_glass_ms = Histogram(

@@ -13,6 +13,9 @@ Every served frame carries a :class:`FrameTrace` — a trace context of
 
     capture -> stage -> dispatch -> fetch_wait -> pack -> queue -> send -> ack
 
+(the waits between them, and a mesh lane's ``lane_step``, are in
+:data:`STAGES`).
+
 Call sites mark stages with absolute monotonic intervals; the recorder
 never reads the clock on the hot path. A span is *closed* exactly once,
 with a terminal mark:
@@ -52,9 +55,11 @@ one write index each):
 
 * the **thread track**: ``(thread, state, t0, t1)`` for every state a
   worker thread enters (the ``tpuenc-async`` driver writes ``stage``,
-  ``dispatch``, ``fetch_wait``, ``pack``, ``emit`` and ``sleep``); time of
-  a running thread that no state covers is time it wanted to run and could
-  not (a lock, the interpreter) — readers call it ``other``;
+  ``dispatch``, ``fetch_wait``, ``pack``, ``emit`` and ``sleep``; a mesh
+  lane's ``mesh-encode`` worker the same but ``emit``: its frames are
+  taken by the sessions' polls); time of a running thread that no state
+  covers is time it wanted to run and could not (a lock, the interpreter)
+  — readers call it ``other``;
 * the **clock pairs**: ``(device, t_enqueued, t_ready)`` of the device
   probe (observability/device_probe.py), which pair this clock with the
   device's in any profiler trace;
@@ -77,24 +82,43 @@ __all__ = [
 #: the stages of a served frame's flight, in path order. Work stages time
 #: a thread working on the frame; wait stages (``*_wait``, ``in_device``,
 #: ``queue``) time the frame sitting somewhere, and are marked where the
-#: wait ends. Together they run from capture to ACK without a hole.
+#: wait ends. Together they run from capture to ACK without a hole, on the
+#: solo driver (``encoder/async_driver.py``) and on a mesh lane
+#: (``parallel/coordinator.py``) under the same names; ``lane_step`` alone
+#: lies across the others and is no part of the path.
 #:
 #: capture       host wall time in ``source.next_frame()``
-#: submit_wait   accepted into the driver's submit queue -> taken out
+#: submit_wait   accepted into the driver's submit queue -> taken out (a
+#:               lane: into the session's latest-wins mailbox -> the
+#:               worker's tick takes it; a capture that was replaced there
+#:               is not the one timed)
 #: pipe_wait     taken out -> its staging begins (behind the rest of the
-#:               pass's work, and in ``pipe.submit`` while the pipe is full)
-#: stage         H2D staging (donated ring copy / host batch stack)
+#:               pass's work, and in ``pipe.submit`` while the pipe is full;
+#:               a lane: the worker makes room in the in-flight window)
+#: stage         H2D staging (donated ring copy / host batch stack; a lane:
+#:               padding into the host batch, its private copy, device_put)
 #: dispatch      device program launch (not device compute)
 #: in_device     dispatch done -> the driver sees the result ready or
 #:               begins to block for it (queued and running on the device)
 #: fetch_wait    host time blocked materializing the D2H fetch
 #: pack          host-side entropy glue / stripe assembly
+#: lane_step     mesh lanes only: the worker's occupied time in the tick
+#:               that took the frame, from the take to the end of the lane's
+#:               part of the tick (room-making, staging, launch and every
+#:               harvest done there). Ticks that take no capture and only
+#:               harvest are in no frame's ``lane_step``, so 1000 / its
+#:               median is no bound on the lane's rate and does not follow
+#:               it (docs/observability.md, "Mesh sessions")
 #: harvest_wait  packed -> the capture loop's poll() takes the frame
 #: queue         dwell in the owner's bounded send queue
 #: send          transport send (websocket write)
 #: ack           send completion -> CLIENT_FRAME_ACK (network RTT + decode)
+#:
+#: An injected lane encoder that does not say when it launched keeps
+#: ``stage`` inside ``dispatch``; one without the harvest's split keeps
+#: ``pack`` inside ``fetch_wait``.
 STAGES = ("capture", "submit_wait", "pipe_wait", "stage", "dispatch",
-          "in_device", "fetch_wait", "pack", "harvest_wait",
+          "in_device", "fetch_wait", "pack", "lane_step", "harvest_wait",
           "queue", "send", "ack")
 
 #: states a worker thread writes to the thread track
@@ -108,9 +132,10 @@ class FrameTrace:
     """One frame's flight: (display, wire frame id) + stage intervals.
 
     ``spans`` maps stage name to an absolute ``(start, end)`` monotonic
-    interval. Stages may overlap or be missing (a mesh session folds
-    pack into fetch_wait; a host-rung frame has no device dispatch) —
-    consumers read durations per stage, never assume contiguity.
+    interval. Stages may overlap or be missing (a mesh lane's
+    ``lane_step`` lies across its frame's other stages; a host-rung frame
+    has no device dispatch) — consumers read durations per stage, never
+    assume contiguity.
     """
 
     __slots__ = ("display", "frame_id", "t0", "spans", "terminal",

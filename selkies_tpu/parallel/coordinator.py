@@ -70,6 +70,10 @@ LANE_BUILD_BLOCK_S = 30.0
 #: one lane across ALL coordinators, not one per bucket
 _lane_ids = itertools.count()
 
+#: the worker thread's name, and the name of its track in the flight
+#: recorder (the solo driver's is ``tpuenc-async``)
+WORKER_THREAD = "mesh-encode"
+
 
 def _p50(samples, ndigits: int = 3) -> float:
     """Median of a small sample window (0.0 when empty). Shared with
@@ -94,6 +98,7 @@ class MeshSessionFacade:
         self.closed = False
         #: see :meth:`try_submit`
         self.replaced_seq: Optional[int] = None
+        self._base: Any = None
 
     @property
     def slot(self) -> Optional[int]:
@@ -103,6 +108,30 @@ class MeshSessionFacade:
     @property
     def lane_id(self) -> Optional[int]:
         return self._coord._lane_of(self.sid)
+
+    @property
+    def recorder(self):
+        """The FlightRecorder the lane's worker writes its track to. The
+        server hands over the one it holds when a capture loop starts, as
+        it does to the solo driver; the sessions of a coordinator share
+        it."""
+        return getattr(self._coord, "recorder", None)
+
+    @recorder.setter
+    def recorder(self, rec) -> None:
+        self._coord.recorder = rec
+
+    @property
+    def base(self) -> Any:
+        """The lane encoder this session rides (observability/
+        device_phases.py asks it for ``lower_step``): the current lane's
+        while attached, the last one's once released, None where the
+        coordinator knows none."""
+        encoder_of = getattr(self._coord, "_encoder_of", None)
+        enc = encoder_of(self.sid) if encoder_of is not None else None
+        if enc is not None:
+            self._base = enc
+        return self._base
 
     def try_submit(self, frame) -> Optional[int]:
         """The seq the frame will harvest under; None when it took the
@@ -138,18 +167,18 @@ class MeshSessionFacade:
         return self._coord._compiling_for_s(self.sid)
 
     def pop_trace(self, seq: int):
-        """Flight-recorder stage intervals for a harvested frame.
-
-        The mesh encoders split the harvest wall into ``fetch_wait``
-        (D2H materialization, attributed per SFE stripe shard in their
-        ``last_harvest_stages``) and ``pack`` (host slice concat /
-        entropy glue); injected encoders without the split fall back to
-        whole-wall ``fetch_wait`` (docs/observability.md)."""
+        """Flight-recorder stage intervals for a harvested frame: the
+        solo driver's seven from ``submit_wait`` to ``pack``, tiling
+        acceptance to the harvest's end, and ``lane_step``
+        (``_harvest_oldest`` says where each begins and ends, and what an
+        injected encoder without the launch mark or the harvest's split
+        keeps coarse; docs/observability.md)."""
         return self._coord._pop_trace(self.sid, seq)
 
     def close(self) -> None:
         if not self.closed:
             self.closed = True
+            self._base = self.base     # for who asks once the lane is let go
             self._coord._release(self.sid)
 
 
@@ -159,7 +188,8 @@ class _Session:
 
     __slots__ = ("sid", "lane", "slot", "gen", "seq", "pending", "results",
                  "traces", "inflight", "staged", "want_key", "want_reset",
-                 "migrations_pending", "coded_bytes_total", "closed")
+                 "migrations_pending", "coded_bytes_total", "closed",
+                 "accepted_at")
 
     def __init__(self, sid: int, lane: "_Lane", slot: int) -> None:
         self.sid = sid
@@ -171,6 +201,9 @@ class _Session:
         self.gen = 0
         self.seq = 0
         self.pending: Any = None
+        #: when ``pending`` was accepted into the mailbox (latest wins:
+        #: a replacing capture brings its own time)
+        self.accepted_at = 0.0
         self.results: List[Tuple[int, list]] = []
         #: seq -> stage intervals for the flight recorder (bounded)
         self.traces: Dict[int, dict] = {}
@@ -185,6 +218,26 @@ class _Session:
         self.migrations_pending = 0
         self.coded_bytes_total = 0
         self.closed = False
+
+
+class _Batch(list):
+    """The ``(session, slot, generation)`` rows one tick took for one
+    lane, and the clock readings its frames share: they ride with the
+    rows through the in-flight window to the harvest, which writes them
+    into each frame's trace."""
+
+    __slots__ = ("t_taken", "accepted", "t_launch", "t_step_end")
+
+    def __init__(self, t_taken: float) -> None:
+        super().__init__()
+        self.t_taken = t_taken
+        #: sid -> when the capture taken was accepted into its mailbox
+        self.accepted: Dict[int, float] = {}
+        #: when ``lane.enc.dispatch`` launched its step (None where the
+        #: encoder does not say)
+        self.t_launch: Optional[float] = None
+        #: the end of the lane's part of the tick that took the rows
+        self.t_step_end: Optional[float] = None
 
 
 class _Lane:
@@ -290,6 +343,13 @@ class MeshEncodeCoordinator:
         #: fault-injection registry checked at the tick/slot sites
         #: (mesh.tick_raise / mesh.slot_raise); wired by the server
         self.faults = None
+        #: the server's FlightRecorder, handed over through a session's
+        #: facade as to the solo driver: the worker writes its states to
+        #: the thread track there; without one nothing is written
+        self.recorder = None
+        #: when the worker last finished a tick that did work: where its
+        #: ``sleep`` begins
+        self._worked_until: Optional[float] = None
 
         #: bounded in-flight window PER LANE (ISSUE 12): up to
         #: ``max_inflight`` dispatched ticks ride the device at once —
@@ -569,10 +629,13 @@ class MeshEncodeCoordinator:
             sess = self._sessions.get(sid)
             return sess.lane.id if sess is not None else None
 
-    def _compiling_for_s(self, sid: int) -> float:
+    def _encoder_of(self, sid: int):
         with self._lock:
             sess = self._sessions.get(sid)
-            enc = sess.lane.enc if sess is not None else None
+            return sess.lane.enc if sess is not None else None
+
+    def _compiling_for_s(self, sid: int) -> float:
+        enc = self._encoder_of(sid)
         watch = getattr(enc, "compile_watch", None)
         return watch.compiling_for_s() if watch is not None else 0.0
 
@@ -607,6 +670,7 @@ class MeshEncodeCoordinator:
                 return None, False
             dropped = sess.pending is not None
             sess.pending = frame
+            sess.accepted_at = time.monotonic()
             # the seq THIS frame will harvest under: seq advances only at
             # harvest, so same-generation frames already in the in-flight
             # window come first — without the offset, overlapped steady
@@ -661,7 +725,7 @@ class MeshEncodeCoordinator:
                 self.worker_restarts_total += 1
             self._stop.clear()
             self._thread = threading.Thread(
-                target=self._run, name="mesh-encode", daemon=True)
+                target=self._run, name=WORKER_THREAD, daemon=True)
             self._thread.start()
 
     def _run(self) -> None:
@@ -763,6 +827,13 @@ class MeshEncodeCoordinator:
                     problems.append(f"session {sid}: dangling slot binding")
         return problems
 
+    def _track(self, state: str, t0: float, t1: float) -> None:
+        """One state of the worker, to the recorder's thread track, from
+        clock readings the tick takes for its frames' stages anyway."""
+        rec = self.recorder
+        if rec is not None and t1 > t0:
+            rec.thread_state(WORKER_THREAD, state, t0, t1)
+
     def _fetch_ready(self, lane: _Lane, pending) -> bool:
         ready = getattr(lane.enc, "fetch_ready", None)
         if ready is None:
@@ -787,22 +858,41 @@ class MeshEncodeCoordinator:
                     lane.health.record_error(slot)
                     sess.inflight = max(0, sess.inflight - 1)
             raise
-        # flight-recorder intervals: the mesh encoders report the
-        # fetch/concat split of the harvest wall (last_harvest_stages,
-        # with per-shard fetch attribution for SFE lanes) — D2H
-        # materialization rides fetch_wait, host slice-concat/entropy
-        # glue rides pack. Encoders without the split (injected fakes)
-        # keep the coarse whole-wall fetch_wait attribution.
+        # flight-recorder intervals, under the solo driver's names
+        # (observability/tracing.py STAGES). They tile the frame's time
+        # from its acceptance to the end of this harvest:
+        #   submit_wait  accepted into the session's mailbox -> taken
+        #   pipe_wait    taken -> lane.enc.dispatch entered (room-making)
+        #   stage        dispatch entered -> the step launched
+        #   dispatch     launched -> dispatch returned
+        #   in_device    dispatch returned -> this harvest began
+        #   fetch_wait   D2H materialization (last_harvest_stages, with
+        #                per-shard attribution for SFE lanes)
+        #   pack         host slice-concat / entropy glue
+        # and lane_step lies across them: the worker's occupied time in
+        # the tick that took the frame (to this harvest's end where the
+        # frame is harvested in that same tick). An encoder that does
+        # not say when it launched (injected fakes) keeps stage inside
+        # dispatch; one without the harvest's split keeps pack inside
+        # fetch_wait.
         t1 = time.monotonic()
         harvest_ms = (t1 - t0) * 1000.0
         stages = getattr(lane.enc, "last_harvest_stages", None)
-        if isinstance(stages, dict) and "fetch_ms" in stages:
-            t_split = min(t1, t0 + float(stages["fetch_ms"]) / 1000.0)
-            trace_iv = {"dispatch": dispatch_iv,
-                        "fetch_wait": (t0, t_split),
-                        "pack": (t_split, t1)}
-        else:
-            trace_iv = {"dispatch": dispatch_iv, "fetch_wait": (t0, t1)}
+        has_split = isinstance(stages, dict) and "fetch_ms" in stages
+        t_split = min(t1, t0 + float(stages["fetch_ms"]) / 1000.0) \
+            if has_split else t1
+        self._track("fetch_wait", t0, t_split)
+        self._track("pack", t_split, t1)
+        trace_iv = {"pipe_wait": (took.t_taken, dispatch_iv[0]),
+                    "dispatch": dispatch_iv,
+                    "in_device": (dispatch_iv[1], max(dispatch_iv[1], t0)),
+                    "fetch_wait": (t0, t_split),
+                    "lane_step": (took.t_taken, took.t_step_end or t1)}
+        if took.t_launch is not None:
+            trace_iv["stage"] = (dispatch_iv[0], took.t_launch)
+            trace_iv["dispatch"] = (took.t_launch, dispatch_iv[1])
+        if has_split:
+            trace_iv["pack"] = (t_split, t1)
         # encoder-internal stripe-job failures (whole-frame containment
         # withheld the AU without raising) must charge the slot exactly
         # like a harvest raise or an injected fault — otherwise a sick
@@ -811,7 +901,7 @@ class MeshEncodeCoordinator:
         failed = getattr(lane.enc, "last_failed_sessions", None) \
             or frozenset()
         with self._lock:
-            if isinstance(stages, dict) and "fetch_ms" in stages:
+            if has_split:
                 # under the lock: stats() sorts these windows while the
                 # worker appends — deques must not be mutated mid-iteration
                 self._fetch_ms_window.append(float(stages["fetch_ms"]))
@@ -834,6 +924,8 @@ class MeshEncodeCoordinator:
                 sess.seq = seq + 1
                 sess.results.append((seq, out[slot]))
                 sess.traces[seq] = dict(trace_iv)
+                sess.traces[seq]["submit_wait"] = (
+                    took.accepted[sess.sid], took.t_taken)
                 while len(sess.traces) > 32:
                     sess.traces.pop(next(iter(sess.traces)))
 
@@ -856,7 +948,7 @@ class MeshEncodeCoordinator:
         if faults is not None:
             faults.maybe_raise("mesh.tick_raise")
         now = time.monotonic()
-        plans: List[Tuple[_Lane, list, list]] = []
+        plans: List[Tuple[_Lane, list, _Batch]] = []
         with self._lock:
             self._retire_idle_lanes_locked(now)
             for sess in self._sessions.values():
@@ -877,11 +969,14 @@ class MeshEncodeCoordinator:
                                      "slot %d", lane.id, sess.slot)
                 sess.want_reset = False
                 sess.want_key = False
+            # the take: one reading for every capture this tick takes out
+            # of its mailbox (none can be accepted while the lock is held)
+            t_taken = time.monotonic()
             for lane in self.lanes:
                 if now < lane.skip_until:
                     continue
                 frames = [None] * lane.n_slots
-                took: List[Tuple[_Session, int, int]] = []
+                took = _Batch(t_taken)
                 for slot, sess in list(lane.sessions.items()):
                     if sess.pending is None:
                         continue
@@ -913,13 +1008,20 @@ class MeshEncodeCoordinator:
                     sess.inflight += 1
                     sess.staged += 1
                     took.append((sess, slot, sess.gen))
+                    took.accepted[sess.sid] = sess.accepted_at
                 if took or lane.inflight_q:
                     plans.append((lane, frames, took))
-        for lane, frames, took in plans:
-            self._tick_lane(lane, frames, took)
-        self._migrate_sick_sessions()
+        if plans and self._worked_until is not None:
+            self._track("sleep", self._worked_until, now)
+        try:
+            for lane, frames, took in plans:
+                self._tick_lane(lane, frames, took)
+            self._migrate_sick_sessions()
+        finally:
+            if plans:
+                self._worked_until = time.monotonic()
 
-    def _tick_lane(self, lane: _Lane, frames: list, took: list) -> None:
+    def _tick_lane(self, lane: _Lane, frames: list, took: _Batch) -> None:
         dispatched = False
         try:
             # make room FIRST: the window is a hard bound on dispatched-
@@ -930,9 +1032,20 @@ class MeshEncodeCoordinator:
             t_disp0 = time.monotonic()
             pending = lane.enc.dispatch(frames) if took else None
             if pending is not None:
+                t_disp1 = time.monotonic()
+                # where staging ended and the launch began, if the
+                # encoder says (as last_harvest_stages says the harvest's
+                # split): an injected fake without it keeps one dispatch
+                launch = getattr(lane.enc, "last_launch_at", None)
+                if launch is not None and t_disp0 <= launch <= t_disp1:
+                    took.t_launch = launch
+                    self._track("stage", t_disp0, launch)
+                    self._track("dispatch", launch, t_disp1)
+                else:
+                    self._track("dispatch", t_disp0, t_disp1)
                 with self._lock:
                     lane.inflight_q.append(
-                        (pending, took, (t_disp0, time.monotonic())))
+                        (pending, took, (t_disp0, t_disp1)))
                     for sess, _slot, _gen in took:
                         sess.staged = max(0, sess.staged - 1)
                     depth = sum(len(ln.inflight_q) for ln in self.lanes)
@@ -968,6 +1081,9 @@ class MeshEncodeCoordinator:
                              lane.id, lane.error_streak)
         else:
             lane.error_streak = 0
+        if took:
+            # lane_step of the frames this tick took: the take to here
+            took.t_step_end = time.monotonic()
 
     def _retire_idle_lanes_locked(self, now: float) -> None:
         """Rebalance on leave: a drained lane is retired after a grace

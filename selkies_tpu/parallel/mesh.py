@@ -208,23 +208,29 @@ def make_batched_entropy_step(mesh: Mesh, pad_h: int, pad_w: int,
     cap = packer.cap_words
 
     def local_step(frames, prev, qy, qc, qsel):
+        # the phases are named as the solo step names them (ops/phases.py;
+        # _encode_body brings colour, damage and transform): scopes are
+        # metadata and change no byte. The two psums lie in no phase
         enc = functools.partial(_encode_body, stripe_h=stripe_h)
         yq, cbq, crq, damage, new_prev = jax.vmap(
             enc, in_axes=(0, 0, None, None, 0))(frames, prev, qy, qc, qsel)
-        words, nbytes, base, ovf = jax.vmap(packer._pack_fn)(yq, cbq, crq)
+        with jax.named_scope("entropy"):
+            words, nbytes, base, ovf = jax.vmap(packer._pack_fn)(
+                yq, cbq, crq)
         session_bytes = jax.lax.psum(
             nbytes.sum(axis=1).astype(jnp.int32), "stripe")
         total_bytes = jax.lax.psum(session_bytes.sum(), "session")
-        # session_bytes rides the fetched head (one extra word) so the
-        # host never pays a second D2H round trip for rate feedback
-        head = jnp.concatenate([
-            nbytes.astype(jnp.uint32),
-            base.astype(jnp.uint32),
-            ovf.astype(jnp.uint32),
-            damage.astype(jnp.uint32),
-            session_bytes[:, None].astype(jnp.uint32),
-        ], axis=1)                                    # [N_local, mw + 1]
-        packed = jnp.concatenate([head, words], axis=1)[:, None, :]
+        with jax.named_scope("entropy"):
+            # session_bytes rides the fetched head (one extra word) so the
+            # host never pays a second D2H round trip for rate feedback
+            head = jnp.concatenate([
+                nbytes.astype(jnp.uint32),
+                base.astype(jnp.uint32),
+                ovf.astype(jnp.uint32),
+                damage.astype(jnp.uint32),
+                session_bytes[:, None].astype(jnp.uint32),
+            ], axis=1)                                # [N_local, mw + 1]
+            packed = jnp.concatenate([head, words], axis=1)[:, None, :]
         return (packed, new_prev, yq, cbq, crq, session_bytes, total_bytes)
 
     sharded = jax.shard_map(
@@ -410,6 +416,9 @@ class MeshStripeEncoder:
         #: fetch/concat split of the latest harvest wall, with per-shard
         #: fetch attribution (the coordinator's flight-recorder feed)
         self.last_harvest_stages: Optional[dict] = None
+        #: when the latest dispatch launched its step (``time.monotonic``):
+        #: staging lies before it, the launch after (the same feed)
+        self.last_launch_at: Optional[float] = None
 
     # -- control -----------------------------------------------------------
 
@@ -432,6 +441,21 @@ class MeshStripeEncoder:
         self._prev = jax.device_put(
             jnp.asarray(self._prev).at[session].set(0),
             self._frame_sharding)
+
+    def lower_step(self):
+        """The lane's step, lowered for this encoder's geometry and mesh:
+        what observability/device_phases.py compiles (from the cache,
+        where the lane has served) to name a trace's operations by phase.
+        Shapes only: nothing runs, and no state of the encoder is read
+        that a dispatch under way could be replacing."""
+        frames = jax.ShapeDtypeStruct(
+            (self.n_sessions, self.pad_h, self.pad_w, 3), jnp.uint8,
+            sharding=self._frame_sharding)
+        qsel = jax.ShapeDtypeStruct(
+            (self.n_sessions, self.n_stripes), jnp.int32,
+            sharding=self._qsel_sharding)
+        tables = jax.ShapeDtypeStruct(self._qy.shape, self._qy.dtype)
+        return self._step.lower(frames, frames, tables, tables, qsel)
 
     # -- per-tick ----------------------------------------------------------
 
@@ -503,6 +527,7 @@ class MeshStripeEncoder:
         if batch is self._last_host:
             batch = batch.copy()
         frames_d = jax.device_put(jnp.asarray(batch), self._frame_sharding)
+        self.last_launch_at = time.monotonic()
         with self.compile_watch.first_use("step"):
             packed, self._prev, yq, cbq, crq, _sb, _total = self._step(
                 frames_d, self._prev, self._qy, self._qc, qsel)

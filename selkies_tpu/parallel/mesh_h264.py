@@ -287,6 +287,8 @@ class MeshH264Encoder:
         #: fetch/concat split of the latest harvest wall with per-shard
         #: fetch attribution (the coordinator's flight-recorder feed)
         self.last_harvest_stages: Optional[dict] = None
+        #: when the latest dispatch launched its step (``time.monotonic``)
+        self.last_launch_at: Optional[float] = None
         #: stripes recovered through the flat16 host coder (overflow /
         #: prefix undershoot; IDR resyncs excluded) — observability
         self.host_fallback_stripes_total = 0
@@ -440,6 +442,7 @@ class MeshH264Encoder:
                                  self._plane_sharding)
         idr_d = jax.device_put(jnp.asarray(idr.astype(np.int32)),
                                self._plane_sharding)
+        self.last_launch_at = time.monotonic()
         with self.compile_watch.first_use((with_idr, self._prefix)):
             (buf, flat16, self._prev_y, self._prev_cb, self._prev_cr,
              self._ref_y, self._ref_cb, self._ref_cr) = fn(

@@ -46,6 +46,12 @@ def test_a_phase_map_from_hlo_text():
     assert m["rewritten.5"] == "motion"           # the compiler's own name
     assert device_phases.phase_of_op_name("jit(f)/transform/motion/x") \
         == "transform"                            # the outermost phase
+    # a scope inside a vmapped function, as a mesh lane's step has them
+    of = device_phases.phase_of_op_name
+    assert of("jit(local_step)/shard_map/vmap(colour)/mul") == "colour"
+    assert of("jit(f)/vmap(vmap(motion))/x") == "motion"
+    assert of("jit(f)/entropy/vmap()/vmap(jit(colour))/x") == "entropy"
+    assert of("jit(f)/vmap(jit(colour))/x") is None    # a function's name
 
 
 def test_the_jpeg_step_names_every_phase_but_motion():
@@ -122,3 +128,82 @@ def test_the_phase_decorator_scopes_what_the_function_traces():
     assert "entropy" in text
     with pytest.raises(AssertionError):
         phase("no-such-phase")
+
+
+def test_the_phases_a_text_names():
+    # the optimised module's: the operations inside fusions count too
+    assert device_phases.phases_named(HLO) >= {"colour", "motion"}
+    assert device_phases.phases_named("no scope path here") == set()
+    # the lowered module's: named locations, not files'; inside a
+    # shard_map the path starts at the mapped function
+    lowered = ('#loc7 = loc("jit(local_step)/shard_map"(#loc3))\n'
+               '#loc8 = loc("vmap(colour)/mul"(#loc7))\n'
+               '#loc9 = loc("entropy/cumsum")\n'
+               '#loc10 = loc("/checkout/motion/transform/ops.py":27:18)')
+    assert device_phases.phases_named(lowered) == {"colour", "entropy"}
+
+
+#: a step whose second scope is the program's argument, compiled twice in
+#: two processes that share one persistent compile cache: the cache's key
+#: leaves metadata out, so the second process is handed the first's scopes
+STALE = """
+import logging, sys
+import jax, jax.numpy as jnp
+from selkies_tpu.observability import device_phases
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+logging.basicConfig(level=logging.WARNING)
+
+def step(x):
+    with jax.named_scope("colour"):
+        y = x * 2 + 1
+    with jax.named_scope(sys.argv[2]):
+        return jnp.cumsum(y, axis=0) @ y.T
+
+class Enc:
+    def lower_step(self):
+        return jax.jit(step).lower(
+            jax.ShapeDtypeStruct((64, 64), jnp.float32))
+
+first = jax.jit(step).lower(
+    jax.ShapeDtypeStruct((64, 64), jnp.float32)).compile().as_text()
+print("LOADED", sorted(device_phases.phases_named(first)))
+print("READ", sorted(set(device_phases.step_phases(Enc()).values()) - {"other"}))
+"""
+
+
+def test_a_cached_executable_with_another_trees_scopes_is_compiled_again(
+        tmp_path):
+    """The trap of PR 35: a lane step whose ``entropy`` scope was new came
+    back from the cache under the scopes of the tree that compiled it
+    first, and ``phase_entropy_ms`` read 0.0."""
+    import os
+    import subprocess
+    import sys
+
+    script = tmp_path / "stale.py"
+    script.write_text(STALE)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+
+    def run(scope):
+        p = subprocess.run(
+            [sys.executable, str(script), str(tmp_path / "cache"), scope],
+            capture_output=True, text=True, env=env, timeout=300, cwd=root)
+        assert p.returncode == 0, p.stderr[-2000:]
+        return p.stdout, p.stderr
+
+    out, err = run("transform")
+    assert "READ ['colour', 'transform']" in out
+    assert "another tree's executable" not in err
+    out, err = run("entropy")
+    # the cache handed the first process's executable back ...
+    assert "LOADED ['colour', 'transform']" in out
+    # ... and the reader saw it, said so, and read the program's own names
+    assert "another tree's executable" in err
+    assert "READ ['colour', 'entropy']" in out
+    # kept under the key that holds the metadata: found there next time
+    out, err = run("entropy")
+    assert "READ ['colour', 'entropy']" in out

@@ -222,7 +222,8 @@ async def test_a_frames_marks_run_from_capture_to_send_without_a_hole(
     await serve_frames(server, 1.5)
     done = [t for t in rec._completed() if t.terminal == "acked"]
     assert len(done) >= (10 if full else 25)
-    want = STAGES[:STAGES.index("send") + 1]
+    # (lane_step is a mesh lane's, and no part of the path)
+    want = [s for s in STAGES[:STAGES.index("send") + 1] if s != "lane_step"]
     whole = [t for t in done if all(s in t.spans for s in want)]
     assert len(whole) >= 0.9 * len(done), (
         [sorted(set(want) - set(t.spans)) for t in done][:5])

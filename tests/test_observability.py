@@ -486,7 +486,8 @@ def test_http_endpoint_healthz_trace_and_nonfatal_bind():
 
 def test_stage_names_stable():
     """The stage glossary is a wire/bench/docs contract: the eight work
-    stages, and the four waits between them (in path order)."""
+    stages, the four waits between them (in path order), and a mesh
+    lane's ``lane_step``, which lies across its frame's others."""
     assert STAGES == ("capture", "submit_wait", "pipe_wait", "stage",
                       "dispatch", "in_device", "fetch_wait", "pack",
-                      "harvest_wait", "queue", "send", "ack")
+                      "lane_step", "harvest_wait", "queue", "send", "ack")
