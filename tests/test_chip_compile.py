@@ -10,6 +10,7 @@ TPU library, and under xdist every worker imports every test file. All
 compile tests live in THIS file so one worker owns the library.
 """
 
+import re
 import time
 
 import jax
@@ -185,6 +186,50 @@ def test_pallas_dct_alive_1080p(one_chip, no_persistent_cache):
     t0 = time.time()
     _check(dct8_quant_raster.lower(plane, recip, interpret=False).compile(),
            kernel=True, label="pallas dct (1088,1920)", t0=t0)
+
+
+#: ``f32[544,2,960,2]{3,2,1,0:T(8,128)...}``: type, shape, minor-to-major
+_TILED_ARRAY = re.compile(r"\b\w+\[([\d,]+)\]\{([\d,]+):T\(8,128\)[^}]*\}")
+
+
+def _narrow_tiled_arrays(hlo_text: str, scope: str):
+    """The (8, 128)-tiled arrays of ``scope``'s operations whose physical
+    minor dimension is under 8: the chip pads it to 128 lanes, so such an
+    array holds 16 to 128 times its data."""
+    found = set()
+    for line in hlo_text.splitlines():
+        if f"/{scope}/" not in line:
+            continue
+        for m in _TILED_ARRAY.finditer(line):
+            shape = [int(d) for d in m.group(1).split(",")]
+            minor = int(m.group(2).split(",")[0])
+            if shape[minor] < 8:
+                found.add(m.group(0))
+    return sorted(found)
+
+
+def test_colour_phase_pads_no_lane_1080p(one_chip, no_persistent_cache):
+    """``prepare_planes`` at (1088, 1920), for what the CPU backend cannot
+    show: a 4:2:0 mean through ``f32[544,2,960,2]{3,2,1,0:T(8,128)}`` is a
+    535 MB buffer for an 8.4 MB plane (``temp_size_in_bytes`` 543 MB, 3.1 ms
+    of every step: PERF.md, PR 36)."""
+    from selkies_tpu.encoder import h264_device as dev
+
+    assert _narrow_tiled_arrays(      # the check sees what it is for
+        '%r = f32[544,2,960,2]{3,2,1,0:T(8,128)} reshape(%p), '
+        'metadata={op_name="jit(f)/colour/reshape"}', "colour")
+    assert not _narrow_tiled_arrays(  # channels last, laid out plane-major
+        '%c = f32[1088,1920,3]{1,0,2:T(8,128)} convert(%p), '
+        'metadata={op_name="jit(f)/colour/convert"}', "colour")
+    rgb = _sds(one_chip, (H, W, 3), jnp.uint8)
+    t0 = time.time()
+    compiled = jax.jit(dev.prepare_planes, static_argnums=(1, 2)).lower(
+        rgb, H, W).compile()
+    _check(compiled, kernel=False, label="prepare_planes 1088x1920", t0=t0)
+    text = compiled.as_text()
+    assert "/colour/" in text
+    assert _narrow_tiled_arrays(text, "colour") == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 @pytest.mark.slow   # ~4.6 min here: a by-hand rehearsal, not tier 1
