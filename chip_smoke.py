@@ -592,11 +592,8 @@ class RecordingEncoder:
         import jax
         import numpy as np
 
-        if isinstance(frames, np.ndarray):
-            rec = frames.copy()
-        else:
-            rec = [None if f is None else np.array(f, copy=True)
-                   for f in frames]
+        rec = [None if f is None else np.array(f, copy=True)
+               for f in frames]
         p = self._enc.dispatch(frames)
         self._live[id(p)] = p
         devs = set()

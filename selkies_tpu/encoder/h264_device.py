@@ -649,8 +649,11 @@ class StagingRing:
     pins the guard.
     """
 
-    def __init__(self, depth: int = 2) -> None:
+    def __init__(self, depth: int = 2, device=None) -> None:
         self.depth = max(2, int(depth))
+        #: where frames land: a lane's chip, or None for the default
+        #: device (the solo encoders)
+        self._device = device
         #: shape/dtype-keyed slot lists — a resize simply starts a new
         #: lane; stale lanes are dropped
         self._slots: "list[object]" = [None] * self.depth
@@ -676,7 +679,7 @@ class StagingRing:
         Release the ticket via :meth:`release` once the consuming frame
         has been harvested.
         """
-        frame = jnp.asarray(frame)
+        frame = jax.device_put(frame, self._device)
         key = (frame.shape, frame.dtype)
         if key != self._shape:
             # geometry change: abandon old slots (freed by GC) and

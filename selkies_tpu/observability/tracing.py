@@ -95,8 +95,8 @@ __all__ = [
 #: pipe_wait     taken out -> its staging begins (behind the rest of the
 #:               pass's work, and in ``pipe.submit`` while the pipe is full;
 #:               a lane: the worker makes room in the in-flight window)
-#: stage         H2D staging (donated ring copy / host batch stack; a lane:
-#:               padding into the host batch, its private copy, device_put)
+#: stage         H2D staging (the donated ring; a lane: each chip's share
+#:               of each new frame through that chip's ring)
 #: dispatch      device program launch (not device compute)
 #: in_device     dispatch done -> the driver sees the result ready or
 #:               begins to block for it (queued and running on the device)

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..encoder.h264_device import StagingRing, StagingTicket
 from ..encoder.jpeg import _encode_body
 from ..runtime import CompileWatch
 
@@ -72,6 +73,74 @@ def plane_sharding(mesh: Mesh) -> NamedSharding:
     while spec and spec[-1] is None:
         spec.pop()
     return NamedSharding(mesh, P(*spec))
+
+
+class LaneStaging:
+    """How a lane's frames reach its chips: the ``[N, pad_h, pad_w, 3]``
+    batch a step reads is put together from pieces, one per session and
+    chip that holds rows of it, each staged through that chip's
+    :class:`StagingRing` as a solo encoder stages its frames. A piece
+    stays until its session's next frame replaces it: a session with no
+    new frame uploads nothing."""
+
+    def __init__(self, sharding: NamedSharding, n_sessions: int,
+                 pad_h: int, pad_w: int) -> None:
+        self._sharding = sharding
+        self._shape = (n_sessions, pad_h, pad_w, 3)
+        #: per chip: its sessions, its rows of each, its ring (a session
+        #: may hold three slots: the piece the next step reads and those
+        #: of the coordinator's two dispatches in flight) and its pieces
+        self._chips = []
+        for dev, idx in sharding.addressable_devices_indices_map(
+                self._shape).items():
+            n0, n1, _ = idx[0].indices(n_sessions)
+            r0, r1, _ = idx[1].indices(pad_h)
+            self._chips.append((range(n0, n1), r0, r1, StagingRing(
+                depth=3 * (n1 - n0), device=dev), [None] * (n1 - n0)))
+        self._replaced: List[StagingTicket] = []
+        for n in range(n_sessions):
+            self.reset(n)
+
+    def put(self, session: int, frame: np.ndarray) -> None:
+        """Stage one session's frame, edge-padded to the batch's rows
+        and columns. ``np.pad`` returns a new array also where it adds
+        nothing: whatever the caller writes into ``frame`` next, the
+        transfer (and on the CPU backend, where a ring slot's first
+        ``device_put`` may alias it, the piece) reads memory that
+        nobody writes again."""
+        h, w = frame.shape[:2]
+        for sessions, r0, r1, ring, pieces in self._chips:
+            if session not in sessions:
+                continue
+            rows = frame[min(r0, h - 1):r1]
+            piece = np.pad(
+                rows, ((0, r1 - r0 - rows.shape[0]),
+                       (0, self._shape[2] - w), (0, 0)), mode="edge")
+            staged, slot = ring.stage(piece[None])
+            old = pieces[session - sessions[0]]
+            if old is not None:
+                self._replaced.append(old[1])
+            pieces[session - sessions[0]] = staged, StagingTicket(ring, slot)
+
+    def reset(self, session: int) -> None:
+        """A recycled slot's pieces become zeros on the device."""
+        self.put(session, np.zeros((1, 1, 3), np.uint8))
+
+    def stage(self, frames) -> Tuple[jax.Array, np.ndarray, list]:
+        """``frames``: a frame (unpadded is fine) or None per session.
+        Returns the device batch, which sessions had None (their pieces
+        ride again) and the tickets of the pieces replaced since the last
+        call, to release once this dispatch is harvested: every earlier
+        step, which may have read them, has been by then."""
+        reuse_prev = np.array([f is None for f in frames])
+        for n in np.flatnonzero(~reuse_prev):
+            self.put(n, np.asarray(frames[n], np.uint8))
+        shards = [own[0][0] if len(own) == 1
+                  else jnp.concatenate([piece for piece, _ in own])
+                  for *_, own in self._chips]
+        tickets, self._replaced = self._replaced, []
+        return (jax.make_array_from_single_device_arrays(
+            self._shape, self._sharding, shards), reuse_prev, tickets)
 
 
 def make_mesh(
@@ -332,6 +401,7 @@ class _MeshPending:
     reuse_prev: np.ndarray
     first: np.ndarray
     stride: int
+    tickets: list               # staged pieces this dispatch replaced
 
 
 class MeshStripeEncoder:
@@ -406,10 +476,8 @@ class MeshStripeEncoder:
         self._static = np.zeros((n_sessions, S), np.int64)
         self._painted = np.zeros((n_sessions, S), bool)
         self._first = np.ones(n_sessions, bool)
-        #: host mirror of each slot's last submitted padded frame (idle
-        #: ticks re-present it without touching the device prev buffer)
-        self._last_host = np.zeros(
-            (n_sessions, self.pad_h, self.pad_w, 3), np.uint8)
+        self._staging = LaneStaging(
+            self._frame_sharding, n_sessions, self.pad_h, self.pad_w)
         #: adaptive D2H prefix (words per (session, shard) fetched besides
         #: metadata); a miss costs one extra read of the missing slice
         self._guess = self._packer.bucket_words(8192)
@@ -435,9 +503,9 @@ class MeshStripeEncoder:
         force_keyframe alone is NOT enough the day an inter profile
         rides the mesh: the previous occupant's
         pixels would persist in the prev/reference planes and in the
-        idle-tick re-present buffer."""
+        staged pieces an idle tick presents again."""
         self.force_keyframe(session)
-        self._last_host[session] = 0
+        self._staging.reset(session)
         self._prev = jax.device_put(
             jnp.asarray(self._prev).at[session].set(0),
             self._frame_sharding)
@@ -459,48 +527,17 @@ class MeshStripeEncoder:
 
     # -- per-tick ----------------------------------------------------------
 
-    def _pad(self, frame: np.ndarray) -> np.ndarray:
-        if frame.shape[0] == self.pad_h and frame.shape[1] == self.pad_w:
-            return frame
-        return np.pad(
-            frame,
-            ((0, self.pad_h - frame.shape[0]),
-             (0, self.pad_w - frame.shape[1]), (0, 0)),
-            mode="edge")
-
     def dispatch(self, frames) -> "_MeshPending":
         """Dispatch one mesh step for all sessions and start the async D2H
         prefix fetch; pair with :meth:`harvest`. Keeping one dispatch in
         flight while harvesting the previous one hides the device
         round-trip exactly like the solo PipelinedJpegEncoder does.
 
-        ``frames``: [N, H, W, 3] uint8 array, a device-resident pre-padded
-        jnp array, or a length-N sequence (entries may be unpadded; None
-        reuses the previous frame, which damage gating then suppresses).
+        ``frames``: a length-N sequence of frames (unpadded is fine) or
+        None: a slot with None presents its staged frame again, which
+        damage gating then suppresses.
         """
-        reuse_prev = np.zeros(self.n_sessions, bool)
-        if isinstance(frames, jnp.ndarray):
-            # device-resident batch (bench/synthetic sources): must already
-            # be padded to the encoder geometry
-            want = (self.n_sessions, self.pad_h, self.pad_w, 3)
-            if frames.shape != want:
-                raise ValueError(f"device batch must be pre-padded to {want}")
-            batch = frames
-        elif isinstance(frames, np.ndarray) and frames.ndim == 4:
-            for n in range(self.n_sessions):
-                self._last_host[n] = self._pad(np.asarray(frames[n], np.uint8))
-            batch = self._last_host
-        else:
-            # the persistent host batch doubles as the last-frame cache:
-            # slots without a new frame this tick keep their old pixels
-            # (damage then reads all-zero on device) with no realloc and
-            # never a blocking device prev readback
-            for n, f in enumerate(frames):
-                if f is None:
-                    reuse_prev[n] = True
-                else:
-                    self._last_host[n] = self._pad(np.asarray(f, np.uint8))
-            batch = self._last_host
+        frames_d, reuse_prev, tickets = self._staging.stage(frames)
 
         paint_candidate = (
             self.use_paint_over_quality
@@ -518,15 +555,6 @@ class MeshStripeEncoder:
         qsel = jax.device_put(
             jnp.asarray(paint_candidate.astype(np.int32)),
             self._qsel_sharding)
-        # a PRIVATE copy goes to the device: JAX may still be reading a
-        # host array after device_put returns (and the CPU backend aliases
-        # it outright), while _last_host is rewritten by the next
-        # dispatch — with two dispatches in flight the lane encoded torn
-        # frames (chip_smoke.py --chips 4 caught it against a one-device
-        # replay)
-        if batch is self._last_host:
-            batch = batch.copy()
-        frames_d = jax.device_put(jnp.asarray(batch), self._frame_sharding)
         self.last_launch_at = time.monotonic()
         with self.compile_watch.first_use("step"):
             packed, self._prev, yq, cbq, crq, _sb, _total = self._step(
@@ -538,7 +566,7 @@ class MeshStripeEncoder:
         return _MeshPending(
             prefix=prefix, packed=packed, yq=yq, cbq=cbq, crq=crq,
             paint_candidate=paint_candidate, reuse_prev=reuse_prev,
-            first=first, stride=stride)
+            first=first, stride=stride, tickets=tickets)
 
     def fetch_ready(self, p: "_MeshPending") -> bool:
         """True when the eagerly-started prefix fetch has landed — the
@@ -555,6 +583,8 @@ class MeshStripeEncoder:
         from ..encoder.jpeg import StripeOutput, split_meta
 
         t_h0 = time.perf_counter()
+        for t in p.tickets:
+            t.release()
         host, per_shard_ms = fetch_sharded_prefix(p.prefix)
         fetch_ms = sum(per_shard_ms.values())
         head = self._mw + 1

@@ -41,7 +41,7 @@ from ..encoder import h264_device as dev
 from ..encoder.h264 import H264Stripe, encode_picture_nals_np, make_pps, make_sps
 from ..encoder.h264 import _entropy_pool
 from ..runtime import CompileWatch, pallas_interpret
-from .mesh import fetch_sharded_prefix, plane_sharding
+from .mesh import LaneStaging, fetch_sharded_prefix, plane_sharding
 
 logger = logging.getLogger("selkies_tpu.parallel.h264")
 
@@ -174,6 +174,7 @@ class _MeshH264Pending:
     paint: np.ndarray             # [N, S] bool
     reuse_prev: np.ndarray        # [N] bool
     qp: np.ndarray                # [N, S] int — qp each stripe coded at
+    tickets: list                 # staged pieces this dispatch replaced
 
 
 class MeshH264Encoder:
@@ -281,8 +282,8 @@ class MeshH264Encoder:
         self._idr_pic_id = np.zeros((n_sessions, S), np.int64)
         self._static = np.zeros((n_sessions, S), np.int64)
         self._painted = np.zeros((n_sessions, S), bool)
-        self._last_host = np.zeros(
-            (n_sessions, self.pad_h, self.pad_w, 3), np.uint8)
+        self._staging = LaneStaging(
+            plane, n_sessions, self.pad_h, self.pad_w)
         self._sps_pps: Dict[int, bytes] = {}
         #: fetch/concat split of the latest harvest wall with per-shard
         #: fetch attribution (the coordinator's flight-recorder feed)
@@ -321,7 +322,7 @@ class MeshH264Encoder:
         them — the known hazard for mesh inter)."""
         self.force_keyframe(session)
         self._frame_num[session] = 0
-        self._last_host[session] = 0
+        self._staging.reset(session)
         self._withheld[session] = False
         put = functools.partial(jax.device_put)
         for name in ("_prev_y", "_prev_cb", "_prev_cr",
@@ -372,45 +373,18 @@ class MeshH264Encoder:
                 + make_pps())
         return self._sps_pps[h]
 
-    def _pad(self, frame: np.ndarray) -> np.ndarray:
-        if frame.shape[0] == self.pad_h and frame.shape[1] == self.pad_w:
-            return frame
-        return np.pad(
-            frame,
-            ((0, self.pad_h - frame.shape[0]),
-             (0, self.pad_w - frame.shape[1]), (0, 0)),
-            mode="edge")
-
     # -- per-tick ----------------------------------------------------------
 
     def dispatch(self, frames) -> _MeshH264Pending:
         """One sharded step for all sessions; pair with :meth:`harvest`.
 
-        ``frames``: [N, H, W, 3] array, a device-resident pre-padded jnp
-        batch (bench/synthetic sources; bypasses the idle re-present
-        cache like MeshStripeEncoder's), or a length-N sequence (None
-        entries re-present the previous frame; damage gating suppresses
-        them).
+        ``frames``: a length-N sequence of frames or None (a None slot
+        presents its staged frame again; damage gating suppresses it).
         """
-        reuse_prev = np.zeros(self.n_sessions, bool)
-        batch: Any = self._last_host
-        if isinstance(frames, jnp.ndarray):
-            want = (self.n_sessions, self.pad_h, self.pad_w, 3)
-            if frames.shape != want:
-                raise ValueError(f"device batch must be pre-padded to {want}")
-            batch = frames
-        elif isinstance(frames, np.ndarray) and frames.ndim == 4:
-            for n in range(self.n_sessions):
-                self._last_host[n] = self._pad(np.asarray(frames[n], np.uint8))
-        else:
-            for n, f in enumerate(frames):
-                if f is None:
-                    reuse_prev[n] = True
-                else:
-                    self._last_host[n] = self._pad(np.asarray(f, np.uint8))
+        frames_d, reuse_prev, tickets = self._staging.stage(frames)
 
         # a withheld session's client never received the content already
-        # sitting in _last_host (whole-frame containment dropped it), so
+        # staged for it (whole-frame containment dropped it), so
         # an idle re-present is NOT a no-op for it: run the armed
         # full-frame IDR resync now instead of waiting for fresh damage
         reuse_prev &= ~self._withheld
@@ -428,16 +402,6 @@ class MeshH264Encoder:
         qp_arr = np.where(paint, self.paint_over_qp, self.qp)
         with_idr = bool(idr.any())
         fn = self._step_for(with_idr)
-        # a PRIVATE copy goes to the device: JAX may still be reading a
-        # host array after device_put returns (and the CPU backend aliases
-        # it outright), while _last_host is rewritten by the next
-        # dispatch — with two dispatches in flight the lane encoded torn
-        # frames (chip_smoke.py --chips 4 caught it against a one-device
-        # replay)
-        if batch is self._last_host:
-            batch = batch.copy()
-        frames_d = jax.device_put(jnp.asarray(batch),
-                                  self._frame_sharding)
         paint_d = jax.device_put(jnp.asarray(paint.astype(np.int32)),
                                  self._plane_sharding)
         idr_d = jax.device_put(jnp.asarray(idr.astype(np.int32)),
@@ -454,7 +418,7 @@ class MeshH264Encoder:
         prefix.copy_to_host_async()
         return _MeshH264Pending(
             prefix=prefix, buf=None, flat16=flat16, idr=idr,
-            paint=paint, reuse_prev=reuse_prev, qp=qp_arr)
+            paint=paint, reuse_prev=reuse_prev, qp=qp_arr, tickets=tickets)
 
     def fetch_ready(self, p: _MeshH264Pending) -> bool:
         """True when the eagerly-started prefix fetch has landed — the
@@ -470,6 +434,8 @@ class MeshH264Encoder:
         harvest wall with per-stripe-shard fetch attribution — which the
         coordinator folds into each frame's flight-recorder span."""
         t_h0 = time.perf_counter()
+        for t in p.tickets:
+            t.release()
         # [N, stripe_ax, prefix]: materialized shard by shard so the D2H
         # wall is attributable per SFE stripe shard
         host, per_shard_ms = fetch_sharded_prefix(p.prefix)

@@ -105,6 +105,11 @@ def test_entry_compiles_and_runs():
 # mesh dispatch, bit-exact with the solo JpegStripeEncoder.
 
 
+def _staged_total(enc):
+    """Stagings so far, over the rings of a lane's chips."""
+    return sum(ring.staged_total for *_, ring, _ in enc._staging._chips)
+
+
 def _frame_seq(rng, n_frames):
     """Per-session frame sequence: random → static → partial change."""
     f0 = rng.integers(0, 256, (H, W, 3), dtype=np.uint8)
@@ -135,7 +140,7 @@ def test_mesh_stripe_encoder_matches_solo(mesh):
         entropy="device") for _ in range(N_SESSIONS)]
 
     for t in range(n_frames):
-        frames = np.stack([seqs[n][t] for n in range(N_SESSIONS)])
+        frames = [seqs[n][t] for n in range(N_SESSIONS)]
         mesh_out, session_bytes = menc.encode_frames(frames)
         assert session_bytes.shape == (N_SESSIONS,)
         for n in range(N_SESSIONS):
@@ -154,15 +159,18 @@ def test_mesh_stripe_encoder_none_frames_and_keyframe(mesh):
 
     rng = np.random.default_rng(5)
     menc = MeshStripeEncoder(mesh, N_SESSIONS, W, H, stripe_h=STRIPE_H)
-    frames = rng.integers(0, 256, (N_SESSIONS, H, W, 3), dtype=np.uint8)
+    frames = list(rng.integers(0, 256, (N_SESSIONS, H, W, 3), dtype=np.uint8))
     out, _ = menc.encode_frames(frames)
     assert all(len(s) == H // STRIPE_H for s in out)   # first: all stripes
 
-    # idle slots (None) produce nothing and keep the keyframe flag armed
+    # idle slots (None) produce nothing, upload nothing and keep the
+    # keyframe flag armed
     menc.force_keyframe(2)
+    staged = _staged_total(menc)
     out, _ = menc.encode_frames([None] * N_SESSIONS)
     assert all(len(s) == 0 for s in out)
     assert menc._first[2]
+    assert _staged_total(menc) == staged
     out, _ = menc.encode_frames(frames)                # same content
     assert len(out[2]) == H // STRIPE_H                # keyframe fired
     assert all(len(out[n]) == 0 for n in range(N_SESSIONS) if n != 2)
@@ -196,7 +204,9 @@ def test_reset_session_zeroes_prev_planes(mesh):
     prev = np.asarray(enc._prev)
     assert not prev[1].any()           # recycled slot zeroed
     assert prev[0].any()               # neighbours untouched
-    assert not enc._last_host[1].any()
+    staged = np.asarray(enc._staging.stage([None] * 4)[0])
+    assert not staged[1].any()         # and its staged pieces with it
+    assert (staged[0] == 200).all()
     assert enc._first[1]
 
 
@@ -234,7 +244,7 @@ def test_mesh_h264_matches_solo(mesh):
              for _ in range(N_SESSIONS)]
 
     for t in range(n_frames):
-        frames = np.stack([seqs[n][t] for n in range(N_SESSIONS)])
+        frames = [seqs[n][t] for n in range(N_SESSIONS)]
         mesh_out, coded = menc.encode_frames(frames)
         assert coded.shape == (N_SESSIONS,)
         for n in range(N_SESSIONS):
@@ -253,7 +263,7 @@ def test_mesh_h264_idle_keyframe_and_reset(mesh):
     rng = np.random.default_rng(6)
     menc = MeshH264Encoder(mesh, N_SESSIONS, W, H, stripe_h=STRIPE_H,
                            me="xla")
-    frames = rng.integers(0, 256, (N_SESSIONS, H, W, 3), dtype=np.uint8)
+    frames = list(rng.integers(0, 256, (N_SESSIONS, H, W, 3), dtype=np.uint8))
     out, _ = menc.encode_frames(frames)
     assert all(len(s) == H // STRIPE_H for s in out)      # join: all IDR
     assert all(s.is_key for sess in out for s in sess)
@@ -273,6 +283,123 @@ def test_mesh_h264_idle_keyframe_and_reset(mesh):
     assert not np.asarray(menc._ref_y)[1].any()
     assert not np.asarray(menc._prev_y)[1].any()
     assert np.asarray(menc._ref_y)[0].any()
+    staged = np.asarray(menc._staging.stage([None] * N_SESSIONS)[0])
+    assert not staged[1].any()         # the staged pieces too
+    np.testing.assert_array_equal(staged[0], frames[0])
+
+
+# ------------------------------------------------------------ lane staging
+# A lane stages each chip's share of a frame through that chip's
+# StagingRing (parallel/mesh.py LaneStaging). The reference is a second
+# encoder fed private copies, one call at a time: for JPEG on a
+# one-device mesh, as chip_smoke.py --chips 4 has it on the chips (all
+# four sessions' pieces joined on one device); for H.264 on the lane's
+# own mesh, whose programs the module has compiled (the one-device step
+# is two minutes of compile here, and the staging is the same code).
+
+
+def _lane_pair(kind, mesh):
+    from selkies_tpu.parallel import parse_mesh_spec
+    from selkies_tpu.parallel.mesh import MeshStripeEncoder
+    from selkies_tpu.parallel.mesh_h264 import MeshH264Encoder
+
+    one = parse_mesh_spec("session:1,stripe:1", jax.devices()[:1])
+    if kind == "jpeg":
+        return [MeshStripeEncoder(m, N_SESSIONS, W, H, stripe_h=STRIPE_H)
+                for m in (mesh, one)]
+    return [MeshH264Encoder(mesh, N_SESSIONS, W, H, stripe_h=STRIPE_H,
+                            me="xla") for _ in range(2)]
+
+
+def _wire(out):
+    return [[(s.y_start, getattr(s, "annexb", None) or s.jpeg) for s in sess]
+            for sess in out[0]]
+
+
+@pytest.mark.parametrize("spec,n,hw,pad", [
+    ("session:4", 4, (60, 30), (64, 32)),             # a whole session a chip
+    ("session:4", 8, (64, 32), (64, 32)),             # two sessions a chip
+    ("session:1,stripe:4", 1, (50, 30), (64, 32)),    # a band of rows a chip
+    ("session:1,stripe:4", 1, (20, 32), (64, 32)),    # bands below the frame
+    ("session:2,stripe:2", 2, (33, 17), (64, 32)),
+])
+def test_lane_staging_puts_each_chip_its_rows(spec, n, hw, pad):
+    """The batch the step reads is every frame edge-padded to the lane's
+    geometry, each chip holding its sessions' rows of it; None keeps a
+    slot's pieces, ``reset`` blacks them, and what was staged is not the
+    caller's memory."""
+    from selkies_tpu.parallel import parse_mesh_spec
+    from selkies_tpu.parallel.mesh import LaneStaging, plane_sharding
+
+    lane = parse_mesh_spec(spec, jax.devices()[:4])
+    sharding = plane_sharding(lane)
+    staging = LaneStaging(sharding, n, *pad)
+    rng = np.random.default_rng(47)
+    frames = [rng.integers(1, 256, hw + (3,), dtype=np.uint8)
+              for _ in range(n)]
+    want = np.stack([np.pad(f, ((0, pad[0] - hw[0]), (0, pad[1] - hw[1]),
+                                (0, 0)), mode="edge") for f in frames])
+    batch, reuse, tickets = staging.stage(frames)
+    for f in frames:
+        f[...] = 0
+    assert batch.sharding == sharding and not reuse.any()
+    assert len(tickets) == len(staging._chips) * n // lane.shape["session"]
+    np.testing.assert_array_equal(np.asarray(batch), want)
+    batch, reuse, tickets = staging.stage([None] * n)
+    assert reuse.all() and tickets == []
+    np.testing.assert_array_equal(np.asarray(batch), want)
+    staging.reset(n - 1)
+    want[n - 1] = 0
+    np.testing.assert_array_equal(
+        np.asarray(staging.stage([None] * n)[0]), want)
+
+
+@pytest.mark.parametrize("kind", ["jpeg", "h264"])
+def test_lane_frames_in_flight_are_never_torn(mesh, kind):
+    """After ``dispatch`` returns the frame is the lane's: the caller
+    writes the next frame into the very arrays it passed and dispatches
+    again, two steps in flight, and both harvests are the replay's. The
+    frames need no padding, so nothing but the staging stands between
+    the caller's writes and the pixels the device reads."""
+    lane, ref = _lane_pair(kind, mesh)
+    rng = np.random.default_rng(41)
+    steps = [rng.integers(0, 256, (N_SESSIONS, H, W, 3), dtype=np.uint8)
+             for _ in range(3)]
+    want = [_wire(ref.encode_frames([f.copy() for f in step]))
+            for step in steps]
+    bufs = [f.copy() for f in steps[0]]
+    got = [_wire(lane.encode_frames(bufs))]
+    pend = []
+    for step in steps[1:]:
+        for buf, f in zip(bufs, step):
+            buf[...] = f
+        pend.append(lane.dispatch(bufs))
+    for buf in bufs:
+        buf[...] = 0
+    got += [_wire(lane.harvest(p)) for p in pend]
+    assert all(len(sess) == H // STRIPE_H for g in got for sess in g)
+    assert got == want
+
+
+def test_lane_stages_only_the_slots_that_have_a_frame(mesh):
+    """A slot given None keeps its staged pieces: the rings count one
+    staging for each chip a NEW frame has rows on, and the None slots
+    harvest as an idle re-present (nothing), on a step that ran."""
+    lane, _ = _lane_pair("jpeg", mesh)
+    rng = np.random.default_rng(43)
+    first = list(rng.integers(0, 256, (N_SESSIONS, H, W, 3), dtype=np.uint8))
+    lane.encode_frames(first)
+    chips_per_frame = mesh.shape["stripe"]
+    before = _staged_total(lane)
+    assert before == 2 * N_SESSIONS * chips_per_frame   # zeros, then frames
+    new = rng.integers(0, 256, (H, W, 3), dtype=np.uint8)
+    out, _ = lane.encode_frames([new, None, new, None])
+    assert _staged_total(lane) == before + 2 * chips_per_frame
+    assert [len(s) for s in out] == [H // STRIPE_H, 0, H // STRIPE_H, 0]
+    # what rode again is what was staged: the same frame now is no damage
+    out, _ = lane.encode_frames([None, first[1], None, first[3]])
+    assert [len(s) for s in out] == [0, 0, 0, 0]
+    assert not any(ring.stalls_total for *_, ring, _ in lane._staging._chips)
 
 
 # ------------------------------------------------------------- SFE (ISSUE 15)
@@ -422,9 +549,9 @@ def test_mesh_h264_decodes_in_conformance_oracle(mesh):
     smooth[..., 0] = (xx * 4) % 256
     smooth[..., 1] = (yy * 4) % 256
     smooth[..., 2] = 128
-    out, _ = menc.encode_frames(np.stack([smooth] * N_SESSIONS))
+    out, _ = menc.encode_frames([smooth] * N_SESSIONS)
     shifted = np.roll(smooth, 2, axis=0)
-    out2, _ = menc.encode_frames(np.stack([shifted] * N_SESSIONS))
+    out2, _ = menc.encode_frames([shifted] * N_SESSIONS)
 
     dec = conformance.ConformanceDecoder("h264", max_dim=256)
     n_dec = 0

@@ -40,14 +40,32 @@ def _frame(h=128, w=160, seed=0):
 # staging ring
 
 
-def test_staging_ring_ping_pongs_and_releases():
-    ring = StagingRing(depth=2)
+@pytest.fixture(params=["default device", "last device"])
+def device(request):
+    """Where a ring stages: the default device (None, the solo
+    encoders') or another one (a lane's ring for one of its chips)."""
+    import jax
+
+    return None if request.param == "default device" else jax.devices()[-1]
+
+
+def _on(device, *staged):
+    """Every staged array lives where its ring was told to put it."""
+    import jax
+
+    want = {jax.devices()[0] if device is None else device}
+    return all(a.devices() == want for a in staged)
+
+
+def test_staging_ring_ping_pongs_and_releases(device):
+    ring = StagingRing(depth=2, device=device)
     a, ta = ring.stage(_frame(seed=1))
     assert ta is not None and ring.in_use == 1
     b, tb = ring.stage(_frame(seed=2))
     assert tb is not None and ring.in_use == 2
     np.testing.assert_array_equal(np.asarray(a), _frame(seed=1))
     np.testing.assert_array_equal(np.asarray(b), _frame(seed=2))
+    assert _on(device, a, b)
     ring.release(ta)
     ring.release(tb)
     assert ring.in_use == 0
@@ -56,13 +74,14 @@ def test_staging_ring_ping_pongs_and_releases():
     assert tc == ta
     np.testing.assert_array_equal(np.asarray(c), _frame(seed=3))
     assert ring.stalls_total == 0
+    assert _on(device, c)
 
 
-def test_use_after_donate_guard_never_donates_busy_slot():
+def test_use_after_donate_guard_never_donates_busy_slot(device):
     """A slot whose ticket is still held must NOT be donated: the guard
     allocates fresh instead (counted), and the busy slots' arrays stay
     readable — the in-flight batch that references them is safe."""
-    ring = StagingRing(depth=2)
+    ring = StagingRing(depth=2, device=device)
     a, ta = ring.stage(_frame(seed=1))
     b, tb = ring.stage(_frame(seed=2))
     c, tc = ring.stage(_frame(seed=3))     # ring exhausted → fallback
@@ -74,6 +93,7 @@ def test_use_after_donate_guard_never_donates_busy_slot():
     np.testing.assert_array_equal(np.asarray(a), _frame(seed=1))
     np.testing.assert_array_equal(np.asarray(b), _frame(seed=2))
     np.testing.assert_array_equal(np.asarray(c), _frame(seed=3))
+    assert _on(device, a, b, c)            # the fallback too
     ring.release(ta)
     _, td = ring.stage(_frame(seed=4))     # freed slot donates again
     assert td == ta
@@ -83,21 +103,21 @@ def test_use_after_donate_guard_never_donates_busy_slot():
     assert ring.in_use == 0
 
 
-def test_staging_ring_shape_change_starts_fresh_lane():
-    ring = StagingRing(depth=2)
+def test_staging_ring_shape_change_starts_fresh_lane(device):
+    ring = StagingRing(depth=2, device=device)
     _, t0 = ring.stage(_frame(96, 128))
     assert ring.in_use == 1
     staged, t1 = ring.stage(_frame(64, 64))     # resize: new lane
-    assert staged.shape == (64, 64, 3)
+    assert staged.shape == (64, 64, 3) and _on(device, staged)
     assert ring.in_use == 1 and t1 is not None
 
 
-def test_stale_ticket_from_retired_lane_is_a_noop():
+def test_stale_ticket_from_retired_lane_is_a_noop(device):
     """A ticket issued before a shape change must NOT free the new
     lane's same-index slot: that slot's array may ride an in-flight
     batch, and freeing it would let the next stage() donate (delete)
     a live buffer."""
-    ring = StagingRing(depth=2)
+    ring = StagingRing(depth=2, device=device)
     _, ta = ring.stage(_frame(96, 128))         # lane A, slot 0
     _, tb = ring.stage(_frame(64, 64))          # lane B, slot 0 (A retired)
     assert ring.in_use == 1
