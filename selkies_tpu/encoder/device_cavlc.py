@@ -461,20 +461,54 @@ def _unit_spans(words_unit, Lb):
     return cs0, cs1, g0, e, t_bits
 
 
+#: words a row of ``_last_unit``'s histogram holds: the chip's lane count,
+#: so neither one-hot operand has a minor dimension the chip would pad
+_HIST_LANES = 128
+
+
+def _last_unit(g0, V: int):
+    """For each of a stripe's first V words, the last unit that starts at
+    or before it, [S, V] i32; units past word V - 1 count as starting in it.
+
+    g0 [S, U] i32 is non-decreasing along U and starts at 0 (an exclusive
+    running sum of bit lengths), so the last such unit is the number of
+    units that start at or before the word, less one: a histogram of the
+    start words and a running sum over it, with no scatter and no search.
+    The histogram is ``_lut1024``'s trick turned round: the word index
+    splits into a row and a lane, and the two one-hot operands meet over
+    the units on the MXU. 0/1 is exact in bf16 and the f32 accumulator
+    counts exactly up to 2^24 units."""
+    S, U = g0.shape
+    assert U < 1 << 24, U
+    L = _HIST_LANES
+    rows = -(-V // L)
+    g0c = jnp.clip(g0, 0, V - 1)
+    # units on the minor dimension of the rows' operand, lanes on the other's
+    in_row = (g0c // L)[:, None, :] == jnp.arange(
+        rows, dtype=jnp.int32)[None, :, None]                # [S, rows, U]
+    in_lane = (g0c % L)[:, :, None] == jnp.arange(
+        L, dtype=jnp.int32)[None, None, :]                   # [S, U, L]
+    count = jax.lax.dot_general(
+        in_row.astype(jnp.bfloat16), in_lane.astype(jnp.bfloat16),
+        (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)                  # [S, rows, L]
+    count = count.astype(jnp.int32).reshape(S, rows * L)[:, :V]
+    return jnp.cumsum(count, axis=1) - 1
+
+
 def _stripe_words(cs0, cs1, g0, e, V: int, W: int):
     """The first V words of each stripe's bitstream, [S, V] u32.
 
     Same analytic boundary construction as device_entropy (empty units
     are safe: a non-boundary unit never has bits past the word its
-    successor starts in).  Every output word costs one scatter slot and
-    three scalar-core gathers, so the cost is V's, whatever the stripe
-    produced: callers pick V (``pack_p_frame_words``)."""
-    S, U = g0.shape
-    g0c = jnp.clip(g0, 0, V - 1)
-    srows = jnp.arange(S, dtype=jnp.int32)[:, None]
-    bidx = jnp.arange(U, dtype=jnp.int32)[None, :]
-    lastblk = jnp.zeros((S, V), jnp.int32).at[srows, g0c].max(bidx)
-    lastblk = jax.lax.associative_scan(jnp.maximum, lastblk, axis=1)
+    successor starts in).  Every output word costs three scalar-core
+    gathers, so the cost is V's, whatever the stripe produced: callers
+    pick V (``pack_p_frame_words``).  Which unit a word is gathered from
+    (``_last_unit``) costs the units' one-hot lanes on the vector unit:
+    0.15 ms a served frame beside 1.05 of gathers at the lowest rung
+    (PERF.md, PR 39)."""
+    S = g0.shape[0]
+    lastblk = _last_unit(g0, V)
 
     ge = (jnp.clip(g0, 0, (1 << 15) - 1) << 16) | (
         jnp.clip(e + 1, 0, (1 << 15) - 1))

@@ -121,6 +121,27 @@ def test_h264_idr_step_1080p(one_chip, no_persistent_cache):
         sh=SH).compile(), kernel=False, label="idr step 1088x1920", t0=t0)
 
 
+#: any array tiled onto the 128 lanes, whatever its sublane tile
+_LANE_TILED_ARRAY = re.compile(
+    r"\b\w+\[([\d,]+)\]\{([\d,]+):T\(\d+,128\)[^}]*\}")
+
+
+def _lane_padded_arrays(hlo_text: str, units: int):
+    """The lane-tiled arrays of the pack's output stage (the branches of
+    its ``switch``) that have a dimension of ``units`` and a physical
+    minor dimension under 128, which the chip pads to 128 lanes."""
+    found = set()
+    for line in hlo_text.splitlines():
+        if "/cond/branch_" not in line:
+            continue
+        for m in _LANE_TILED_ARRAY.finditer(line):
+            shape = [int(d) for d in m.group(1).split(",")]
+            minor = int(m.group(2).split(",")[0])
+            if units in shape and shape[minor] < 128:
+                found.add(m.group(0))
+    return sorted(found)
+
+
 @pytest.mark.parametrize("n_stripes", [
     2,                                          # tier 1: the same per-stripe
     pytest.param(S, marks=pytest.mark.slow),    # program, fewer of them
@@ -142,14 +163,29 @@ def test_device_cavlc_pack_1080p(one_chip, no_persistent_cache, n_stripes):
 
     i32 = jnp.int32
     t0 = time.time()
-    _check(jax.jit(pack).lower(
+    compiled = jax.jit(pack).lower(
         _sds(one_chip, (n_stripes, n, 2), i32),
         _sds(one_chip, (n_stripes, n, 16, 4, 4), i32),
         _sds(one_chip, (n_stripes, n, 2, 2, 2), i32),
         _sds(one_chip, (n_stripes, n, 2, 4, 4, 4), i32),
         _sds(one_chip, (n_stripes,), jnp.bool_),
-        _sds(one_chip, (n_stripes,), jnp.bool_)).compile(),
-        kernel=False, label=f"pack_p_frame {n_stripes}x480", t0=t0)
+        _sds(one_chip, (n_stripes,), jnp.bool_)).compile()
+    _check(compiled, kernel=False, label=f"pack_p_frame {n_stripes}x480",
+           t0=t0)
+    # the output stage's histogram (``_last_unit``) meets two one-hot
+    # operands over the stripe's 27 * 480 + 1 units; one laid out with
+    # under 128 of anything on the lanes is padded to 128 of them (a
+    # minor dimension of 32: 17 x 12,961 x 128 x 2 B = 56 MB a step)
+    units = 27 * n + 1
+    assert _lane_padded_arrays(      # the check sees what it is for
+        f'%e = bf16[17,{units},32]{{2,1,0:T(8,128)(2,1)}} convert(%p), '
+        'metadata={op_name="jit(f)/entropy/cond/branch_2_fun/eq"}', units)
+    assert not _lane_padded_arrays(
+        f'%e = pred[2,256,{units}]{{1,2,0:T(8,128)(4,1)}} compare(%p), '
+        'metadata={op_name="jit(f)/entropy/cond/branch_0_fun/eq"}', units)
+    text = compiled.as_text()
+    assert "/cond/branch_" in text and "scatter" not in text
+    assert _lane_padded_arrays(text, units) == []
 
 
 #: the served encoder's fetch-prefix tiers (``H264StripeEncoder

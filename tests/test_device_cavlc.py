@@ -7,6 +7,8 @@ chroma DC/AC, skip/mvd paths, |level| > 127), and overflow must be
 flagged exactly where the flat16 + host fallback has to engage.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -142,6 +144,98 @@ def test_device_pack_overflow_levels_flagged_and_rest_exact(tiered):
         mv[1], luma[1], ldc, cdc[1], cac[1], is_idr=False,
         mb_w=mb_w, mb_h=mb_h, qp=26, frame_num=3)
     assert got == ref
+
+
+def _last_unit_by_scatter(g0, V):
+    """The plain reference: what ``_stripe_words`` computed until PR 39, a
+    scatter-max of the unit indices into their start words (clipped to
+    the last one) and a running maximum over the words."""
+    import jax
+    import jax.numpy as jnp
+
+    S, U = g0.shape
+    g0c = jnp.clip(g0, 0, V - 1)
+    srows = jnp.arange(S, dtype=jnp.int32)[:, None]
+    bidx = jnp.arange(U, dtype=jnp.int32)[None, :]
+    lastblk = jnp.zeros((S, V), jnp.int32).at[srows, g0c].max(bidx)
+    return jax.lax.associative_scan(jnp.maximum, lastblk, axis=1)
+
+
+def _start_words(kind: str, V: int, S: int = 3, U: int = 12961):
+    """g0 [S, U] as ``_unit_spans`` makes it (the word each unit starts in:
+    an exclusive running sum of bit lengths, over 32) for the served
+    stripe's 12,961 units, with long runs of empty units in every kind."""
+    rng = np.random.default_rng(V)
+    Lb = rng.integers(1, 545, (S, U)) * (rng.random((S, U)) < 0.3)
+    Lb[:, -40:] = 0                      # the stripe's last macroblock skipped
+    # "sparse": a few hundred words, whatever the rung holds
+    scale = {"sparse": 0.02, "overflow": 1.3, "exact": 1.0}.get(kind, 0.5)
+    Lb = (Lb * (scale * 32 * V / Lb.sum(1, keepdims=True))).astype(np.int64)
+    if kind == "exact":
+        # the last unit that holds bits ends on bit 32 * V, so the empty
+        # units after it start in word V, one past the last output word
+        last = U - 1 - np.argmax(Lb[:, ::-1] > 0, axis=1)
+        Lb[np.arange(S), last] += 32 * V - Lb.sum(1)
+        assert (Lb.sum(1) == 32 * V).all() and (Lb >= 0).all()
+    if kind == "masked":
+        Lb[1] = 0                        # a stripe outside the update mask
+    g0 = ((np.cumsum(Lb, axis=1) - Lb) >> 5).astype(np.int32)
+    if kind == "overflow":
+        assert (g0[:, -1] > V).all()     # units past the last word: clipped
+    if kind == "exact":
+        assert (g0[:, -1] == V).all()
+    return g0
+
+
+#: the served 1080p stripe's three rungs, and a V that is no power of two
+#: and no multiple of the histogram's 128 lanes
+@pytest.mark.parametrize("V", [32768, 8192, 2048, 1000])
+@pytest.mark.parametrize("kind", [
+    "sparse", "overflow", "exact", "masked", "vmap"])
+def test_last_unit_equals_the_scatter_form(kind, V):
+    """``_last_unit`` (a histogram of the start words on the MXU and a
+    running sum) gives the scatter form's integers for any g0 the pack
+    can make: runs of empty units, units clipped at V - 1, a stripe of
+    exactly 32 * V bits, a stripe that packs nothing, and a batch of
+    lanes under ``jax.vmap``."""
+    import jax
+    import jax.numpy as jnp
+
+    from selkies_tpu.encoder import device_cavlc as dcav
+
+    new = functools.partial(dcav._last_unit, V=V)
+    ref = functools.partial(_last_unit_by_scatter, V=V)
+    if kind == "vmap":
+        g0 = jnp.asarray(np.stack([
+            _start_words(k, V, S=2) for k in ("sparse", "overflow", "masked")]))
+        new, ref = jax.vmap(new), jax.vmap(ref)
+    else:
+        g0 = jnp.asarray(_start_words(kind, V))
+    got = np.asarray(jax.jit(new)(g0))
+    assert got.dtype == np.int32 and got.shape == g0.shape[:-1] + (V,)
+    np.testing.assert_array_equal(got, np.asarray(jax.jit(ref)(g0)))
+    assert (got[..., -1] == g0.shape[-1] - 1).all()   # every unit counted
+
+
+def test_stripe_words_lowers_to_no_scatter():
+    """The scatter of 17 x 12,961 updates cost 1.9 ms of every step
+    whatever the rung (PERF.md, PR 39); it must not come back unnoticed,
+    and the reference above must still be one (the check sees what it is
+    for)."""
+    import jax
+    import jax.numpy as jnp
+
+    from selkies_tpu.encoder import device_cavlc as dcav
+
+    S, U, W, V = 2, 217, dcav.UNIT_WORDS, 512
+    cs = jax.ShapeDtypeStruct((S, U * W), jnp.uint32)
+    g = jax.ShapeDtypeStruct((S, U), jnp.int32)
+    text = jax.jit(dcav._stripe_words, static_argnums=(4, 5)).lower(
+        cs, cs, g, g, V, W).as_text()
+    assert "gather" in text and "dot_general" in text
+    assert "scatter" not in text
+    assert "scatter" in jax.jit(
+        _last_unit_by_scatter, static_argnums=1).lower(g, V).as_text()
 
 
 def test_pack_under_vmap_equals_solo_pack():
