@@ -60,9 +60,12 @@ def parse_stripe(msg: bytes):
 
 class Client:
     def __init__(self, port: int, display_id: str, width: int,
-                 height: int) -> None:
+                 height: int, framerate: Optional[float] = None) -> None:
         self.port, self.display_id = port, display_id
         self.width, self.height = width, height
+        #: the rate the client asks for in SETTINGS, as a browser that
+        #: picked one does; None: none asked, the server's default
+        self.framerate = framerate
         self.frames: List[Frame] = []       # in arrival order, all of them
         self.said: List[str] = []           # text the server sent
         self.killed: Optional[str] = None
@@ -82,10 +85,12 @@ class Client:
         if schema.get("type") != "server_settings":
             raise RuntimeError("no server_settings from the server")
         self.t_settings = time.monotonic()
-        await self.ws.send("SETTINGS," + json.dumps({
-            "displayId": self.display_id,
-            "initialClientWidth": self.width,
-            "initialClientHeight": self.height}))
+        settings = {"displayId": self.display_id,
+                    "initialClientWidth": self.width,
+                    "initialClientHeight": self.height}
+        if self.framerate is not None:
+            settings["framerate"] = int(round(self.framerate))
+        await self.ws.send("SETTINGS," + json.dumps(settings))
         self._task = asyncio.create_task(self._receive())
 
     async def _receive(self) -> None:

@@ -28,10 +28,10 @@ from .sources.desktop import CallLog
 #: of set-up and of the window has to be there still when the frames are
 #: attributed after it (60 a second and display; the default is 4096)
 RECORDER_CAPACITY = 1 << 16
-#: looking which regime a stream filled in (``regime.enter``): the seconds
-#: of stream one look reads, the stop that runs a stream dry, the seconds a
-#: refilled stream gets before it is looked at, and how often it is refilled
-LOOK_S, STOP_S, SETTLE_S, REFILLS = 2.0, 0.5, 1.5, 6
+#: a traced run has one trace a side to give its per-layer line: where the
+#: traced seconds held an ``interpreter`` stall (the machine standing still)
+#: of more than this, they are traced once more and the first is logged
+RETRACE_STALL_S = 1.0
 
 
 def say(*parts: Any) -> None:
@@ -84,7 +84,13 @@ class Run:
         self.env_extra = dict(env_extra or {})
         conf = cell.config
         self.width, self.height = rehearsal or (conf["width"], conf["height"])
-        self.fps = float(conf.get("framerate", 60))
+        #: the rate the cell's client asks for in its SETTINGS, and what the
+        #: server has to say the session ran at (``session_fps``): the
+        #: mix's where it says what its client asks for (a browser's user
+        #: picks one inside the server's range), else the configuration's
+        self.fps = float(cell.traffic.get("client", {}).get(
+            "framerate", conf.get("framerate", 60)))
+        self.session_fps: Dict[str, float] = {}
         self.displays: List[str] = list(conf.get("displays", ["primary"]))
         self.log = CallLog()
         self.sources: List[Any] = []
@@ -102,6 +108,10 @@ class Run:
         #: for its step's phases)
         self.served_encoder: Any = None
         self.latencies_ms: List[float] = []  # of every change due in window
+        #: the same latencies, by the whole second of the window in which
+        #: the change fell due (what a standstill costs, and for how long)
+        self.latencies_by_second: Dict[int, List[float]] = {}
+        self.trace_asked_at: Optional[float] = None
         self.metrics: Dict[str, float] = {}  # end to end, of the window
         self.loop_late_s = 0.0
         self._attributed: Dict[str, int] = {}
@@ -163,17 +173,15 @@ class Run:
 
     async def join(self) -> None:
         """One client per display, one after another (each join reflows the
-        layout), then wait for steady state: every client has had the mix's
-        number of frames and its seconds have passed since the last join.
-        The stream is not stopped again before the window: after a stop of
-        half a second 6 windows of 18 filled in the pipeline's deeper regime,
-        without it 1 of 18 (PERF.md, PR 27). Where the configuration says
-        how to tell its regime, the harness then looks which one the stream
-        filled in, and only a stream in another is stopped and filled again
-        (``_enter_regime``)."""
+        layout), each asking for the cell's rate in its SETTINGS (``self.fps``),
+        then wait for steady state: every client has had the mix's number of
+        frames and its seconds have passed since the last join. The stream
+        is not stopped again before the window: after a stop of half a
+        second 6 windows of 18 filled in the old pipeline's deeper regime,
+        without it 1 of 18 (PERF.md, PR 27)."""
         steady = self.cell.traffic.get("steady", {})
         for did in self.displays:
-            c = Client(self.port, did, self.width, self.height)
+            c = Client(self.port, did, self.width, self.height, self.fps)
             await c.connect()
             self.clients[did] = c
             await self._until(lambda c=c: c.frames_seen() >= 1, 1500.0,
@@ -186,9 +194,7 @@ class Run:
                         for d, c in self.clients.items())
             and time.monotonic() - t_joined >= float(steady.get("seconds", 2)),
             300.0, "steady state")
-        enter = self.cell.config.get("regime", {}).get("enter")
-        if enter:
-            await self._enter_regime(enter)
+        self.read_session_rates()
         if self.cell.config.get("env", {}).get("SELKIES_TPU_MESH"):
             # the server skips its boot warm-up under tpu_mesh: a lane's
             # programs compile (or load) when the first client joins it
@@ -196,55 +202,28 @@ class Run:
             self.counters["warmup_s"] = \
                 first.frames[0].t_last - first.t_settings
 
-    async def _enter_regime(self, enter: Dict[str, Any]) -> None:
-        """See to it that the window opens in the regime the configuration's
-        bounds were measured in (``regime.enter``), inside set-up. Which
-        regime the H.264 pipeline fills in is the program's lottery, drawn
-        as the stream fills; once filled it keeps to the shallow one, and
-        holding the interpreter as the host's stalls do ended the deep one
-        in only 2 fills of 4 (PERF.md, PR 27). So the harness looks: the
-        median of the recorder's stage ``stage`` over the last ``LOOK_S``
-        seconds says which regime the stream is in (up to ``max_p50_ms``:
-        the stated one). There nothing happens and no time passes. Else the desktop stands still for ``STOP_S``, the pipeline
-        runs dry and fills again, and after ``SETTLE_S`` the harness looks
-        again, ``REFILLS`` times at most; a stream that never gets there is
-        measured as it is, and its run says so (``regime`` in the result
-        line)."""
-        # a CPU's regimes are not the chip's: a rehearsal refills once
-        tries = min(REFILLS, 1) if self.rehearsal else REFILLS
-        want = int(self.cell.traffic.get("steady", {}).get("frames", 20))
-        for attempt in range(tries + 1):
-            p50 = self._stage_p50(enter["stage"], LOOK_S)
-            ok = p50 is not None and p50 <= float(enter["max_p50_ms"])
-            say(f"regime.enter: look {attempt + 1}: {enter['stage']} p50 over "
-                f"the last {LOOK_S:g} s "
-                f"{'not read' if p50 is None else format(p50, '.2f') + ' ms'}"
-                f" (the stated regime: up to {enter['max_p50_ms']:g}): "
-                f"{'in it' if ok else 'not in it'}")
-            self.counters["regime_rolls"] = float(attempt)
-            if ok or attempt == tries:
-                return
-            for s in self.sources:
-                s.stopped = True
-            await asyncio.sleep(STOP_S)
-            for s in self.sources:
-                s.stopped = False
-            base = {d: c.frames_seen() for d, c in self.clients.items()}
-            t_back = time.monotonic()
-            await self._until(
-                lambda: all(c.frames_seen() - base[d] >= want
-                            for d, c in self.clients.items())
-                and time.monotonic() - t_back >= SETTLE_S + LOOK_S,
-                300.0, "steady state after the stop")
-
-    def _stage_p50(self, stage: str, last_s: float) -> Optional[float]:
-        """The median of one recorder stage over the frames that left it in
-        the last ``last_s`` seconds, in ms; None where no frame has it."""
-        horizon = time.monotonic() - last_s
-        vals = [(tr.spans[stage][1] - tr.spans[stage][0]) * 1000.0
-                for tr in self.server.recorder._completed()
-                if stage in tr.spans and tr.spans[stage][1] >= horizon]
-        return metrics.percentile(vals, 50) if vals else None
+    def read_session_rates(self) -> None:
+        """The rate each display's session runs at, as the server says it
+        (``DisplayState.bp.framerate``: what its capture loop ticks at and
+        its backpressure counts in). A session at another rate than the
+        cell's client asked for is another cell: the run ends here."""
+        for did in self.displays:
+            st = self.server.display_clients.get(did)
+            rate = getattr(getattr(st, "bp", None), "framerate", None)
+            if rate is None:
+                raise RuntimeError(f"{did}: the server names no rate for it")
+            self.session_fps[did] = float(rate)
+        say("session rate, as the server says it: " + ", ".join(
+            f"{d} {r:g}" for d, r in self.session_fps.items())
+            + f" (asked for in SETTINGS: {self.fps:g})")
+        off = {d: r for d, r in self.session_fps.items() if r != self.fps}
+        # and what each capture loop handed its source when it started
+        off.update({f"source {s.number}": s.fps for s in self.sources
+                    if s.fps != self.fps})
+        if off:
+            raise RuntimeError(
+                f"the cell's client asked for framerate {self.fps:g} and "
+                f"the server ran {off}: not the cell that is named")
 
     async def _until(self, cond: Callable[[], bool], timeout_s: float,
                      what: str) -> None:
@@ -270,8 +249,23 @@ class Run:
             lead = min(float(conf.get("start_s", 2.0)), self.seconds / 4)
             span = min(float(conf.get("seconds", 3.0)), self.seconds / 2)
             await asyncio.sleep(lead)
-            self.profile = await trace_mod.capture(
-                os.path.join(self.bench_dir, ".work", "trace"), span)
+            work = os.path.join(self.bench_dir, ".work", "trace")
+            self.trace_asked_at = time.monotonic()
+            self.profile = await trace_mod.capture(work, span)
+            # on the check's machines an ``interpreter`` stall is the
+            # machine standing still
+            held = sum(b - a for a, b in self.stalls_by_kind(
+                self.trace_asked_at, time.monotonic()).get("interpreter", []))
+            if held > RETRACE_STALL_S and \
+                    time.monotonic() + span + 1.0 < self.window[1]:
+                busy = trace_mod.busy_s(self.profile)
+                say(f"traced seconds held {held:.2f} s of interpreter stall "
+                    f"(over {RETRACE_STALL_S:g} s): device busy "
+                    f"{sum(busy.values()) / max(1, len(busy)):.3f} s of "
+                    f"{trace_mod.window_s(self.profile):.3f}; kept here, not "
+                    f"read: the window is traced once more")
+                self.trace_asked_at = time.monotonic()
+                self.profile = await trace_mod.capture(work, span)
         watch = asyncio.create_task(self._watch_gate())
         await asyncio.sleep(max(0.0, self.window[1] - time.monotonic()))
         watch.cancel()
@@ -337,13 +331,16 @@ class Run:
                  if isinstance(v, (int, float, str, bool))})[:400])
         return out
 
-    def stalls_by_kind(self) -> Dict[str, List[Tuple[float, float]]]:
+    def stalls_by_kind(self, t0: Optional[float] = None,
+                       t1: Optional[float] = None
+                       ) -> Dict[str, List[Tuple[float, float]]]:
         """The program's stall watch (PERF.md section 3): (t0, t1) of the
-        stalls that began in the window, by kind; {} where the program has
-        no stall watch or recorded none."""
+        stalls that began in [t0, t1] (the window, where none is given), by
+        kind; {} where the program has no stall watch or recorded none."""
         get = getattr(self.server.recorder, "stalls", None)
+        span = self.window if t0 is None else (t0, t1)
         out: Dict[str, List[Tuple[float, float]]] = {}
-        for kind, a, b in (get(*self.window) if get else ()):
+        for kind, a, b in (get(*span) if get else ()):
             out.setdefault(kind, []).append((a, b))
         return out
 
@@ -477,11 +474,15 @@ class Run:
         w0, w1 = self.window
         lat: List[float] = []
         never = frames = nbytes = 0
+        self.latencies_by_second = {}
         for did in self.displays:
             ch, fr = self.changes_and_frames(did)
             l, n = metrics.latencies_ms(ch, fr, self.seconds)
             lat += l
             never += n
+            for (_index, due), ms in zip(ch, l):
+                self.latencies_by_second.setdefault(
+                    int(due - w0), []).append(ms)
             k, b = metrics.delivered(
                 [(f.t_last, f.nbytes) for f in self.clients[did].frames],
                 w0, w1)
@@ -511,12 +512,18 @@ class Run:
         (PERF.md): how many delivered-frame periods the median change waited
         (Little's law: frames in flight between due and shown), the median
         wait for a fetch, and what the encoder says it holds in flight. The
-        configuration states the band its bounds were measured in; a run
-        outside it is flagged, not failed."""
+        configuration states the band its bounds were measured in, on
+        untraced windows; an untraced run outside it is flagged ``other``,
+        not failed. Under the profiler the driver thread's ``pack`` doubles
+        and the stream runs deeper (7.3 frames in flight traced against
+        4.1-5.5 untraced, PERF.md, PR 39): a traced run is flagged
+        ``traced`` and held to no band."""
         from .readers import in_flight as in_flight_reader, recorder_stage
 
         in_flight = in_flight_reader.read(self, {})
-        band = self.cell.config.get("regime", {}).get("frames_in_flight")
+        regime = self.cell.config.get("regime", {})
+        band = regime.get("frames_in_flight_by_traffic", {}).get(
+            self.cell.traffic_name, regime.get("frames_in_flight"))
         out = {"latency_p95_ms": metrics.percentile(self.latencies_ms, 95),
                "frames_in_flight": in_flight,
                "fetch_wait_p50_ms": recorder_stage.read(
@@ -524,11 +531,15 @@ class Run:
                "inflight_batches": [st.get("inflight_batches")
                                     for st in self.encoder_stats.values()],
                "band": band,
-               "refills_before_window": self.counters.get("regime_rolls"),
+               "session_fps": dict(self.session_fps),
+               "latency_p50_by_second_ms": [
+                   round(metrics.percentile(v, 50), 3) for _k, v in
+                   sorted(self.latencies_by_second.items())],
                "stalled_s": {k: sum(b - a for a, b in v)
                              for k, v in self.stalls_by_kind().items()}}
-        out["regime"] = "not stated" if not band else (
-            "expected" if band[0] <= in_flight <= band[1] else "other")
+        out["regime"] = "traced" if self.trace else (
+            "not stated" if not band else (
+                "expected" if band[0] <= in_flight <= band[1] else "other"))
         return out
 
     def collect_spans(self) -> None:

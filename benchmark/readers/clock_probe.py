@@ -50,9 +50,13 @@ def align(run):
     prof = run.profile
     if prof is None:
         return None
-    conf = run.cell.traffic.get("trace", {})
-    lead = min(float(conf.get("start_s", 2.0)), run.seconds / 4)
-    guess = run.window[0] + lead             # where the session began
+    # where the session began: the harness notes when it asked for it (a
+    # window traced a second time began later); else the mix's lead
+    guess = getattr(run, "trace_asked_at", None)
+    if guess is None:
+        conf = run.cell.traffic.get("trace", {})
+        guess = run.window[0] + min(float(conf.get("start_s", 2.0)),
+                                    run.seconds / 4)
     w0, w1 = prof.window()
     host = pairs(run, guess - 1.0, guess + (w1 / 1e9) + 2.0)
     out = {}

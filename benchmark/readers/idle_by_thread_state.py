@@ -18,7 +18,9 @@ first listed is read then). ``args.thread``, one name, is read as a list of
 one. On several devices a share is the mean over the devices: one thread
 drives them all, and each device's idle intervals are laid over its track.
 None without a trace, without a track from any of the threads, or without
-three probes in the traced seconds."""
+three probes in the traced seconds; the run says on an earlier line which
+of the three it was (``not read: ...``), once for the five metrics that
+share a track, so that a line without them says why."""
 
 from .. import trace
 from ..harness import say
@@ -68,16 +70,23 @@ def by_state(run, threads):
     cache[threads] = None
     prof = run.profile
     track_of = getattr(run.server.recorder, "thread_track", None)
-    offsets = clock_probe.align(run) if track_of is not None else None
-    if prof is None or not offsets:
+    if prof is None or track_of is None:
+        say("idle by thread state: not read: "
+            + ("no trace" if prof is None
+               else "no track (the recorder keeps no thread track)"))
+        return None
+    offsets = clock_probe.align(run)
+    if not offsets:
+        say("idle by thread state: not read: fewer than three probes "
+            "matched in the traced seconds of a device")
         return None
     w0, w1 = prof.window()
     first = min(offsets.values())
     left = [t for t in threads
             if track_of(t, first + w0 / 1e9, first + w1 / 1e9)]
     if not left:
-        say(f"thread track: nothing from {' or '.join(map(repr, threads))} "
-            f"in the traced seconds")
+        say(f"idle by thread state: not read: no track (nothing from "
+            f"{' or '.join(map(repr, threads))} in the traced seconds)")
         return None
     thread = run.driving_thread = left[0]
     say(f"thread track: the device's driver is read from {thread!r}"
@@ -89,8 +98,8 @@ def by_state(run, threads):
                 for a, b in idle_intervals(prof, dev)]
         track = track_of(thread, off + w0 / 1e9, off + w1 / 1e9)
         if not track:
-            say(f"thread track: nothing from {thread!r} in device {dev}'s "
-                f"traced seconds")
+            say(f"idle by thread state: not read: no track (nothing from "
+                f"{thread!r} in device {dev}'s traced seconds)")
             return None
         part = split(idle, track)
         whole = split([(off + w0 / 1e9, off + w1 / 1e9)], track)

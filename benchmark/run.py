@@ -146,8 +146,13 @@ async def run_cell(args, cell, device, rehearsal) -> Dict[str, Any]:
             f"{'not read' if fetch_wait is None else format(fetch_wait, '.3f') + ' ms'}"
             f", encoder's inflight_batches "
             f"{window['inflight_batches']}; the configuration's band "
-            f"{window['band']}: {window['regime']}"
-            f"{'  <-- another regime than the bounds were measured in' if window['regime'] == 'other' else ''}")
+            f"{window['band']}: {window['regime']}" + {
+                "other": "  <-- another regime than the bounds were "
+                         "measured in",
+                "traced": " (the band is of untraced windows: held to none)",
+            }.get(window["regime"], ""))
+        say("latency p50 by the second the change fell due: " + " ".join(
+            format(v, ".1f") for v in window["latency_p50_by_second_ms"]))
 
     # -- correct: the clients' pictures against the desktop ---------------
     t_cmp = time.monotonic()

@@ -121,8 +121,12 @@ def read_ruler(y_top: np.ndarray, height: int, px: int):
     bits = ruler_bits(height, px)
     groups = height // px
 
+    # a group's outer rows are left out where it has inner ones (a codec
+    # blurs across the edge); a group of two rows is read whole
+    trim = 1 if px >= 3 else 0
+
     def group(k: int) -> int:
-        rows = y_top[k * px + 1:(k + 1) * px - 1]
+        rows = y_top[k * px + trim:(k + 1) * px - trim]
         g = 0
         for b in range(bits):
             cell = rows[:, b * RULER_CELL + 3:(b + 1) * RULER_CELL - 3]

@@ -56,6 +56,9 @@ def scratch_checkout(tmp_path, real_root=ROOT):
     for sub in ("configs", "traffic", "layer_metrics"):
         shutil.copytree(os.path.join(real_root, "benchmark", sub),
                         root / "benchmark" / sub)
+    kept = os.path.join(real_root, "benchmark", "kept_cells.json")
+    if os.path.exists(kept):
+        shutil.copy(kept, root / "benchmark" / "kept_cells.json")
     return root
 
 
@@ -147,6 +150,29 @@ def cell_finds_its_files(workload, root):
     return cell
 
 
+def kept_cells_are_whole(spec, root):
+    """A cell that left ``workloads`` and is kept on file
+    (``benchmark/kept_cells.json``): it is in no list of ``BENCHMARK.json``
+    any more, its entry is as it was there, its configuration is still one
+    of ``configs`` (with another cell), its files are on disk, the harness
+    loads it by its name with the metrics that named it, and it says when
+    it returns. Returns the names; [] where no cell is kept."""
+    kept = cells.kept_cells(os.path.join(root, "benchmark"))
+    live = {w["name"] for w in spec["workloads"]}
+    names = {m["name"] for m in spec["per_layer"]}
+    extra = {"per_layer", "left", "returns_when"}
+    for w in kept.get("workloads", []):
+        assert w["name"] not in live and extra <= set(w)
+        workload_entry(spec, {k: v for k, v in w.items() if k not in extra})
+        assert w["returns_when"] and w["left"]
+        assert set(w["per_layer"]) <= names
+        for m in spec["end_to_end"] + spec["per_layer"]:
+            assert w["name"] not in m.get("workloads", [])
+        cell = cell_finds_its_files(w["name"], root)
+        assert {m["name"] for m in cell.per_layer} >= set(w["per_layer"])
+    return [w["name"] for w in kept.get("workloads", [])]
+
+
 def per_layer_metric_has_a_reader(name, root):
     bench_dir = os.path.join(root, "benchmark")
     spec = cells.layer_metric_spec(name, bench_dir)
@@ -190,3 +216,4 @@ def whole(spec, root):
         per_layer_metric_has_a_reader(m["name"], root)
     names_are_unique_and_setup_is_there(spec)
     accepted_entries_are_untouched(spec)
+    kept_cells_are_whole(spec, root)

@@ -191,7 +191,7 @@ def test_a_later_pr_adds_a_cell_a_mix_a_configuration_and_a_metric(tmp_path):
     for w in SPEC["workloads"]:
         there = cells.load_cell(w["name"], root=str(root))
         here = cells.load_cell(w["name"])
-        assert there.traffic_name == here.traffic_name == "scroll"
+        assert there.traffic_name == here.traffic_name == w["traffic"]
         assert there.per_layer == here.per_layer
         assert there.end_to_end == here.end_to_end
 
@@ -243,3 +243,80 @@ def test_metric_entry(m):
 
 def test_names_are_unique_and_setup_is_there():
     spec_checks.names_are_unique_and_setup_is_there(SPEC)
+
+
+# -- what PR 41 added and took out -------------------------------------------
+
+H264_ALONE = ["me_kernel_ms", "me_kernel_roofline", "phase_motion_ms",
+              "cavlc_low_tier_pct", "fetch_prefix_hit_pct",
+              "cavlc_tier_fill_pct"]
+
+
+@pytest.mark.parametrize("name", H264_ALONE)
+def test_what_one_codec_alone_has_names_every_h264_cell(name):
+    """A cell that reports ``delivered_fps`` reports what moves it: the six
+    entries that only the H.264 step can fill list the H.264 cells of
+    ``workloads``, all of them and no other; a cell kept on file says
+    itself which of them named it."""
+    h264 = [w["name"] for w in SPEC["workloads"]
+            if cells.load_cell(w["name"]).config["env"]["SELKIES_ENCODER"]
+            == "x264enc-striped"]
+    m = next(x for x in SPEC["per_layer"] if x["name"] == name)
+    assert m["workloads"] == h264 == ["h264-1080p120.scroll"]
+    assert m["moves"] == "delivered_fps"
+    kept = cells.kept_cells(cells.BENCH_DIR)["workloads"]
+    assert all(name in w["per_layer"] for w in kept)
+
+
+def test_the_cell_above_the_knee_is_the_accepted_h264_deployment_at_120():
+    """No file of sizes of its own: the deployment is the accepted one
+    (the server's default is 60, its range 8-120), and the mix says what
+    its client asks for."""
+    new, knee = cells.load_cell("h264-1080p120.scroll"), \
+        cells.load_cell("h264-1080p60.scroll")
+    assert (new.config_name, new.traffic_name, new.chips) == (
+        "ws-1080p60-h264", "scroll120", 1)
+    assert new.config == knee.config and new.config["reduced"] == []
+    assert new.traffic["client"] == {"framerate": 120}
+    assert new.end_to_end == knee.end_to_end
+    assert new.per_layer == knee.per_layer
+    # one configuration, a band for each of its mixes
+    regime = new.config["regime"]
+    assert regime["frames_in_flight"] == [6.7, 9.8]
+    assert regime["frames_in_flight_by_traffic"] == {"scroll": [3.7, 6.2]}
+
+
+def test_cells_kept_on_file_are_whole_and_load_by_their_names():
+    kept = spec_checks.kept_cells_are_whole(SPEC, ROOT)
+    assert kept == ["h264-1080p60.scroll"]
+    for name in kept:
+        cell = cells.load_cell(name)
+        assert cell.name == name and len(cell.end_to_end) == 4
+    # a name that is in neither place is refused as ever
+    with pytest.raises(SystemExit):
+        cells.load_cell("h264-1080p60.scrol")
+
+
+def test_a_cell_that_leaves_workloads_stays_on_file(tmp_path):
+    """What a ``benchmark`` PR does to take a cell out and keep it: the
+    entry moves from ``BENCHMARK.json`` to ``benchmark/kept_cells.json``
+    with the metrics that named it, no file of the cell is deleted, every
+    check of the spec holds, and the harness still loads the cell. (Its
+    configuration keeps another cell: the contract lets none go empty.)"""
+    root = spec_checks.scratch_checkout(tmp_path)
+    spec = json.loads(json.dumps(SPEC))
+    spec["workloads"].append(dict(
+        spec["workloads"][-1], name="jpeg-1080p60.other", traffic="scroll120",
+        why="a second cell of the last configuration"))
+    gone = spec["workloads"].pop(-2)           # its first cell leaves
+    kept = cells.kept_cells(str(root / "benchmark"))
+    kept["workloads"].append(dict(gone, per_layer=[], left="PR n",
+                                  returns_when="a stated reading"))
+    (root / "benchmark" / "kept_cells.json").write_text(json.dumps(kept))
+    (root / "BENCHMARK.json").write_text(json.dumps(spec, indent=1))
+    spec_checks.whole(spec_checks.read_spec(str(root)), str(root))
+    assert gone["name"] in spec_checks.kept_cells_are_whole(spec, str(root))
+    there, here = cells.load_cell(gone["name"], root=str(root)), \
+        cells.load_cell(gone["name"])
+    assert there.config == here.config and there.traffic == here.traffic
+    assert there.per_layer == here.per_layer

@@ -73,6 +73,103 @@ def test_jpeg_traced_rehearsal_reports_per_layer_and_no_device_metric(capsys):
         out["window"]["frames_in_flight"])
 
 
+# -- the cell above the knee: 120 content steps a second, a client at 120 ----
+
+NEW_CELL = "h264-1080p120.scroll"
+#: what only a device's trace or its clock probe can say: a rehearsal has
+#: neither, and its line leaves these out for that stated reason
+NO_DEVICE_IN_A_REHEARSAL = DEVICE_METRICS | {
+    "phase_colour_ms", "phase_transform_ms", "phase_entropy_ms",
+    "phase_motion_ms", "idle_driver_stage_pct", "idle_driver_pack_pct",
+    "idle_driver_fetch_pct", "idle_driver_sleep_pct",
+    "idle_driver_other_pct", "device_queue_delay_p50_ms"}
+
+
+def test_the_cell_above_the_knee_rehearses_end_to_end(capsys):
+    from benchmark.cells import load_cell
+
+    code, out, err = rehearse(capsys, NEW_CELL, 0, seed=str(2**31 + 41))
+    cell = load_cell(NEW_CELL)
+    assert code == 0 and out["correct"] is True, out
+    # the accepted H.264 deployment (its default is 60), a client at 120
+    assert cell.config["framerate"] == 60 and cell.chips == 1
+    assert cell.traffic["client"] == {"framerate": 120}
+    assert set(out["metrics"]) == {m["name"] for m in cell.end_to_end} == {
+        "delivered_fps", "latency_p50_ms", "wire_kB_per_frame", "setup_s"}
+    assert all(v["value"] > 0 for v in out["metrics"].values())
+    w = out["window"]
+    assert w["latency_p95_ms"] >= out["metrics"]["latency_p50_ms"]["value"]
+    assert w["frames_in_flight"] == pytest.approx(
+        out["metrics"]["latency_p50_ms"]["value"]
+        * out["metrics"]["delivered_fps"]["value"] / 1000.0)
+    # 120 changes a second fall due, whatever is delivered
+    assert 355 <= out["attempted"] <= 361 and out["failed"] == 0
+    assert w["session_fps"] == {"primary": 120.0}
+    assert "session rate, as the server says it: primary 120" in err
+    assert "late against their 120 Hz ticks" in err
+    assert len(w["latency_p50_by_second_ms"]) == 3
+    assert w["regime"] in ("expected", "other") and w["band"] == [6.7, 9.8]
+    # the ruler of two-row groups was read in every frame of the window
+    assert out["compared"]["unreadable"] == {"value": 0.0, "limit": 0}
+    assert "names another content step than the picture shows in 0 of" in err
+    assert list(out)[-1] == "compared"
+
+
+def test_the_cell_above_the_knee_traced_every_listed_key_or_a_stated_reason(
+        capsys):
+    from benchmark.cells import load_cell
+
+    code, out, err = rehearse(capsys, NEW_CELL, 1)
+    assert code == 0 and out["correct"] is True, out
+    listed = {m["name"] for m in load_cell(NEW_CELL).per_layer}
+    # the six that one codec alone has name this cell
+    assert {"me_kernel_ms", "me_kernel_roofline", "phase_motion_ms",
+            "cavlc_low_tier_pct", "fetch_prefix_hit_pct",
+            "cavlc_tier_fill_pct"} <= listed
+    got = out["metrics"]
+    assert set(got) <= listed
+    missing = listed - set(got)
+    assert missing <= NO_DEVICE_IN_A_REHEARSAL, \
+        missing - NO_DEVICE_IN_A_REHEARSAL
+    assert not (set(got) & DEVICE_METRICS)
+    for name in ("cavlc_low_tier_pct", "fetch_prefix_hit_pct",
+                 "cavlc_tier_fill_pct"):
+        assert 0.0 < got[name]["value"] <= 100.0 and got[name]["unit"] == "%"
+    # half of the captures find _in_q full where the step does not keep up
+    assert got["submit_drop_pct"]["value"] >= 0.0
+    assert got["frames_in_flight"]["value"] == pytest.approx(
+        out["window"]["frames_in_flight"])
+    # a traced window is held to no band, and the idle split says why it
+    # read nothing
+    assert out["window"]["regime"] == "traced"
+    assert "idle by thread state: not read:" in err
+
+
+def test_the_cell_on_its_knee_is_kept_on_file_and_still_runs(capsys):
+    """``h264-1080p60.scroll`` left ``workloads`` with PR 41 (PERF.md
+    section 2); the first test of this file still runs it from its files.
+    Here: what the harness knows of it (its own band, the session at the
+    configuration's 60, every end-to-end metric, the six H.264 metrics)."""
+    from benchmark.cells import BENCH_DIR, kept_cells, load_cell
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        live = {w["name"] for w in json.load(f)["workloads"]}
+    kept = {w["name"]: w for w in kept_cells(BENCH_DIR)["workloads"]}
+    assert "h264-1080p60.scroll" in kept and "h264-1080p60.scroll" not in live
+    assert "15.5 ms" in kept["h264-1080p60.scroll"]["returns_when"]
+    cell = load_cell("h264-1080p60.scroll")
+    assert (cell.config_name, cell.traffic_name) == ("ws-1080p60-h264",
+                                                     "scroll")
+    assert "client" not in cell.traffic and cell.config["framerate"] == 60
+    assert {m["name"] for m in cell.per_layer} == {
+        m["name"] for m in load_cell(NEW_CELL).per_layer}
+    code, out, err = rehearse(capsys, "h264-1080p60.scroll", 0, seed="41")
+    assert code == 0 and out["correct"] is True, out
+    assert out["window"]["band"] == [3.7, 6.2]
+    assert out["window"]["session_fps"] == {"primary": 60.0}
+    assert 175 <= out["attempted"] <= 181
+
+
 def test_without_a_tpu_the_run_command_refuses():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(

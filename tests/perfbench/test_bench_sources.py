@@ -92,3 +92,79 @@ def test_anchor_pins_the_content_phase_to_the_capture_ticks():
     # content steps now fall half a tick before each call's tick phase
     phase = ((200.002 - src.origin) % tick) / tick
     assert phase == pytest.approx(0.5, abs=0.02)
+
+
+# -- scroll120: the same generator, another data file ------------------------
+
+def mix(name):
+    import json
+
+    with open(os.path.join(os.path.dirname(scroll.__file__), "..", "traffic",
+                           name + ".json")) as f:
+        return json.load(f)
+
+
+def make120(seed=3, w=256, h=144):
+    clock = Clock()
+    src = scroll.Source(w, h, 120.0, 0, CallLog(), seed,
+                        mix("scroll120")["params"], clock=clock)
+    return src, clock
+
+
+def test_scroll120_is_scroll_at_twice_the_steps_and_half_the_step():
+    a, b = mix("scroll"), mix("scroll120")
+    assert b["generator"] == a["generator"] == "scroll"
+    assert b["params"] == {"px_per_step": 2, "steps_per_s": 120.0,
+                           "phase_ticks": 0.5}
+    # the same 240 px a second on the screen
+    assert b["params"]["px_per_step"] * b["params"]["steps_per_s"] == \
+        a["params"]["px_per_step"] * a["params"]["steps_per_s"]
+    for key in ("steady", "drain_s", "check_frames", "trace"):
+        assert b[key] == a[key], key
+
+
+def test_scroll120_steps_120_times_a_second_by_the_clock():
+    src, clock = make120()
+    first = src.next_frame().copy()
+    clock.t += 0.5                       # half a second, however many calls
+    later = src.next_frame()
+    assert src.log.entries == [(0, 0), (0, 60)]
+    assert (later == np.roll(first, -2 * 60, axis=0)).all()
+    # one step moves the picture 2 px, and the step before it differs
+    assert (src.frame(61) == np.roll(src.frame(60), -2, axis=0)).all()
+    assert (src.frame(61) != src.frame(60)).any()
+
+
+def test_scroll120_changes_fall_due_every_120th_of_a_second():
+    src, clock = make120()
+    due = src.due_times(clock.t + 1.0, clock.t + 1.05)
+    assert [k for k, _ in due] == [120, 121, 122, 123, 124, 125]
+    assert due[0][1] == pytest.approx(clock.t + 1.0)
+    assert due[1][1] - due[0][1] == pytest.approx(1 / 120.0)
+    assert len(src.due_times(clock.t, clock.t + 30.0)) == 3599   # not k = 0
+
+
+@pytest.mark.parametrize("index", [0, 1, 35, 36, 37, 71, 72, 1000])
+def test_a_ruler_of_two_row_groups_says_which_step_a_picture_shows(index):
+    src, clock = make120()
+    y = check.ycbcr_of(src.frame(index))[0]
+    assert src.read_index(y[:16], 0.0, hint=index + 3) == index
+    assert src.read_index(y[:16], 0.0, hint=max(0, index - 5)) == index
+    assert src.read_index(y[:16], clock.t + (index + 2) / 120.0) == index
+    # the step before it is another picture to the ruler
+    assert src.read_index(check.ycbcr_of(src.frame(index + 1))[0][:16], 0.0,
+                          hint=index) == index + 1
+
+
+def test_scroll120_pins_the_phase_at_half_of_an_8_ms_tick():
+    src, clock = make120()
+    tick = 1.0 / 120.0
+    for n in range(40):                  # calls 2 ms after each tick
+        clock.t = 200.0 + n * tick + 0.002
+        src.next_frame()
+    src.anchor()
+    phase = ((200.002 - src.origin) % tick) / tick
+    assert phase == pytest.approx(0.5, abs=0.02)
+    # and the lateness of calls is told against 120 Hz ticks
+    late = src.tick_lateness_ms(200.0, 200.0 + 40 * tick)
+    assert len(late) == 40 and max(late) < 0.01
