@@ -27,6 +27,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability.device_probe import ReadyStamp, ReadyWatch
 from .h264_device import StagingRing, StagingTicket
 from .jpeg import META_WORDS_PER_STRIPE, JpegStripeEncoder, StripeOutput, split_meta
 
@@ -53,7 +54,13 @@ class _PipelineTelemetry:
     them with :meth:`pop_trace` and folds them into that frame's
     :class:`~selkies_tpu.observability.tracing.FrameTrace`. The same
     clock readings go to ``track(state, t0, t1)`` when the owner (the
-    async driver) has set it: the owning thread's timeline."""
+    async driver) has set it: the owning thread's timeline.
+
+    The ready watch (ISSUE 42): each launch hands its step's own output to
+    a ``tpuenc-ready`` thread, which stamps when it became ready; at
+    harvest the stamp splits ``in_device`` + ``fetch_wait`` into
+    ``device_wait``, ``device_run`` and ``ready_wait``
+    (observability/device_probe.py ``ready_stages``)."""
 
     #: ``callable(state, t0, t1)`` or None: where the thread that drives
     #: this pipe reports the states it is in (AsyncEncodeDriver sets it)
@@ -67,6 +74,7 @@ class _PipelineTelemetry:
         #: oldest-first so an un-popping caller (tests, mesh) can
         #: never grow it unboundedly
         self._trace_out: "dict" = {}
+        self._ready_watch = ReadyWatch("tpuenc-ready")
 
     def compiling_for_s(self) -> float:
         """The base encoder's first-use compile signal (runtime.CompileWatch)."""
@@ -88,7 +96,8 @@ class _PipelineTelemetry:
         if self.track is not None:
             self.track(state, t0, t1)
 
-    def _trace_store(self, seq: int, intervals: dict) -> None:
+    def _trace_store(self, seq: int, intervals: dict,
+                     ready: Optional[ReadyStamp] = None) -> None:
         if not intervals:
             return
         d, f = intervals.get("dispatch"), intervals.get("fetch_wait")
@@ -96,6 +105,9 @@ class _PipelineTelemetry:
             # launched -> the driver saw the result ready (or began to
             # block for it): queued behind earlier steps and running
             intervals["in_device"] = (d[1], max(d[1], f[0]))
+            # the same time and the fetch, told apart by when the step's
+            # output became ready
+            intervals.update(self._ready_watch.stages(d[1], f[1], ready))
         self._trace_out[seq] = intervals
         while len(self._trace_out) > 4 * max(8, getattr(self, "depth", 8)):
             self._trace_out.pop(next(iter(self._trace_out)))
@@ -136,7 +148,13 @@ class _PipelineTelemetry:
             "inflight_batches_max": self.inflight_batches_max,
             "dispatch_p50_ms": round(_p50(self._dispatch_ms), 3),
             "fetch_wait_p50_ms": round(_p50(self._fetch_wait_ms), 3),
+            **self._ready_watch.counts(),
         }
+
+    def _publish_launch_idle(self, st: dict) -> None:
+        if st["launches"]:
+            self.metrics.set_launch_idle_share(
+                st["launches_into_idle"] / st["launches"])
 
 
 @dataclass
@@ -174,6 +192,8 @@ class _InFlight:
     ticket: Optional[StagingTicket] = None
     #: per-frame stage intervals for the flight recorder
     trace: Dict[str, Tuple[float, float]] = field(default_factory=dict)
+    #: when this frame's step was ready (and the step launched before it)
+    ready: Optional[ReadyStamp] = None
 
 
 class PipelinedJpegEncoder(_PipelineTelemetry):
@@ -249,6 +269,7 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
             self.metrics.set_host_entropy_ms_per_frame(
                 st["host_entropy_ms_per_frame"])
             self.metrics.set_inflight_batches(st["inflight_batches"])
+            self._publish_launch_idle(st)
 
     @property
     def n_inflight(self) -> int:
@@ -318,6 +339,7 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
         # mark again at harvest in _decide_emits).
         b._painted |= paint_candidate
         qsel = jnp.asarray(paint_candidate.astype(np.int32))
+        ahead = self._ready_watch.ahead
         with b.compile_watch.first_use("step"):
             packed, new_prev, yq, cbq, crq = b._step(
                 frame, b._prev, b._qy, b._qc, qsel,
@@ -327,6 +349,7 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
             seq=self._seq, paint_candidate=paint_candidate,
             packed=packed, yq=yq, cbq=cbq, crq=crq, ticket=ticket,
         )
+        item.ready = self._ready_watch.launched(packed, ahead)
         if stage_iv is not None:
             self._mark(item.trace, "stage", *stage_iv)
         td1 = time.monotonic()
@@ -438,7 +461,7 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
         nbytes_np, base_np, ovf_np = item.meta
         emit, is_paint = item.emit, item.is_paint
         if not emit.any() or item.words_np is None:
-            self._trace_store(item.seq, item.trace)
+            self._trace_store(item.seq, item.trace, item.ready)
             return []
         t0 = time.monotonic()
         scans = b._scans_from_packed(
@@ -447,7 +470,7 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
         out = b._assemble(emit, is_paint, scans)
         t1 = time.monotonic()
         self._mark(item.trace, "pack", t0, t1)
-        self._trace_store(item.seq, item.trace)
+        self._trace_store(item.seq, item.trace, item.ready)
         self.host_entropy_ms_total += (t1 - t0) * 1000.0
         self._publish_metrics()
         return out
@@ -509,6 +532,7 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
         self._unfetched.clear()
         self._ready.clear()
         self._trace_out.clear()
+        self._ready_watch.stop()
         self._staging.release_all()
 
 
@@ -678,6 +702,8 @@ class _H264InFlight:
     ticket: Optional[StagingTicket] = None
     #: per-frame stage intervals for the flight recorder
     trace: Dict[str, Tuple[float, float]] = field(default_factory=dict)
+    #: when this frame's step was ready (and the step launched before it)
+    ready: Optional[ReadyStamp] = None
 
 
 class PipelinedH264Encoder(_PipelineTelemetry):
@@ -757,6 +783,7 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             self.metrics.set_host_entropy_ms_per_frame(
                 st["host_entropy_ms_per_frame"])
             self.metrics.set_inflight_batches(st["inflight_batches"])
+            self._publish_launch_idle(st)
             if st["cavlc_frames"]:
                 self.metrics.set_cavlc_low_tier_share(
                     st["cavlc_low_tier_frames"] / st["cavlc_frames"])
@@ -798,6 +825,7 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             frame, slot = self._staging.stage(
                 np.asarray(frame, dtype=np.uint8))
         td0 = time.monotonic()
+        ahead = self._ready_watch.ahead
         try:
             # the encoder starts the frame's own host copy (head, or an
             # IDR's flat16) behind the step it has just enqueued
@@ -809,6 +837,11 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             raise
         item = _H264InFlight(seq=self._seq, pending=p,
                              ticket=StagingTicket(self._staging, slot))
+        # the buffer the P step wrote (an IDR's step writes its fetch),
+        # not the prefix slice a program of its own cuts from it
+        buf = getattr(p, "buf", None)
+        item.ready = self._ready_watch.launched(
+            buf if buf is not None else p.fetch, ahead)
         if slot is not None:
             self._mark(item.trace, "stage", ts0, td0)
         td1 = time.monotonic()
@@ -848,7 +881,7 @@ class PipelinedH264Encoder(_PipelineTelemetry):
             # must free its staging slot, or the ring stalls forever
             self._release_ticket(item)
         self._mark(item.trace, "pack", t0, time.monotonic())
-        self._trace_store(item.seq, item.trace)
+        self._trace_store(item.seq, item.trace, item.ready)
         self.frames_completed += 1
         return item.seq, out
 
@@ -894,5 +927,6 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         self._inflight.clear()
         self._ready.clear()
         self._trace_out.clear()
+        self._ready_watch.stop()
         # a rebuilt pipeline must never inherit phantom-busy ring slots
         self._staging.release_all()

@@ -1,11 +1,11 @@
 """Prometheus metrics + observability HTTP endpoint.
 
 Parity with ``legacy/metrics.py:39-75``: ``fps`` gauge, ``fps_hist``
-histogram, ``latency`` gauge, and a ``webrtc_statistics`` Info (the
-reference's ``gpu_utilization`` had no source here and is gone: the device
-probe's ``device_queue_delay_ms`` says how busy the chip is) — plus tpuenc-specific series
-(encode ms, stripe bytes, backpressure state) and the flight-recorder
-stage series (docs/observability.md). Falls back to a no-op registry
+histogram and ``latency`` gauge (the reference's ``gpu_utilization`` and
+``webrtc_statistics`` had no source here and are gone: the device probe's
+``device_queue_delay_ms`` says how busy the chip is) — plus tpuenc-specific
+series (transfer and entropy gauges, backpressure state) and the
+flight-recorder stage series (docs/observability.md). Falls back to a no-op registry
 when prometheus_client is unavailable so the server never grows a hard
 dependency.
 
@@ -29,14 +29,14 @@ import logging
 import os
 import tempfile
 import threading
-from typing import Dict, Optional
+from typing import Any, Dict, Optional
 
 logger = logging.getLogger("selkies_tpu.observability.metrics")
 
 try:
     import prometheus_client as prom
     from prometheus_client import (CollectorRegistry, Counter, Gauge,
-                                   Histogram, Info)
+                                   Histogram)
     HAVE_PROM = True
 except Exception:  # pragma: no cover
     HAVE_PROM = False
@@ -69,14 +69,6 @@ class Metrics:
             registry=self.registry)
         self.latency = Gauge("latency", "Latency observed by client (ms)",
                              registry=self.registry)
-        self.encode_ms = Histogram(
-            "tpuenc_encode_ms", "Per-frame encode wall time (ms)",
-            buckets=(1, 2, 4, 8, 16, 33, 66, 100, float("inf")),
-            registry=self.registry)
-        self.frame_bytes = Histogram(
-            "tpuenc_frame_bytes", "Encoded bytes per frame",
-            buckets=(1e3, 5e3, 2e4, 5e4, 1e5, 2.5e5, 1e6, float("inf")),
-            registry=self.registry)
         # ISSUE 1: the H.264 bottleneck claims (D2H transfer size, host
         # entropy cost per session) must be measured, not inferred — the
         # pipelined encoders record these per frame
@@ -104,6 +96,12 @@ class Metrics:
             "tpuenc_fetch_prefix_hit_share", "Share of device-CAVLC P "
             "frames whose fetch prefix, sized at dispatch, held the whole "
             "payload (no undershoot re-read at harvest)",
+            registry=self.registry)
+        self.launch_idle_share = Gauge(
+            "tpuenc_launch_idle_share", "Share of step launches that found "
+            "nothing of their stream unfinished on the chip (the ready "
+            "watch had seen every earlier step's output ready): the chip "
+            "stood idle before each of them",
             registry=self.registry)
         # ISSUE 12: the dispatch/fetch-floor claims must stay measured —
         # the async pipeline driver keeps >=2 batches in flight, and
@@ -236,9 +234,13 @@ class Metrics:
         self.frame_stage_ms = Histogram(
             "frame_stage_ms", "Per-frame wall time in one pipeline stage "
             "(capture/submit_wait/pipe_wait/stage/dispatch/in_device/"
-            "fetch_wait/pack/lane_step/harvest_wait/queue/send/ack)",
+            "device_wait/device_run/ready_wait/fetch_wait/pack/lane_step/"
+            "harvest_wait/queue/send/ack)",
             ("stage", "display"), buckets=_stage_buckets,
             registry=self.registry)
+        #: (display, stage) -> the labelled child: a frame closes with 16
+        #: stages, and ``labels()`` validates and locks on every call
+        self._stage_children: Dict[tuple, Any] = {}
         self.glass_to_glass_ms = Histogram(
             "glass_to_glass_ms", "Capture start to CLIENT_FRAME_ACK per "
             "acked frame (the latency the user feels)",
@@ -275,8 +277,6 @@ class Metrics:
         self.backpressured = Gauge(
             "backpressured_displays", "Displays currently throttled by the "
             "frame-ACK backpressure loop", registry=self.registry)
-        self.webrtc_stats = Info("webrtc_statistics", "Last WebRTC stats",
-                                 registry=self.registry)
 
     def start_http(self) -> bool:
         """Expose /metrics + /healthz + /debug/trace [+ /debug/jax-trace]
@@ -328,11 +328,6 @@ class Metrics:
         if HAVE_PROM:
             self.latency.set(ms)
 
-    def observe_encode(self, ms: float, nbytes: int) -> None:
-        if HAVE_PROM:
-            self.encode_ms.observe(ms)
-            self.frame_bytes.observe(nbytes)
-
     def set_d2h_bytes_per_frame(self, nbytes: float) -> None:
         if HAVE_PROM:
             self.d2h_bytes_per_frame.set(nbytes)
@@ -353,6 +348,10 @@ class Metrics:
         if HAVE_PROM:
             self.fetch_prefix_hit_share.set(share)
 
+    def set_launch_idle_share(self, share: float) -> None:
+        if HAVE_PROM:
+            self.launch_idle_share.set(share)
+
     def set_inflight_batches(self, n: int) -> None:
         if HAVE_PROM:
             self.inflight_batches.set(n)
@@ -367,8 +366,11 @@ class Metrics:
 
     def observe_stage(self, display: str, stage: str, ms: float) -> None:
         if HAVE_PROM:
-            self.frame_stage_ms.labels(stage=stage, display=display) \
-                .observe(ms)
+            child = self._stage_children.get((display, stage))
+            if child is None:
+                child = self._stage_children[(display, stage)] = \
+                    self.frame_stage_ms.labels(stage=stage, display=display)
+            child.observe(ms)
 
     def observe_glass_to_glass(self, display: str, ms: float) -> None:
         if HAVE_PROM:
@@ -481,11 +483,6 @@ class Metrics:
     def set_backpressured(self, n: int) -> None:
         if HAVE_PROM:
             self.backpressured.set(n)
-
-    def set_webrtc_stats(self, stats: Dict[str, str]) -> None:
-        if HAVE_PROM:
-            self.webrtc_stats.info(
-                {str(k): str(v) for k, v in stats.items()})
 
     def render(self) -> bytes:
         """Current exposition text (for tests / ad-hoc scraping)."""

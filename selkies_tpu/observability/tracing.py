@@ -84,8 +84,9 @@ __all__ = [
 #: ``queue``) time the frame sitting somewhere, and are marked where the
 #: wait ends. Together they run from capture to ACK without a hole, on the
 #: solo driver (``encoder/async_driver.py``) and on a mesh lane
-#: (``parallel/coordinator.py``) under the same names; ``lane_step`` alone
-#: lies across the others and is no part of the path.
+#: (``parallel/coordinator.py``) under the same names; ``lane_step`` lies
+#: across the others, the three stages the ready watch gives lie inside
+#: ``in_device`` + ``fetch_wait``, and none of the four is part of the path.
 #:
 #: capture       host wall time in ``source.next_frame()``
 #: submit_wait   accepted into the driver's submit queue -> taken out (a
@@ -99,7 +100,23 @@ __all__ = [
 #:               of each new frame through that chip's ring)
 #: dispatch      device program launch (not device compute)
 #: in_device     dispatch done -> the driver sees the result ready or
-#:               begins to block for it (queued and running on the device)
+#:               begins to block for it (queued and running on the device):
+#:               ``device_wait``, ``device_run`` and the part of
+#:               ``ready_wait`` before the fetch began
+#: device_wait   dispatch done (L) -> the step launched before it on the
+#:               same chip had its output ready (max(L, R')): queued behind
+#:               earlier steps
+#: device_run    max(L, R') -> the step's own output ready (R, as the ready
+#:               watch stamped it, clipped into [L, F]): the chip free for
+#:               it to its output ready: the step, and the small programs
+#:               between steps
+#: ready_wait    R -> ``fetch_wait`` done (F): the result lies on the chip
+#:               and the host has not got it (the fetch program and the
+#:               copy, a JPEG pair waiting to fill, the driver busy with
+#:               the frame before or asleep); a frame the driver blocked
+#:               for reads its copy alone. The three tile ``in_device`` +
+#:               ``fetch_wait`` and, as ``lane_step``, are no part of the
+#:               path; a frame harvested before its stamp landed has none
 #: fetch_wait    host time blocked materializing the D2H fetch
 #: pack          host-side entropy glue / stripe assembly
 #: lane_step     mesh lanes only: the worker's occupied time in the tick
@@ -118,8 +135,9 @@ __all__ = [
 #: ``stage`` inside ``dispatch``; one without the harvest's split keeps
 #: ``pack`` inside ``fetch_wait``.
 STAGES = ("capture", "submit_wait", "pipe_wait", "stage", "dispatch",
-          "in_device", "fetch_wait", "pack", "lane_step", "harvest_wait",
-          "queue", "send", "ack")
+          "in_device", "device_wait", "device_run", "ready_wait",
+          "fetch_wait", "pack", "lane_step", "harvest_wait", "queue", "send",
+          "ack")
 
 #: states a worker thread writes to the thread track
 THREAD_STATES = ("stage", "dispatch", "fetch_wait", "pack", "emit", "sleep")

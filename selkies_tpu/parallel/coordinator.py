@@ -57,6 +57,7 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from ..observability.device_probe import ReadyStamp, ReadyWatch
 from ..robustness import SlotHealth, backoff_delay
 
 logger = logging.getLogger("selkies_tpu.parallel")
@@ -73,6 +74,8 @@ _lane_ids = itertools.count()
 #: the worker thread's name, and the name of its track in the flight
 #: recorder (the solo driver's is ``tpuenc-async``)
 WORKER_THREAD = "mesh-encode"
+#: the thread that stamps when each launched step's output is ready
+READY_THREAD = "mesh-ready"
 
 
 def _p50(samples, ndigits: int = 3) -> float:
@@ -169,11 +172,17 @@ class MeshSessionFacade:
     def pop_trace(self, seq: int):
         """Flight-recorder stage intervals for a harvested frame: the
         solo driver's seven from ``submit_wait`` to ``pack``, tiling
-        acceptance to the harvest's end, and ``lane_step``
+        acceptance to the harvest's end, ``lane_step``, and the ready
+        watch's three inside ``in_device`` + ``fetch_wait``
         (``_harvest_oldest`` says where each begins and ends, and what an
         injected encoder without the launch mark or the harvest's split
         keeps coarse; docs/observability.md)."""
         return self._coord._pop_trace(self.sid, seq)
+
+    def stats(self) -> dict:
+        """What the solo pipes count a launch, of this session's
+        coordinator (its lanes share one worker and one ready watch)."""
+        return self._coord.launch_stats()
 
     def close(self) -> None:
         if not self.closed:
@@ -226,7 +235,7 @@ class _Batch(list):
     rows through the in-flight window to the harvest, which writes them
     into each frame's trace."""
 
-    __slots__ = ("t_taken", "accepted", "t_launch", "t_step_end")
+    __slots__ = ("t_taken", "accepted", "t_launch", "t_step_end", "ready")
 
     def __init__(self, t_taken: float) -> None:
         super().__init__()
@@ -238,6 +247,9 @@ class _Batch(list):
         self.t_launch: Optional[float] = None
         #: the end of the lane's part of the tick that took the rows
         self.t_step_end: Optional[float] = None
+        #: when the step's output was ready on the chips, and the step the
+        #: worker launched before it (the ready watch's stamp)
+        self.ready: Optional[ReadyStamp] = None
 
 
 class _Lane:
@@ -350,6 +362,10 @@ class MeshEncodeCoordinator:
         #: when the worker last finished a tick that did work: where its
         #: ``sleep`` begins
         self._worked_until: Optional[float] = None
+        #: stamps when each launched step's output is ready (thread
+        #: ``mesh-ready``): a lane frame's ``device_wait``, ``device_run``
+        #: and ``ready_wait``, and the two counts a launch
+        self._ready_watch = ReadyWatch(READY_THREAD)
 
         #: bounded in-flight window PER LANE (ISSUE 12): up to
         #: ``max_inflight`` dispatched ticks ride the device at once —
@@ -655,6 +671,7 @@ class MeshEncodeCoordinator:
     def stop(self) -> None:
         self._stop.set()
         self._kick.set()
+        self._ready_watch.stop()
         if self._thread is not None:
             self._thread.join(timeout=5.0)
             self._thread = None
@@ -724,6 +741,9 @@ class MeshEncodeCoordinator:
                 # loss); account for the re-spawn so it is observable
                 self.worker_restarts_total += 1
             self._stop.clear()
+            # stop() ended it with the worker: a worker started again
+            # gets its stamps again
+            self._ready_watch.resume()
             self._thread = threading.Thread(
                 target=self._run, name=WORKER_THREAD, daemon=True)
             self._thread.start()
@@ -755,6 +775,10 @@ class MeshEncodeCoordinator:
                 # interruptible: stop() must not wait out the backoff
                 self._stop.wait(backoff_delay(
                     self._consecutive_tick_failures, 0.5, 5.0))
+
+    def launch_stats(self) -> dict:
+        """Integers with one writer each: read without the lock."""
+        return self._ready_watch.counts()
 
     def stats(self) -> dict:
         """Scheduler + per-slot fault accounting for health feeds/tests."""
@@ -796,6 +820,7 @@ class MeshEncodeCoordinator:
                 "sfe_fetch_ms_p50": _p50(self._fetch_ms_window),
                 "sfe_concat_ms_p50": _p50(self._concat_ms_window),
                 "lane_detail": lane_detail,
+                **self.launch_stats(),
             }
 
     def verify_slot_accounting(self) -> List[str]:
@@ -869,6 +894,9 @@ class MeshEncodeCoordinator:
         #   fetch_wait   D2H materialization (last_harvest_stages, with
         #                per-shard attribution for SFE lanes)
         #   pack         host slice-concat / entropy glue
+        # device_wait, device_run and ready_wait tile in_device +
+        # fetch_wait by when the step's output was ready (the ready
+        # watch's stamp; none where it has not landed),
         # and lane_step lies across them: the worker's occupied time in
         # the tick that took the frame (to this harvest's end where the
         # frame is harvested in that same tick). An encoder that does
@@ -893,6 +921,8 @@ class MeshEncodeCoordinator:
             trace_iv["dispatch"] = (took.t_launch, dispatch_iv[1])
         if has_split:
             trace_iv["pack"] = (t_split, t1)
+        trace_iv.update(self._ready_watch.stages(
+            dispatch_iv[1], t_split, took.ready))
         # encoder-internal stripe-job failures (whole-frame containment
         # withheld the AU without raising) must charge the slot exactly
         # like a harvest raise or an injected fault — otherwise a sick
@@ -1030,9 +1060,13 @@ class MeshEncodeCoordinator:
             while took and len(lane.inflight_q) >= self.max_inflight:
                 self._harvest_oldest(lane)
             t_disp0 = time.monotonic()
+            ahead = self._ready_watch.ahead
             pending = lane.enc.dispatch(frames) if took else None
             if pending is not None:
                 t_disp1 = time.monotonic()
+                step_out = getattr(pending, "step_out", None)
+                if step_out is not None:
+                    took.ready = self._ready_watch.launched(step_out, ahead)
                 # where staging ended and the launch began, if the
                 # encoder says (as last_harvest_stages says the harvest's
                 # split): an injected fake without it keeps one dispatch

@@ -403,6 +403,12 @@ class _MeshPending:
     stride: int
     tickets: list               # staged pieces this dispatch replaced
 
+    @property
+    def step_out(self):
+        """The step's own output (the coordinator's ready watch stamps
+        when it is ready): not the prefix a slice program cuts from it."""
+        return self.packed
+
 
 class MeshStripeEncoder:
     """Multi-session JPEG-stripe encoder over a device mesh: one sharded

@@ -176,6 +176,12 @@ class _MeshH264Pending:
     qp: np.ndarray                # [N, S] int — qp each stripe coded at
     tickets: list                 # staged pieces this dispatch replaced
 
+    @property
+    def step_out(self):
+        """The step's own output that the pending keeps (the
+        coordinator's ready watch stamps when it is ready)."""
+        return self.flat16
+
 
 class MeshH264Encoder:
     """N solo H264StripeEncoders collapsed into one SPMD program.

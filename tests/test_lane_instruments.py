@@ -24,7 +24,7 @@ import pytest
 from selkies_tpu.observability import FlightRecorder, Metrics
 from selkies_tpu.observability.tracing import STAGES, THREAD_STATES
 from selkies_tpu.parallel.coordinator import (
-    WORKER_THREAD, MeshEncodeCoordinator)
+    READY_THREAD, WORKER_THREAD, MeshEncodeCoordinator)
 from selkies_tpu.robustness import FakeMeshEncoder
 
 W, H, STRIPE_H = 64, 48, 16
@@ -32,6 +32,8 @@ W, H, STRIPE_H = 64, 48, 16
 TILING = ("submit_wait", "pipe_wait", "stage", "dispatch", "in_device",
           "fetch_wait", "pack")
 WORKER_STATES = {"stage", "dispatch", "fetch_wait", "pack", "sleep"}
+#: what the ready watch's stamp makes of ``in_device`` + ``fetch_wait``
+READY = ("device_wait", "device_run", "ready_wait")
 
 
 def frame_of(rng):
@@ -103,7 +105,10 @@ def test_a_served_lanes_frames_carry_the_eight_stages_and_they_tile(
     traces = served["traces"][served["facades"][session].sid]
     assert len(traces) >= 10
     for iv in traces:
-        assert iv is not None and set(iv) == set(TILING) | {"lane_step"}
+        # (and the ready watch's three inside in_device + fetch_wait,
+        # where the frame's stamp had landed: tested further down)
+        assert iv is not None and set(iv) - set(READY) == \
+            set(TILING) | {"lane_step"}
         assert set(iv) <= set(STAGES)
         for a, b in zip(TILING, TILING[1:]):
             assert iv[a][0] <= iv[a][1] == iv[b][0], (a, b, iv)
@@ -356,3 +361,191 @@ def test_the_worker_thread_is_named_as_its_track():
         assert WORKER_THREAD in {t.name for t in threading.enumerate()}
     finally:
         coord.stop()
+
+
+# ---------------------------------------------------------------------------
+# the ready watch on a lane (ISSUE 42): device_wait, device_run, ready_wait
+
+
+def lane_tiles(iv):
+    """A lane frame's three stages tile ``in_device`` + ``fetch_wait``."""
+    d, f = iv["dispatch"], iv["fetch_wait"]
+    w, r, q = (iv[s] for s in READY)
+    assert d[1] == w[0] <= w[1] == r[0] <= r[1] == q[0] <= q[1] == f[1]
+    parts = sum(iv[s][1] - iv[s][0] for s in READY)
+    both = sum(iv[s][1] - iv[s][0] for s in ("in_device", "fetch_wait"))
+    assert parts == pytest.approx(both, abs=1e-9)
+    assert parts == pytest.approx(f[1] - d[1], abs=1e-9)
+
+
+@pytest.mark.parametrize("session", [0, 1, 2])
+def test_a_served_lanes_frames_carry_the_ready_watchs_three_and_they_tile(
+        served, session):
+    facade = served["facades"][session]
+    traces = served["traces"][facade.sid]
+    split = [iv for iv in traces if set(READY) <= set(iv)]
+    assert len(split) >= 0.5 * len(traces) > 0
+    for iv in split:
+        lane_tiles(iv)
+        assert iv["in_device"] == (iv["dispatch"][1], max(
+            iv["dispatch"][1], iv["fetch_wait"][0]))
+    # sessions taken in one tick share the step, so its stamp too
+    st = facade.stats()
+    assert st == served["coord"].launch_stats()
+    assert st["launches"] >= len(traces) and st["launches_into_idle"] >= 1
+    assert st["launches"] == served["coord"].stats()["launches"]
+
+
+class StepOut:
+    """A lane step's output: ready when the test says."""
+
+    def __init__(self):
+        self.gate = threading.Event()
+        self.raises = None
+
+    def block_until_ready(self):
+        assert self.gate.wait(10.0)
+        if self.raises is not None:
+            raise self.raises
+
+
+class Stamped(list):
+    """A pending that says which buffer its step wrote."""
+
+    step_out = None
+
+
+class StampedEncoder(HeldEncoder):
+    def __init__(self, n):
+        super().__init__(n)
+        self.outs = []
+
+    def dispatch(self, frames):
+        pending = Stamped(super().dispatch(frames))
+        pending.step_out = StepOut()
+        self.outs.append(pending.step_out)
+        return pending
+
+
+def stamped_by_hand(max_inflight=3):
+    """``by_hand`` over an encoder whose step outputs land when the test
+    says, with a watch of its own (``stop()`` ended the coordinator's)."""
+    from selkies_tpu.observability.device_probe import ReadyWatch
+
+    coord = MeshEncodeCoordinator(
+        "session:1", 1, W, H, slots_per_lane=1, max_lanes=1,
+        max_inflight=max_inflight, enc_factory=StampedEncoder)
+    facade = coord.acquire(W, H)
+    coord.stop()
+    assert coord._ready_watch.stopped
+    coord._ready_watch = watch = ReadyWatch(READY_THREAD)
+    return coord, facade, coord.lanes[0].enc, watch
+
+
+def wait_for(cond, timeout=5.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, "timed out"
+        time.sleep(0.001)
+
+
+def test_a_lanes_launches_into_idle_are_those_with_nothing_ahead():
+    coord, facade, enc, watch = stamped_by_hand()
+    try:
+        facade.try_submit(b"0")
+        coord._tick()                        # nothing ahead: idle
+        facade.try_submit(b"1")
+        coord._tick()                        # step 0 unfinished
+        assert facade.stats() == {"launches": 2, "launches_into_idle": 1,
+                                  "ready_stamps_missed": 0}
+        enc.outs[0].gate.set()
+        enc.outs[1].gate.set()
+        wait_for(lambda: watch.readied == 2)
+        facade.try_submit(b"2")
+        coord._tick()                        # both have ended: idle again
+        assert facade.stats()["launches_into_idle"] == 2
+        enc.outs[2].gate.set()
+        wait_for(lambda: watch.readied == 3)
+        enc.ready = True
+        coord._tick()
+        got = {seq: facade.pop_trace(seq) for seq, _s in facade.poll()}
+        assert sorted(got) == [0, 1, 2]
+        for iv in got.values():
+            lane_tiles(iv)
+        # step 1 was launched behind step 0: it waited for it
+        assert got[1]["device_wait"][1] == max(
+            got[1]["dispatch"][1], got[0]["device_run"][1])
+        assert got[0]["device_wait"][1] == got[0]["dispatch"][1]
+        # the results lay on the chip until the tick that harvested them
+        assert got[0]["ready_wait"][1] - got[0]["ready_wait"][0] > 0.0
+        assert coord.stats()["ready_stamps_missed"] == 0
+    finally:
+        watch.stop()
+    wait_for(lambda: not watch.alive)
+
+
+def test_a_lane_frame_harvested_before_its_stamp_has_none_and_is_counted():
+    coord, facade, enc, watch = stamped_by_hand()
+    try:
+        facade.try_submit(b"0")
+        enc.ready = True
+        coord._tick()                        # harvested in its own tick
+        (seq, _s), = facade.poll()
+        iv = facade.pop_trace(seq)
+        assert set(iv) == set(TILING) | {"lane_step"}
+        assert facade.stats() == {"launches": 1, "launches_into_idle": 1,
+                                  "ready_stamps_missed": 1}
+        enc.outs[0].gate.set()
+    finally:
+        watch.stop()
+
+
+def test_a_lane_step_output_that_raises_stops_the_watch_not_the_lane(caplog):
+    import logging
+
+    coord, facade, enc, watch = stamped_by_hand()
+    enc.ready = True
+    with caplog.at_level(logging.WARNING,
+                         "selkies_tpu.observability.device_probe"):
+        got = []
+        for n in range(4):
+            facade.try_submit(b"%d" % n)
+            coord._tick()
+            if n == 0:
+                enc.outs[0].raises = RuntimeError("deleted buffer")
+                enc.outs[0].gate.set()
+                watch.join(2.0)
+            got += facade.poll()
+    assert [seq for seq, _s in got] == [0, 1, 2, 3]
+    assert watch.stopped and not watch.alive
+    assert len([r for r in caplog.records
+                if "mesh-ready stopped" in r.getMessage()]) == 1
+    assert coord.tick_errors_total == 0
+    assert facade.stats()["launches"] == 1
+
+
+def test_stop_leaves_no_mesh_ready_thread_and_no_step_output():
+    import gc
+    import weakref
+
+    coord = MeshEncodeCoordinator(
+        "session:1", 1, W, H, slots_per_lane=1, max_lanes=1, max_inflight=3,
+        enc_factory=StampedEncoder, framerate=200.0)
+    facade = coord.acquire(W, H)
+    enc = coord.lanes[0].enc
+    for n in range(3):
+        facade.try_submit(b"%d" % n)
+        wait_for(lambda: len(enc.outs) == n + 1)
+    watch = coord._ready_watch
+    assert watch.alive and watch.name == READY_THREAD
+    assert READY_THREAD in {t.name for t in threading.enumerate()}
+    refs = [weakref.ref(o) for o in enc.outs]
+    first = enc.outs[0]
+    facade.close()
+    coord.stop()                             # three steps unready
+    first.gate.set()                         # the watch was blocked for one
+    del first, enc.outs[:]
+    # (the module's served lane may hold a thread of that name of its own)
+    wait_for(lambda: not watch.alive)
+    coord.lanes[0].inflight_q.clear()        # (the pendings held them too)
+    wait_for(lambda: gc.collect() >= 0 and all(r() is None for r in refs))

@@ -39,17 +39,16 @@ def test_metrics_render():
     m.set_fps(60.0)
     m.set_latency(12.5)
     m.set_device_queue_delay(0, 45.0)
-    m.observe_encode(8.0, 50_000)
     m.set_clients(3)
     m.set_backpressured(1)
-    m.set_webrtc_stats({"bitrate": "8000000"})
     text = m.render().decode()
     assert "fps 60.0" in text
     assert "latency 12.5" in text
     assert 'device_queue_delay_ms{device="0"} 45.0' in text
     assert "connected_clients 3.0" in text
-    assert 'webrtc_statistics_info{bitrate="8000000"}' in text
-    assert "tpuenc_encode_ms_bucket" in text
+    # the two exporters nothing in the served program fed are gone
+    assert "webrtc_statistics" not in text
+    assert "tpuenc_encode_ms" not in text and "tpuenc_frame_bytes" not in text
 
 
 def test_metrics_d2h_and_host_entropy_gauges():
@@ -486,8 +485,10 @@ def test_http_endpoint_healthz_trace_and_nonfatal_bind():
 
 def test_stage_names_stable():
     """The stage glossary is a wire/bench/docs contract: the eight work
-    stages, the four waits between them (in path order), and a mesh
-    lane's ``lane_step``, which lies across its frame's others."""
+    stages, the four waits between them (in path order), the ready
+    watch's three inside ``in_device`` + ``fetch_wait``, and a mesh lane's
+    ``lane_step``, which lies across its frame's others."""
     assert STAGES == ("capture", "submit_wait", "pipe_wait", "stage",
-                      "dispatch", "in_device", "fetch_wait", "pack",
-                      "lane_step", "harvest_wait", "queue", "send", "ack")
+                      "dispatch", "in_device", "device_wait", "device_run",
+                      "ready_wait", "fetch_wait", "pack", "lane_step",
+                      "harvest_wait", "queue", "send", "ack")

@@ -441,6 +441,69 @@ def test_driver_streams_real_jpeg_and_reports_gauges():
         drv.close()
 
 
+def _h264_driver(**kw):
+    from selkies_tpu.encoder.h264 import H264StripeEncoder
+    from selkies_tpu.encoder.pipeline import PipelinedH264Encoder
+
+    pipe = PipelinedH264Encoder(
+        H264StripeEncoder(128, 96, stripe_height=32, qp=26), depth=3)
+    return AsyncEncodeDriver(pipe, **kw), pipe
+
+
+@pytest.mark.parametrize("codec", ["jpeg", "h264"])
+def test_through_the_driver_the_ready_stages_tile_in_device_and_fetch_wait(
+        codec):
+    """ISSUE 42, on the real pipes behind the driver thread: every frame
+    whose stamp had landed at its harvest carries ``device_wait``,
+    ``device_run`` and ``ready_wait``, each >= 0, adding up to
+    ``in_device`` + ``fetch_wait`` (``fetch_wait`` end less ``dispatch``
+    end) to the clock reading; ``in_device`` is what it was; ``stats()``
+    counts the launches, and closing the driver ends the watch's thread."""
+    drv, pipe = (_jpeg_driver if codec == "jpeg" else _h264_driver)()
+    size = (128, 160) if codec == "jpeg" else (96, 128)
+    ready = ("device_wait", "device_run", "ready_wait")
+    try:
+        sent, traces = [], []
+        deadline = time.monotonic() + 120.0
+        while len(sent) < 8 and time.monotonic() < deadline:
+            seq = drv.try_submit(_frame(*size, seed=len(sent)))
+            if seq is not None:
+                sent.append(seq)
+            time.sleep(0.01)
+            traces += [drv.pop_trace(s) for s, _stripes in drv.poll()]
+        traces += [drv.pop_trace(s) for s, _stripes in drv.flush()]
+        assert len(traces) == len(sent) == 8 and None not in traces
+        split = [tr for tr in traces if "device_run" in tr]
+        st = drv.stats()
+        assert st["launches"] == 8
+        assert 1 <= st["launches_into_idle"] <= 8
+        assert len(split) == 8 - st["ready_stamps_missed"] >= 6
+        for tr in traces:
+            d, f = tr["dispatch"], tr["fetch_wait"]
+            assert tr["in_device"] == (d[1], max(d[1], f[0]))
+        worst = 0.0
+        for tr in split:
+            d, f = tr["dispatch"], tr["fetch_wait"]
+            w, r, q = (tr[s] for s in ready)
+            assert d[1] == w[0] <= w[1] == r[0] <= r[1] == q[0] <= q[1] \
+                == f[1]
+            parts = sum(tr[s][1] - tr[s][0] for s in ready)
+            both = sum(tr[s][1] - tr[s][0]
+                       for s in ("in_device", "fetch_wait"))
+            worst = max(worst, abs(parts - both))
+        assert worst < 1e-9
+        assert any(t.name == "tpuenc-ready" for t in threading.enumerate())
+    finally:
+        drv.close()
+    drv._thread.join(timeout=30.0)
+    deadline = time.monotonic() + 5.0
+    while any(t.name == "tpuenc-ready" for t in threading.enumerate()) \
+            and time.monotonic() < deadline:
+        time.sleep(0.01)
+    # (another test's pipe may hold one of its own: none is this pipe's)
+    assert not pipe._ready_watch.alive
+
+
 def test_restart_midflight_releases_ring_and_recovers():
     """Supervisor-style restart: close() with work in flight must return
     promptly, leave no busy staging slot behind, and a rebuilt driver
