@@ -21,8 +21,12 @@ idles waiting on the host:
 * host frames double-buffer through the donated staging ring
   (:class:`.h264_device.StagingRing`), so H2D upload overlaps the
   previous frame's compute and donation never serializes dispatches;
-* a bounded submit queue gives backpressure (frames drop at the edge,
-  counted, instead of stalling every display on the loop);
+* between the two stands a mailbox of ONE capture, latest wins: a
+  capture that finds an older one still waiting for a slot of the pipe
+  takes its place (under its seq; the older one is the frame lost,
+  counted), so the chip's next frame is the newest picture and never
+  the oldest of a backlog — the contract a mesh lane's facade speaks
+  (``MeshSessionFacade.try_submit``), and the event loop never stalls;
 * ``flush()`` drains deterministically; ``close()`` mid-flight neither
   deadlocks nor leaks a staging slot, so PR 2 supervisor restarts and
   PR 3 evictions stay safe.
@@ -64,11 +68,9 @@ class AsyncEncodeDriver:
     #: cheap; the short beat keeps both submit and harvest latency low)
     POLL_INTERVAL_S = 0.002
 
-    def __init__(self, pipe, *, submit_depth: Optional[int] = None,
-                 wire_fullframe: bool = False,
+    def __init__(self, pipe, *, wire_fullframe: bool = False,
                  metrics=None, faults=None) -> None:
         self.pipe = pipe
-        self.submit_depth = int(submit_depth or max(4, pipe.depth))
         self.wire_fullframe = bool(wire_fullframe)
         self._metrics = metrics
         pipe.metrics = metrics
@@ -85,8 +87,12 @@ class AsyncEncodeDriver:
         self.on_error: Optional[Callable[[BaseException], None]] = None
 
         self._cond = threading.Condition()
-        self._in_q: deque = deque()          # (driver_seq, frame, t_accepted)
+        #: the mailbox: the one capture waiting for a slot of the pipe,
+        #: (driver_seq, frame, t_accepted), or empty
+        self._in_q: deque = deque()
         self._out: deque = deque()           # (driver_seq, stripes)
+        #: see :meth:`try_submit`
+        self.replaced_seq: Optional[int] = None
         #: driver seq -> (accepted into _in_q, taken out): the two ends of
         #: ``submit_wait``, and where ``pipe_wait`` begins
         self._waits: dict = {}
@@ -104,6 +110,9 @@ class AsyncEncodeDriver:
         self._flush_ack = 0
         self._stop = False
         self.frames_dropped_total = 0
+        #: captures that took a waiting capture's place (each lost that
+        #: older one: a subset of ``frames_dropped_total``)
+        self.frames_replaced_total = 0
         self.encode_errors_total = 0
         self._error_streak = 0
         #: pipe.stats() snapshot maintained by the driver thread — the
@@ -147,17 +156,28 @@ class AsyncEncodeDriver:
             rec.thread_state(self._thread.name, state, t0, t1)
 
     def try_submit(self, frame) -> Optional[int]:
-        """Queue one frame for the driver thread; None = dropped (queue
-        full — the pipeline is not keeping up, backpressure at the edge
-        instead of a stalled event loop)."""
+        """Hand the driver thread its next capture; never refuses one
+        while the driver runs. The seq the frame will harvest under; None
+        when it took the place of a capture still waiting for a slot of
+        the pipe (the mailbox holds one): the OLDER capture is the one
+        lost, and ``replaced_seq`` then says under which seq this one
+        will harvest, so that the capture loop can hand the lost frame's
+        place in its records to this one. ``replaced_seq`` is None after
+        every call that replaced nothing."""
         t_accepted = time.monotonic()
         with self._cond:
+            self.replaced_seq = None
             if self._stop:
                 return None
-            if len(self._in_q) >= self.submit_depth:
+            if self._in_q:
+                # the survivor waits from its OWN acceptance on
+                seq = self._in_q[0][0]
+                self._in_q[0] = (seq, frame, t_accepted)
                 self.frames_dropped_total += 1
+                self.frames_replaced_total += 1
                 if self._metrics is not None:
                     self._metrics.inc_frames_dropped()
+                self.replaced_seq = seq
                 return None
             seq = self._seq
             self._seq += 1
@@ -167,7 +187,7 @@ class AsyncEncodeDriver:
 
     def submit(self, frame) -> Optional[int]:
         """Alias of :meth:`try_submit` — this facade NEVER blocks the
-        caller; a full queue drops (the capture loop's contract)."""
+        caller (the capture loop's contract)."""
         return self.try_submit(frame)
 
     def poll(self) -> List[Tuple[int, list]]:
@@ -179,8 +199,9 @@ class AsyncEncodeDriver:
         return out
 
     def flush(self, timeout: float = 60.0) -> List[Tuple[int, list]]:
-        """Drain everything submitted so far (deterministic: on return,
-        every accepted frame has been harvested or accounted as an
+        """Drain everything submitted so far (deterministic: on return
+        the mailbox is empty and every capture accepted has been
+        harvested, replaced by a later one that was, or accounted as an
         error). Blocks the caller — warm-up/teardown paths only."""
         with self._cond:
             if not self._thread.is_alive():
@@ -198,17 +219,18 @@ class AsyncEncodeDriver:
         return out
 
     def close(self) -> None:
-        """Stop the driver and abandon queued frames (display teardown,
-        supervised restart). NEVER blocks the caller: teardown runs on
-        the event loop, where a join would stall every display sharing
-        it. All cleanup (pipe.close + ring release) happens on the
-        driver thread as it exits — releasing the rings from HERE would
-        race the thread's current dispatch and defeat the
-        use-after-donate guard. A thread wedged in a dead device fetch
-        is abandoned with its (equally abandoned) pipe — the bounded
-        exposure ThreadedEncoderAdapter also documents, policed by the
-        server's wedge_faults cap; the supervised restart builds a
-        fresh pipeline with fresh rings either way."""
+        """Stop the driver and abandon its frames, the waiting capture and
+        those in flight (display teardown, supervised restart). NEVER
+        blocks the caller: teardown runs on the event loop, where a join
+        would stall every display sharing it. All cleanup (pipe.close +
+        ring release) happens on the driver thread as it exits —
+        releasing the rings from HERE would race the thread's current
+        dispatch and defeat the use-after-donate guard. A thread wedged
+        in a dead device fetch is abandoned with its (equally abandoned)
+        pipe — the bounded exposure ThreadedEncoderAdapter also
+        documents, policed by the server's wedge_faults cap; the
+        supervised restart builds a fresh pipeline with fresh rings
+        either way."""
         with self._cond:
             self._stop = True
             self._in_q.clear()
@@ -244,8 +266,10 @@ class AsyncEncodeDriver:
         pipe directly from here would iterate deques the driver thread
         mutates concurrently."""
         with self._cond:
-            st = dict(self._stats_cache)
-            st["submit_queue_depth"] = len(self._in_q)
+            # (first: a reader that prints the head of the line shows it)
+            st = {"frames_replaced": self.frames_replaced_total,
+                  **self._stats_cache}
+            st["submit_queue_depth"] = len(self._in_q)     # 0 or 1
         st["frames_dropped"] = (st.get("frames_dropped", 0)
                                 + self.frames_dropped_total)
         st["encode_errors"] = self.encode_errors_total
@@ -281,15 +305,15 @@ class AsyncEncodeDriver:
                     if tr:
                         if waits is not None:
                             # the frame's two waits on this side of the
-                            # pipe: in _in_q, then behind the pass's work
-                            # and a full pipe until its staging began
+                            # pipe: in the mailbox, then behind the pass's
+                            # work until its staging began
                             first = tr.get("stage") or tr.get("dispatch")
                             tr["submit_wait"] = waits
                             if first is not None:
                                 tr["pipe_wait"] = (
                                     waits[1], max(waits[1], first[0]))
                         self._trace_out[seq] = tr
-                        while len(self._trace_out) > 4 * self.submit_depth:
+                        while len(self._trace_out) > 4 * self.pipe.depth:
                             self._trace_out.pop(
                                 next(iter(self._trace_out)))
                 self._out.append((seq, stripes))
@@ -306,7 +330,7 @@ class AsyncEncodeDriver:
     def _harvest(self, flush_partial: bool, wait: bool = False) -> bool:
         """One harvest pass; True if anything completed. ``wait`` blocks
         until the pipe's oldest frame is in (the pipe marks that
-        ``fetch_wait``): only ever asked with captures queued behind a
+        ``fetch_wait``): only ever asked with a capture waiting behind a
         full pipe, when nothing else is left for this thread to do."""
         if self.faults is not None:
             self.faults.maybe_hang_sync(FETCH_HANG_POINT)
@@ -325,10 +349,10 @@ class AsyncEncodeDriver:
             self._cleanup()
 
     def _feed(self) -> None:
-        """Dispatch queued captures, oldest first, one for each free slot
-        of the pipe. A capture leaves ``_in_q`` only when the pipe has
-        room for it: what cannot be staged yet waits where ``try_submit``
-        counts it (and refuses the next), not in a list of this thread's
+        """Dispatch the waiting capture into a free slot of the pipe. A
+        capture leaves the mailbox only when the pipe has room for it:
+        what cannot be staged yet waits where ``try_submit`` sees it (and
+        puts a newer one in its place), not in a list of this thread's
         behind a submit that blocks. An erroring frame costs ITSELF
         (counted + reported), never the rest of the pass; a frame the
         pipe never accepted gets no seq mapping, so its loss cannot shift
@@ -358,17 +382,17 @@ class AsyncEncodeDriver:
                 return False
             flush_want = self._flush_req
         flushing = flush_want > self._flush_ack
-        # 1. feed the device first: every free slot of the pipe takes
-        # the oldest queued capture
+        # 1. feed the device first: a free slot of the pipe takes the
+        # waiting capture
         self._feed()
         try:
-            # 2. harvest whatever is ready. With captures still queued
+            # 2. harvest whatever is ready. With a capture still waiting
             # the pipe is full: block for its oldest frame, whose slot
             # the next pass hands on; its successors keep the device busy
             # while this thread packs it.
             with self._cond:
                 backlog = bool(self._in_q)
-            # (with the queue dry, JPEG's partly filled fetch group ships)
+            # (with the mailbox dry, JPEG's partly filled fetch group ships)
             self._harvest(
                 flush_partial=not backlog,
                 wait=backlog and not self.pipe.has_room)

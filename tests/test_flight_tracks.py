@@ -1,7 +1,9 @@
 """The flight recorder's other rings and what writes them (ISSUE 25):
 
 * a frame's flight through ``AsyncEncodeDriver`` has marks with no hole,
-  with room in the pipe and with the pipe full;
+  with room in the pipe and with the pipe full; behind a full pipe the
+  driver's mailbox keeps the newest capture, and the span that rides
+  with a delivered frame is the one its own capture opened (ISSUE 43);
 * the driver thread's track never overlaps itself and covers the loop;
 * the device probe writes clock pairs, from a thread of its own;
 * the stall watch tells a blocked loop from a kept interpreter lock;
@@ -41,13 +43,14 @@ def anyio_backend():
 class FakePipe(_PipelineTelemetry):
     """A pipelined encoder with a pretend device: one step at a time,
     ``step_s`` each; ``submit`` blocks draining the oldest when ``depth``
-    frames are in flight, as the real pipes do."""
+    frames are in flight, as the real pipes do. A stripe's payload says
+    which picture it was made from (the frame's first byte)."""
 
     def __init__(self, depth=4, step_s=0.004, stage_s=0.001):
         self.depth, self.step_s, self.stage_s = depth, step_s, stage_s
         self.metrics = None
         self.d2h_bytes_total = 0
-        self._inflight = deque()        # [seq, trace, ready_at]
+        self._inflight = deque()        # [seq, trace, ready_at, level]
         self._ready = []
         self._seq = 0
         self._device_free_at = 0.0
@@ -77,11 +80,12 @@ class FakePipe(_PipelineTelemetry):
         t2 = time.monotonic()
         self._mark(trace, "dispatch", t1, t2)
         seq, self._seq = self._seq, self._seq + 1
-        self._inflight.append([seq, trace, self._device_free_at])
+        self._inflight.append([seq, trace, self._device_free_at,
+                               int(np.asarray(frame).flat[0])])
         return seq
 
     def _drain_one(self, block):
-        seq, trace, ready_at = self._inflight[0]
+        seq, trace, ready_at, level = self._inflight[0]
         t0 = time.monotonic()
         if t0 < ready_at:
             if not block:
@@ -94,7 +98,7 @@ class FakePipe(_PipelineTelemetry):
         self._mark(trace, "pack", t1, time.monotonic())
         self._trace_store(seq, trace)
         return seq, [StripeOutput(y_start=0, height=64,
-                                  jpeg=b"\xff\xd8F%d\xff\xd9" % seq,
+                                  jpeg=b"\xff\xd8L%d\xff\xd9" % level,
                                   is_paintover=False)]
 
     def poll(self, flush_partial=True, wait=False):
@@ -221,7 +225,7 @@ async def test_a_frames_marks_run_from_capture_to_send_without_a_hole(
 
     def factory(w, h, settings, overrides=None):
         pipes.append(FakePipe(depth=2 if full else 4, step_s=step_s))
-        return AsyncEncodeDriver(pipes[-1], submit_depth=2)
+        return AsyncEncodeDriver(pipes[-1])
 
     server = make_server(tmp_path, monkeypatch, factory, [])
     rec = server.recorder = FlightRecorder(capacity=4096)   # as a harness does
@@ -246,9 +250,11 @@ async def test_a_frames_marks_run_from_capture_to_send_without_a_hole(
     waits = sorted(t.duration_ms("submit_wait") + t.duration_ms("pipe_wait")
                    for t in clean)
     if full:
-        # frames queue: in _in_q, behind the pass's work, in a full pipe
-        assert waits[len(waits) // 2] > 20.0
-        assert rec.dropped_total > 0          # and captures are refused
+        # one capture waits for a slot, and a newer one takes its place:
+        # the survivor has waited a tick (33 ms) at most, where the
+        # oldest of a queue of two waited two steps (120 ms)
+        assert waits[len(waits) // 2] < 45.0
+        assert rec.dropped_total > 0          # the older captures are lost
     else:
         assert waits[len(waits) // 2] < 5.0
     assert rec.open_spans() == 0
@@ -257,7 +263,7 @@ async def test_a_frames_marks_run_from_capture_to_send_without_a_hole(
 def test_the_thread_track_never_overlaps_and_covers_a_busy_second():
     rec = FlightRecorder(capacity=4096)
     pipe = FakePipe(depth=2, step_s=0.012)
-    drv = AsyncEncodeDriver(pipe, submit_depth=2)
+    drv = AsyncEncodeDriver(pipe)
     drv.recorder = rec          # the way the server hands it over
     try:
         t0 = time.monotonic()
@@ -464,3 +470,70 @@ async def test_through_a_lane_the_capture_mark_is_the_frames_own(
     # its own span at submit, none rode on with another frame's picture
     lost = [t for t in rec._completed() if t.terminal == "dropped@submit"]
     assert lost, "no pending frame was replaced: the test did not bite"
+
+
+#: the driver's stages of a frame, in the order they tile acceptance to
+#: ``pack``'s end
+DRIVER_STAGES = tuple(
+    s for s in STAGES[STAGES.index("submit_wait"):STAGES.index("pack") + 1]
+    if s not in OFF_PATH)
+
+
+@pytest.mark.anyio
+async def test_through_the_solo_driver_the_capture_mark_is_the_frames_own(
+        tmp_path, monkeypatch):
+    """The solo twin of the lane test above (ISSUE 43): a real
+    ``AsyncEncodeDriver`` over a pipe that is held full (a step of 50 ms
+    against a tick of 8.3), through ``_capture_loop``. The driver's
+    mailbox keeps the newest capture under the waiting one's seq: every
+    capture it lost closes ``dropped@submit``, and the span that rides
+    with a delivered frame is the one its own capture opened."""
+    log, drivers = [], []
+
+    def factory(w, h, settings, overrides=None):
+        drivers.append(AsyncEncodeDriver(FakePipe(depth=2, step_s=0.05)))
+        return drivers[-1]
+
+    server = make_server(tmp_path, monkeypatch, factory, log)
+    rec = server.recorder = FlightRecorder(capacity=8192)
+    frames = await serve_frames(server, 2.0, fps=120)
+    assert len(drivers) == 1 and len(frames) >= 20
+    drv = drivers[0]
+    done = {}
+    for tr in rec._completed():
+        if tr.terminal == "acked":
+            done.setdefault(tr.frame_id, []).append(tr)
+    calls_at = [a for _n, a, _b in log]
+    checked = 0
+    for fid, payloads in frames.items():
+        if fid not in done:
+            continue                         # (cut off by the stop)
+        assert len(done[fid]) == 1, fid
+        tr = done[fid][0]
+        shown = int(payloads[0][3:-2]) // 8
+        cap = tr.spans["capture"]
+        calls = [i for i, (_n, a, b) in enumerate(log)
+                 if cap[0] <= a and b <= cap[1]]
+        assert len(calls) == 1, (fid, calls)
+        assert log[calls[0]][0] % 32 == shown, (fid, log[calls[0]], shown)
+        # submit_wait begins at the survivor's own acceptance: after its
+        # own capture, before the source was asked again
+        t_acc = tr.spans["submit_wait"][0]
+        later = calls_at[calls[0] + 1] if calls[0] + 1 < len(log) else t_acc
+        assert cap[1] <= t_acc <= later, (fid, cap, t_acc, later)
+        # and the seven stages tile acceptance to pack's end with no hole
+        for a, b in zip(DRIVER_STAGES, DRIVER_STAGES[1:]):
+            assert tr.spans[a][1] == tr.spans[b][0], (fid, a, b)
+        assert all(tr.spans[s][0] <= tr.spans[s][1] for s in DRIVER_STAGES)
+        checked += 1
+    assert checked >= 20
+    # the pipe stood full: a capture waited 8.3 ms at most where a queue's
+    # oldest waited steps, and most captures were lost to a newer one
+    waits = sorted(t[0].duration_ms("submit_wait") for t in done.values())
+    assert waits[len(waits) // 2] < 25.0
+    lost = [t for t in rec._completed() if t.terminal == "dropped@submit"]
+    assert drv.frames_replaced_total > len(frames)
+    assert len(lost) == drv.frames_replaced_total == drv.frames_dropped_total
+    # a lost capture's span never rode on: it ends where it was refused
+    assert all("submit_wait" not in t.spans for t in lost)
+    assert rec.open_spans() == 0

@@ -3,8 +3,9 @@
 Covers the tentpole's safety obligations, not its throughput claims
 (benchmark/run.py measures those on the real chip):
 
-* in-flight depth is bounded — a full pipeline backpressures at the
-  submit edge instead of growing without limit;
+* in-flight depth is bounded — behind a full pipeline one capture
+  waits, and a newer one takes its place (ISSUE 43: a mailbox of one,
+  latest wins, the lane facade's contract) instead of a queue growing;
 * ``flush()`` drains deterministically, including when the drain
   errors mid-way;
 * donated staging buffers are never read (or re-donated) after
@@ -173,7 +174,7 @@ class _StubPipe:
             raise RuntimeError("injected submit failure")
         seq = self._seq
         self._seq += 1
-        self._inflight.append(seq)
+        self._inflight.append((seq, frame))
         return seq
 
     def release(self, n):
@@ -184,7 +185,8 @@ class _StubPipe:
         while not self.gate.is_set() and not self.arrive.acquire(
                 timeout=0.005):
             pass
-        return (self._inflight.popleft(), ["stripe"])
+        seq, frame = self._inflight.popleft()
+        return (seq, [frame])           # the "stripes" say which capture
 
     def poll(self, flush_partial=True, wait=False):
         out, self._ready = self._ready, []
@@ -208,24 +210,58 @@ class _StubPipe:
         self._inflight.clear()
 
 
-def test_driver_bounds_inflight_and_backpressures():
+def _wait_until(cond, timeout=5.0):
+    t_end = time.monotonic() + timeout
+    while not cond() and time.monotonic() < t_end:
+        time.sleep(0.002)
+    return cond()
+
+
+def _submit_taken(drv, frame):
+    """Submit, and wait until the driver thread took the capture out of
+    the mailbox (the pipe had room): what a source slower than the step
+    looks like."""
+    seq = drv.try_submit(frame)
+    assert seq is not None and drv.replaced_seq is None
+    assert _wait_until(lambda: not drv._in_q)
+    return seq
+
+
+class _CountingMetrics:
+    dropped = 0
+
+    def inc_frames_dropped(self):
+        self.dropped += 1
+
+
+def test_driver_bounds_inflight_and_keeps_only_the_newest_capture():
     pipe = _StubPipe(depth=3)
     pipe.gate.clear()                       # nothing ever completes
-    drv = AsyncEncodeDriver(pipe, submit_depth=4)
+    metrics = _CountingMetrics()
+    drv = AsyncEncodeDriver(pipe, metrics=metrics)
     try:
-        accepted = dropped = 0
+        accepted = replaced = 0
         for i in range(50):
             if drv.try_submit(i) is not None:
                 accepted += 1
+                assert drv.replaced_seq is None
             else:
-                dropped += 1
+                replaced += 1
+                # never a refusal: the newcomer rides on, under the seq
+                # of the capture it found waiting
+                assert drv.replaced_seq == accepted - 1
             time.sleep(0.005)
-        # the pipe holds at most depth; the queue at most submit_depth;
-        # +1 for the frame the driver thread may hold between the two
+        # the pipe holds at most depth, the mailbox one: nothing grows
         assert pipe.n_inflight <= pipe.depth
-        assert accepted <= pipe.depth + 4 + 1
-        assert dropped > 0
-        assert drv.frames_dropped_total == dropped
+        assert accepted <= pipe.depth + 1
+        assert drv.n_inflight == pipe.depth + 1
+        assert replaced == 50 - accepted > 0
+        assert drv.frames_dropped_total == replaced == metrics.dropped
+        st = drv.stats()
+        assert st["frames_replaced"] == st["frames_dropped"] == replaced
+        assert st["submit_queue_depth"] == 1
+        with drv._cond:
+            assert [f for _s, f, _t in drv._in_q] == [49]   # the newest
     finally:
         pipe.gate.set()
         drv.close()                          # non-blocking teardown
@@ -234,15 +270,30 @@ def test_driver_bounds_inflight_and_backpressures():
     assert pipe.closed                       # thread-side cleanup ran
 
 
-def test_driver_flush_drains_deterministically_in_order():
+@pytest.mark.parametrize("paced", [True, False], ids=["paced", "burst"])
+def test_driver_flush_drains_deterministically_in_order(paced):
+    """Paced (each capture taken before the next comes) a mailbox and a
+    queue are the same thing: every capture comes out. In a burst the
+    survivors come out, in submission order, each under the seq its
+    acceptance or its predecessor's gave it, the last capture among
+    them; ``flush()`` returns with the mailbox empty."""
     pipe = _StubPipe(depth=4)
-    drv = AsyncEncodeDriver(pipe, submit_depth=16)
+    drv = AsyncEncodeDriver(pipe)
     try:
-        seqs = [drv.try_submit(i) for i in range(9)]
-        assert all(s is not None for s in seqs)
+        if paced:
+            seqs = [_submit_taken(drv, i) for i in range(9)]
+            assert seqs == list(range(9))
+        else:
+            seqs = [s for s in (drv.try_submit(i) for i in range(9))
+                    if s is not None]
         out = drv.flush()
-        got = [s for s, _ in out]
-        assert got == seqs                   # everything, in order
+        assert not drv._in_q
+        assert [s for s, _ in out] == seqs   # every seq given, in order
+        frames = [stripes[0] for _s, stripes in out]
+        assert frames == sorted(frames) and frames[-1] == 8
+        if paced:
+            assert frames == list(range(9))
+        assert len(out) + drv.frames_replaced_total == 9
         assert drv.flush() == []             # drained means drained
     finally:
         drv.close()
@@ -250,83 +301,170 @@ def test_driver_flush_drains_deterministically_in_order():
 
 def test_driver_flush_survives_submit_errors():
     pipe = _StubPipe(depth=4, fail_on={2})
-    drv = AsyncEncodeDriver(pipe, submit_depth=16)
+    drv = AsyncEncodeDriver(pipe)
     errors = []
     drv.on_error = errors.append
     try:
         for i in range(5):
-            assert drv.try_submit(i) is not None
+            _submit_taken(drv, i)
         out = drv.flush()
         # frame 2 died; the other four complete with the RIGHT seqs
         assert len(out) == 4
         assert [s for s, _ in out] == [0, 1, 3, 4]
+        assert [stripes for _s, stripes in out] == [[0], [1], [3], [4]]
         assert drv.encode_errors_total >= 1
         assert errors and isinstance(errors[0], RuntimeError)
     finally:
         drv.close()
 
 
-def _wait_until(cond, timeout=5.0):
-    t_end = time.monotonic() + timeout
-    while not cond() and time.monotonic() < t_end:
-        time.sleep(0.002)
-    return cond()
-
-
-def test_no_capture_leaves_the_queue_while_the_pipe_is_full():
-    """A pipe whose oldest frame is slow to arrive: the driver fills the
-    pipe's free slots and leaves every other capture in ``_in_q`` (where
-    ``try_submit`` counts it and refuses the next), never in a submit
-    that blocks; a slot freed by the oldest frame's arrival takes exactly
-    the oldest queued capture, and frames come out in submission order."""
+def test_behind_a_full_pipe_the_newest_capture_waits_under_the_oldest_seq():
+    """ISSUE 43. A pipe whose oldest frame is slow to arrive: the driver
+    fills the pipe's free slots; of the captures a, b, c that come then,
+    a waits in the mailbox, b takes its place and c takes b's, each under
+    a's seq and with its own acceptance time; none leaves the mailbox
+    while the pipe is full; the slot freed by the oldest frame's arrival
+    takes c, and frames come out in submission order."""
     pipe = _StubPipe(depth=3)
     pipe.gate.clear()                        # the oldest is not in yet
-    drv = AsyncEncodeDriver(pipe, submit_depth=4)
+    drv = AsyncEncodeDriver(pipe)
     try:
-        assert [drv.try_submit(i) for i in range(3)] == [0, 1, 2]
+        assert [_submit_taken(drv, f) for f in "xyz"] == [0, 1, 2]
         assert _wait_until(lambda: pipe.n_inflight == 3)
-        assert [drv.try_submit(i) for i in range(3, 7)] == [3, 4, 5, 6]
+        t0 = time.monotonic()
+        assert drv.try_submit("a") == 3 and drv.replaced_seq is None
+        time.sleep(0.02)
+        assert drv.try_submit("b") is None and drv.replaced_seq == 3
+        time.sleep(0.02)
+        t_c = time.monotonic()
+        assert drv.try_submit("c") is None and drv.replaced_seq == 3
         time.sleep(0.05)                     # the driver waits, takes none
         assert pipe.n_inflight == 3 and pipe.submitted_full == 0
         with drv._cond:
-            assert [s for s, _f, _t in drv._in_q] == [3, 4, 5, 6]
+            (seq, frame, t_accepted), = drv._in_q
+            assert (seq, frame) == (3, "c")
+            assert t0 < t_c <= t_accepted    # the survivor's own reading
             assert sorted(drv._waits) == [0, 1, 2]    # only those taken out
-        assert drv.try_submit(7) is None              # refused at the edge
-        assert drv.frames_dropped_total == 1
+        assert drv.frames_dropped_total == drv.frames_replaced_total == 2
+        st = drv.stats()
+        assert (st["frames_dropped"], st["frames_replaced"]) == (2, 2)
+        assert st["submit_queue_depth"] == 1 and drv.n_inflight == 4
         assert drv.poll() == []
 
-        # the oldest arrives, and only it: one slot, one capture
+        # the oldest arrives, and only it: one slot, and c takes it
         pipe.release(1)
-        assert _wait_until(lambda: len(drv._in_q) == 3)
+        assert _wait_until(lambda: not drv._in_q)
         assert _wait_until(lambda: pipe.n_inflight == 3)
-        assert [s for s, _ in drv.poll()] == [0]
+        assert drv.poll() == [(0, ["x"])]
         with drv._cond:
-            assert [s for s, _f, _t in drv._in_q] == [4, 5, 6]
+            assert drv._waits[3][0] == t_accepted     # submit_wait is c's
         assert pipe.submitted_full == 0
+        # a plain acceptance again: a new seq, nothing replaced
+        assert drv.try_submit("d") == 4 and drv.replaced_seq is None
 
-        # flush() mid-flight: everything accepted, in submission order
+        # flush() mid-flight: every survivor, in submission order, and
+        # the mailbox empty
         pipe.gate.set()
-        assert [s for s, _ in drv.flush()] == [1, 2, 3, 4, 5, 6]
+        assert drv.flush() == [(1, ["y"]), (2, ["z"]), (3, ["c"]), (4, ["d"])]
+        assert not drv._in_q
         assert pipe.submitted_full == 0 and pipe.n_inflight == 0
+        assert drv.frames_dropped_total == 2
+    finally:
+        pipe.gate.set()
+        drv.close()
+    drv._thread.join(timeout=10.0)
+    assert pipe.closed and pipe.submitted_full == 0
 
-        # close() mid-flight, the thread blocked on a frame that is late:
-        # returns at once, queued captures are abandoned, nothing hangs
-        pipe.gate.clear()
+
+def test_close_midflight_with_a_capture_waiting_neither_blocks_nor_answers():
+    """close() with the thread blocked on a frame that is late and a
+    capture in the mailbox: returns at once, the waiting capture is
+    abandoned, a capture that comes after it is refused (None, and no
+    ``replaced_seq``: there is nothing it could ride under)."""
+    pipe = _StubPipe(depth=3)
+    pipe.gate.clear()
+    drv = AsyncEncodeDriver(pipe)
+    try:
         for i in range(3):
-            assert drv.try_submit(i) is not None
+            _submit_taken(drv, i)
         assert _wait_until(lambda: pipe.n_inflight == 3)
-        for i in range(4):
-            assert drv.try_submit(i) is not None
+        assert drv.try_submit(3) == 3
+        assert drv.try_submit(4) is None and drv.replaced_seq == 3
         t0 = time.monotonic()
         drv.close()
         assert time.monotonic() - t0 < 1.0
-        assert drv.try_submit(99) is None
+        assert not drv._in_q and drv.stats()["submit_queue_depth"] == 0
+        assert drv.try_submit(99) is None and drv.replaced_seq is None
+        assert drv.frames_replaced_total == 1         # a refusal is none
     finally:
         pipe.gate.set()
         drv.close()
     drv._thread.join(timeout=10.0)
     assert not drv._thread.is_alive()
     assert pipe.closed and pipe.submitted_full == 0
+
+
+def test_flush_with_a_capture_waiting_behind_a_full_pipe_takes_it_too():
+    """A flush asked for while a capture waits behind a full pipe drains
+    the pipe, the survivor after it, and acknowledges only then."""
+    pipe = _StubPipe(depth=2)
+    pipe.gate.clear()
+    drv = AsyncEncodeDriver(pipe)
+    try:
+        for i in range(2):
+            _submit_taken(drv, i)
+        assert _wait_until(lambda: pipe.n_inflight == 2)
+        assert drv.try_submit("old") == 2
+        assert drv.try_submit("new") is None and drv.replaced_seq == 2
+        got = []
+        t = threading.Thread(target=lambda: got.extend(drv.flush()))
+        t.start()
+        time.sleep(0.05)
+        assert t.is_alive() and drv._in_q    # nothing arrives: it waits
+        pipe.gate.set()
+        t.join(timeout=10.0)
+        assert not t.is_alive()
+        assert got == [(0, [0]), (1, [1]), (2, ["new"])]
+        assert not drv._in_q and pipe.n_inflight == 0
+    finally:
+        pipe.gate.set()
+        drv.close()
+
+
+def test_no_capture_is_lost_twice_or_delivered_twice_under_contention():
+    """The mailbox is shared by the capture loop's thread and the driver
+    thread. Hammered with the interpreter switching threads every 10 us:
+    every capture offered is either harvested or counted as replaced,
+    exactly once; the seqs given out are the seqs harvested, in order;
+    pictures come out in the order they went in."""
+    import sys
+
+    pipe = _StubPipe(depth=2)
+    drv = AsyncEncodeDriver(pipe)
+    offered, seqs, out = 20000, [], []
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for i in range(offered):
+            seq = drv.try_submit(i)
+            if seq is not None:
+                seqs.append(seq)
+            else:
+                assert drv.replaced_seq == seqs[-1]
+            if i % 64 == 0:
+                out += drv.poll()
+        out += drv.flush(timeout=30.0)
+    finally:
+        sys.setswitchinterval(interval)
+        drv.close()
+    drv._thread.join(timeout=10.0)
+    assert not drv._thread.is_alive()
+    assert [s for s, _ in out] == seqs == list(range(len(seqs)))
+    frames = [stripes[0] for _s, stripes in out]
+    assert frames == sorted(set(frames)) and frames[-1] == offered - 1
+    assert len(out) + drv.frames_replaced_total == offered
+    assert drv.frames_dropped_total == drv.frames_replaced_total
+    assert 0 < len(out) <= offered and pipe.submitted_full == 0
 
 
 # ---------------------------------------------------------------------------
@@ -356,10 +494,10 @@ def test_the_driver_reads_a_pipe_only_through_its_interface():
                 raise AssertionError(f"driver read pipe.{name}")
             raise AttributeError(name)
 
-    drv = AsyncEncodeDriver(_Sealed(depth=2), submit_depth=4)
+    drv = AsyncEncodeDriver(_Sealed(depth=2))
     try:
         for _ in range(4):
-            assert drv.try_submit(object()) is not None
+            _submit_taken(drv, object())
         got = []
         deadline = time.monotonic() + 10.0
         while len(got) < 4 and time.monotonic() < deadline:
@@ -421,14 +559,17 @@ def test_driver_streams_real_jpeg_and_reports_gauges():
     drv, pipe = _jpeg_driver()
     try:
         want = 6
-        sent = 0
+        sent = offered = 0
         deadline = time.monotonic() + 60.0
         while sent < want and time.monotonic() < deadline:
-            if drv.try_submit(_frame(seed=sent)) is not None:
+            # (a picture of its own for every capture: one that rides on
+            # in a waiting one's place must not repeat the frame before)
+            if drv.try_submit(_frame(seed=offered)) is not None:
                 sent += 1
+            offered += 1
             time.sleep(0.01)
         out = drv.flush()
-        assert len(out) == sent
+        assert len(out) == sent == offered - drv.frames_replaced_total
         assert all(stripes for _s, stripes in out)   # every frame emitted
         st = drv.stats()
         for key in ("inflight_batches", "inflight_batches_max",
