@@ -31,8 +31,14 @@ class Cell:
     per_layer: List[Dict[str, Any]]
 
     def limits(self) -> Dict[str, float]:
-        """What ``correct`` holds each compared number to."""
-        return dict(self.config.get("limits", {}))
+        """What ``correct`` holds each compared number to: the
+        configuration's limits, and over them those it states for this mix
+        (``limits_by_traffic``: a limit lies between the sound runs' and the
+        control's readings, and a mix on which both read lower than on the
+        others needs one of its own to tell them apart)."""
+        return {**self.config.get("limits", {}),
+                **self.config.get("limits_by_traffic", {}).get(
+                    self.traffic_name, {})}
 
 
 def kept_cells(bench_dir: str) -> Dict[str, Any]:
@@ -40,7 +46,8 @@ def kept_cells(bench_dir: str) -> Dict[str, Any]:
     file (their configuration, mix, band and limits) until a ``benchmark``
     PR brings them back: {"workloads": [...]}, each entry as
     ``BENCHMARK.json`` had it, with ``per_layer`` (the metrics that named
-    it), ``left`` and ``returns_when``. {} where there is none."""
+    it), ``left`` and ``returns_when`` (and ``readings``, where the cell was
+    measured and not admitted). {} where there is none."""
     path = os.path.join(bench_dir, "kept_cells.json")
     return _read(path) if os.path.exists(path) else {}
 

@@ -186,6 +186,10 @@ class Run:
             self.clients[did] = c
             await self._until(lambda c=c: c.frames_seen() >= 1, 1500.0,
                               f"{did}: first frame")
+        for s in self.sources:
+            # every client holds its first frame: a mix that reaches its
+            # steady state by its own traffic begins it here
+            s.joined()
         t_joined = time.monotonic()
         want = int(steady.get("frames", 20))
         base = {d: c.frames_seen() for d, c in self.clients.items()}
@@ -374,9 +378,12 @@ class Run:
             now_seen = sum(len(c.frames) for c in self.clients.values())
             if now_seen != seen:
                 seen, quiet = now_seen, time.monotonic()
-        # the last frame has no successor to show that it is complete
+        # the last frame has no successor to show that it is complete: the
+        # stream has run dry, so it is, and it counts where it arrived (in a
+        # stream of a few frames a second that can be inside the window)
         for c in self.clients.values():
             await c.ack_open_frame()
+            c.close_open_frame()
         await asyncio.sleep(0.2)
 
     def _shown_so_far(self) -> List[Optional[float]]:

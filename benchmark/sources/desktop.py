@@ -50,10 +50,14 @@ def draw_desktop(width: int, height: int, seed: int) -> np.ndarray:
     runs of one seed; a roll sideways as well still moved it by 7%, because
     the scroll ruler then covers other columns of the picture: my chip
     runs, PR 24.)"""
-    img = draw_picture(width, height, PICTURE_SEED)
+    return np.roll(draw_picture(width, height, PICTURE_SEED),
+                   seed_roll(height, seed), axis=0)
+
+
+def seed_roll(height: int, seed: int) -> int:
+    """How many rows (whole stripes of 64) ``seed`` rolls the picture down."""
     rng = np.random.default_rng([int(seed), 0x5EED])
-    dy = 64 * int(rng.integers(0, max(1, height // 64)))
-    return np.roll(img, dy, axis=0)
+    return 64 * int(rng.integers(0, max(1, height // 64)))
 
 
 def draw_picture(width: int, height: int, seed: int) -> np.ndarray:
@@ -199,20 +203,33 @@ class ClockedSource:
         return self.frame(index)
 
     # -- the harness's side ------------------------------------------------
+    def joined(self) -> None:
+        """Every client of the run holds its first frame (a first frame may
+        take a compile's time to come): traffic that is meant to run for a
+        stated time before the window begins here."""
+
     def anchor(self) -> None:
         """Pin where content steps fall inside the capture loop's tick, from
         the phase of the loop's own recent calls: a run's latency then does
         not carry a random share of one tick that another run lacks."""
+        want = self.wanted_phase()
+        if want is not None:
+            tick = 1.0 / self.fps
+            self.origin += (want - self.origin % tick) % tick
+
+    def wanted_phase(self) -> Optional[float]:
+        """Where in a capture tick (seconds into it) content steps have to
+        fall to lie ``phase_ticks`` of a tick before the capture loop's own
+        recent calls; None where the mix pins no phase or the loop has not
+        run yet."""
         if self.phase_ticks is None or len(self._calls) < 20:
-            return
+            return None
         tick = 1.0 / self.fps
         ph = np.array(self._calls[-60:]) % tick
         # circular mean of the call phase
         ang = np.angle(np.mean(np.exp(2j * np.pi * ph / tick)))
         call_phase = (ang / (2 * np.pi)) % 1.0 * tick
-        want = (call_phase - self.phase_ticks * tick) % tick
-        have = self.origin % tick
-        self.origin += (want - have) % tick
+        return (call_phase - self.phase_ticks * tick) % tick
 
     def tick_lateness_ms(self, t_from: float, t_to: float) -> List[float]:
         """How late each call in [t_from, t_to) ran against a tick grid laid

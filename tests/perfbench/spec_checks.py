@@ -35,6 +35,20 @@ APPENDED = ["driver_submit_wait_p50_ms", "driver_pipe_wait_p50_ms",
             "phase_motion_ms", "interpreter_stall_max_ms",
             "loop_stall_max_ms", "cavlc_low_tier_pct"]
 
+#: and after those, in the order they came: PR 29's, PR 33's, PR 42's five
+#: (which read the ready watch's stages and counts as data), PR 44's
+DATA_ONLY = ["driver_device_wait_p50_ms", "driver_device_run_p50_ms",
+             "driver_ready_wait_p50_ms", "driver_ready_wait_p95_ms",
+             "launch_idle_pct"]
+LATER = ["fetch_prefix_hit_pct", "cavlc_tier_fill_pct", *DATA_ONLY,
+         "ready_stamp_lag_p50_ms"]
+#: what only the H.264 step can fill: these entries list the H.264 cells
+H264_ALONE = ["me_kernel_ms", "me_kernel_roofline", "phase_motion_ms",
+              "cavlc_low_tier_pct", "fetch_prefix_hit_pct",
+              "cavlc_tier_fill_pct"]
+#: readers of the device probe's clock pairs: no device, nothing to read
+CLOCK_PROBE_READERS = {"clock_probe"}
+
 #: the five ways the idle split reads its thread's track (the ``args`` of the
 #: five ``idle_driver_*_pct`` files, less which threads): together they
 #: cover every idle second once
@@ -150,6 +164,23 @@ def cell_finds_its_files(workload, root):
     return cell
 
 
+def a_mixs_own_limits_only_tighten(spec, root):
+    """``limits_by_traffic`` of a configuration: every key is a mix on file,
+    every number under it is one of the configuration's ``limits`` (what the
+    reference computes), and its value is at or under the configuration's
+    own: a mix may need a tighter limit to tell its control from its sound
+    runs, and none is ever loosened by one."""
+    traffic = os.path.join(root, "benchmark", "traffic")
+    for c in spec["configs"]:
+        with open(os.path.join(root, c["file"])) as f:
+            config = json.load(f)
+        for mix, own in config.get("limits_by_traffic", {}).items():
+            assert os.path.exists(os.path.join(traffic, mix + ".json")), mix
+            assert own and set(own) <= set(config["limits"]), (mix, own)
+            for k, v in own.items():
+                assert 0 <= v <= config["limits"][k], (mix, k, v)
+
+
 def kept_cells_are_whole(spec, root):
     """A cell that left ``workloads`` and is kept on file
     (``benchmark/kept_cells.json``): it is in no list of ``BENCHMARK.json``
@@ -163,7 +194,10 @@ def kept_cells_are_whole(spec, root):
     extra = {"per_layer", "left", "returns_when"}
     for w in kept.get("workloads", []):
         assert w["name"] not in live and extra <= set(w)
-        workload_entry(spec, {k: v for k, v in w.items() if k not in extra})
+        # ``readings``: what the builder read of a cell that was measured
+        # and not admitted (optional)
+        workload_entry(spec, {k: v for k, v in w.items()
+                              if k not in extra | {"readings"}})
         assert w["returns_when"] and w["left"]
         assert set(w["per_layer"]) <= names
         for m in spec["end_to_end"] + spec["per_layer"]:
@@ -182,11 +216,14 @@ def per_layer_metric_has_a_reader(name, root):
 
 def accepted_entries_are_untouched(spec):
     """The twelve first entries as they were accepted and in their order,
-    every entry appended since after them and in the order it came. How
-    many follow is no business of this check: a later PR appends."""
+    every entry appended since after them and in the order it came (PR 42's
+    five among them: present, in their order, after every entry accepted
+    before them; a subsequence, not the tail). How many follow, and what
+    stands between, is no business of this check: a later PR appends."""
     names = [m["name"] for m in spec["per_layer"]]
     assert names[:len(ACCEPTED)] == ACCEPTED
-    assert [n for n in names if n in APPENDED] == APPENDED
+    came = APPENDED + LATER
+    assert [n for n in names if n in came] == came
     for name in APPENDED:
         appended_entry(spec, name)
 
@@ -199,6 +236,88 @@ def appended_entry(spec, name):
     assert m["layer"] in {x["layer"]
                           for x in spec["per_layer"][:len(ACCEPTED)]}
     return m
+
+
+def codec_of(spec, root, w):
+    """What the configuration of the cell entry ``w`` hands the server as
+    its encoder."""
+    conf = next(c for c in spec["configs"] if c["name"] == w["config"])
+    with open(os.path.join(root, conf["file"])) as f:
+        return json.load(f)["env"]["SELKIES_ENCODER"]
+
+
+def h264_cells(spec, root):
+    """The cells of ``workloads`` whose configuration says
+    ``x264enc-striped``, in their order."""
+    return [w["name"] for w in spec["workloads"]
+            if codec_of(spec, root, w) == "x264enc-striped"]
+
+
+def what_one_codec_alone_has_names_every_h264_cell(spec, root, name):
+    """A cell that reports ``delivered_fps`` reports what moves it: an entry
+    that only the H.264 step can fill lists the H.264 cells of
+    ``workloads``, all of them and no other (however many there are); an
+    H.264 cell kept on file says itself that the entry named it."""
+    m = next(x for x in spec["per_layer"] if x["name"] == name)
+    assert sorted(m["workloads"]) == sorted(h264_cells(spec, root))
+    assert len(set(m["workloads"])) == len(m["workloads"])
+    assert m["moves"] == "delivered_fps"
+    for w in cells.kept_cells(os.path.join(root, "benchmark")).get(
+            "workloads", []):
+        if codec_of(spec, root, w) == "x264enc-striped":
+            assert name in w["per_layer"], (w["name"], name)
+
+
+def no_device_in_a_rehearsal(spec, root):
+    """The per-layer entries that only a device's trace or its clock probe
+    can fill: a CPU rehearsal has neither, and its line leaves them out for
+    that stated reason. Derived from what each entry says of itself
+    (``source``) and from its reader, not a list of names."""
+    out = set()
+    for m in spec["per_layer"]:
+        reader = cells.layer_metric_spec(
+            m["name"], os.path.join(root, "benchmark"))["reader"]
+        if m["source"] == "device_trace" or reader in CLOCK_PROBE_READERS:
+            out.add(m["name"])
+    return out
+
+
+def take_a_cell_out_and_keep_it(spec, root, leaving):
+    """What a ``benchmark`` PR does to take the cell ``leaving`` out of
+    ``workloads`` and keep it on file, played on the checkout at ``root``
+    (a scratch one) for any number of cells: its configuration gets a
+    second cell under a mix it does not have yet (the contract lets no
+    configuration go empty), the entry moves to ``kept_cells.json`` with the
+    metrics that named it, which strike it from their lists, and no file of
+    the cell is deleted. Returns (the new spec, the entry that left)."""
+    spec = json.loads(json.dumps(spec))
+    bench = os.path.join(root, "benchmark")
+    gone = next(w for w in spec["workloads"] if w["name"] == leaving)
+    mix = "stays"
+    shutil.copy(os.path.join(bench, "traffic", gone["traffic"] + ".json"),
+                os.path.join(bench, "traffic", mix + ".json"))
+    stays = dict(gone, name=gone["config"] + ".stays", traffic=mix,
+                 why="a second cell of the configuration whose cell leaves")
+    spec["workloads"] = [w for w in spec["workloads"] if w is not gone] \
+        + [stays]
+    named = []
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if leaving in m.get("workloads", []):
+            if m in spec["per_layer"]:
+                named.append(m["name"])
+            m["workloads"].remove(leaving)
+            # the cell that takes its configuration's place is named by
+            # what one codec alone has, and by a list it would leave empty
+            if m["name"] in H264_ALONE or not m["workloads"]:
+                m["workloads"].append(stays["name"])
+    kept = cells.kept_cells(bench) or {"workloads": []}
+    kept["workloads"].append(dict(gone, per_layer=named, left="PR n",
+                                  returns_when="a stated reading"))
+    with open(os.path.join(bench, "kept_cells.json"), "w") as f:
+        json.dump(kept, f)
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(spec, f, indent=1)
+    return spec, gone
 
 
 def whole(spec, root):
@@ -216,4 +335,8 @@ def whole(spec, root):
         per_layer_metric_has_a_reader(m["name"], root)
     names_are_unique_and_setup_is_there(spec)
     accepted_entries_are_untouched(spec)
+    for name in H264_ALONE:
+        what_one_codec_alone_has_names_every_h264_cell(spec, root, name)
+    no_device_in_a_rehearsal(spec, root)
+    a_mixs_own_limits_only_tighten(spec, root)
     kept_cells_are_whole(spec, root)

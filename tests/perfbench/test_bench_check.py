@@ -173,22 +173,27 @@ def _rehearse(workload, seed=5, seconds=3.0, **kw):
     return asyncio.run(bench_run.run_cell(args, cell, device, (256, 144)))
 
 
-def test_a_run_whose_server_serves_a_stale_stripe_is_not_correct(monkeypatch):
-    """One row band's bytes are frozen where they are produced: the client
-    still decodes every stripe, frames still arrive on time, and the picture
-    no longer shows the desktop it claims to show."""
+def serve_a_stale_stripe(monkeypatch, y_start=64):
+    """Freeze one row band's bytes where stripes are packed."""
     from selkies_tpu.server.data_server import DataStreamingServer
 
     real = DataStreamingServer._pack_stripe
     frozen = {}
 
     def stale(frame_id, s, encoder):
-        if s.y_start == 64:
+        if s.y_start == y_start:
             s = frozen.setdefault("stripe", s)
         return real(frame_id, s, encoder)
 
     monkeypatch.setattr(DataStreamingServer, "_pack_stripe",
                         staticmethod(stale))
+
+
+def test_a_run_whose_server_serves_a_stale_stripe_is_not_correct(monkeypatch):
+    """One row band's bytes are frozen where they are produced: the client
+    still decodes every stripe, frames still arrive on time, and the picture
+    no longer shows the desktop it claims to show."""
+    serve_a_stale_stripe(monkeypatch)
     out = _rehearse("jpeg-1080p60.scroll")
     assert out["attempted"] > 100 and out["failed"] == 0
     assert out["compared"]["undecodable"]["value"] == 0

@@ -41,7 +41,8 @@ def a_run(rate, served, source_fps=None, displays=("primary",), trace=False):
     run.server = SimpleNamespace(display_clients={
         d: SimpleNamespace(bp=SimpleNamespace(framerate=served))
         for d in displays})
-    run.sources = [SimpleNamespace(number=k, fps=source_fps or served)
+    run.sources = [SimpleNamespace(number=k, fps=source_fps or served,
+                                   joined=lambda: None)
                    for k in range(len(displays))]
     return run
 

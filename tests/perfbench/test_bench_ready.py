@@ -2,9 +2,8 @@
 the ready watch's stages and counts as data (``recorder_stage``,
 ``encoder_share``), and a reader, ``ready_stamp``, that checks the stamps
 against a device trace. (The reader's own entry, ``ready_stamp_lag_p50_ms``,
-is not in ``BENCHMARK.json``: PERF.md section 7 says which two tests of this
-directory refuse it as they stand; its file and its reader are here for the
-``benchmark`` PR that lists it.)"""
+is listed for the H.264 cells since PR 44, which unpinned the two tests of
+this directory that refused every way of listing it.)"""
 
 import math
 import os
@@ -32,9 +31,23 @@ DATA_ONLY = list(STAGE_METRICS) + ["launch_idle_pct"]
 MS = 1e6
 
 
-def test_the_whole_spec_holds_with_the_five_entries_appended_last():
+def test_the_whole_spec_holds_with_the_five_entries_in_their_order():
+    """Present, in their order, after every entry accepted before them: a
+    subsequence of the list, not its tail (a later PR appends after them)."""
     spec_checks.whole(SPEC, ROOT)
-    assert [m["name"] for m in SPEC["per_layer"]][-5:] == DATA_ONLY
+    assert DATA_ONLY == spec_checks.DATA_ONLY
+    names = [m["name"] for m in SPEC["per_layer"]]
+    assert [n for n in names if n in DATA_ONLY] == DATA_ONLY
+    before = spec_checks.ACCEPTED + spec_checks.APPENDED + \
+        spec_checks.LATER[:spec_checks.LATER.index(DATA_ONLY[0])]
+    assert max(names.index(n) for n in before) < names.index(DATA_ONLY[0])
+    # and it fails where one of the five moves in front of an older entry
+    moved = [m for m in SPEC["per_layer"] if m["name"] != DATA_ONLY[-1]]
+    moved.insert(len(spec_checks.ACCEPTED), next(
+        m for m in SPEC["per_layer"] if m["name"] == DATA_ONLY[-1]))
+    with pytest.raises(AssertionError):
+        spec_checks.accepted_entries_are_untouched(
+            dict(SPEC, per_layer=moved))
 
 
 @pytest.mark.parametrize("name", DATA_ONLY)
@@ -60,12 +73,26 @@ def test_a_new_entry_names_no_cell_and_reads_data_only(name):
         assert name in {x["name"] for x in cells.load_cell(w).per_layer}
 
 
-def test_the_lag_metrics_file_is_there_for_the_pr_that_lists_it():
+def test_the_lag_metric_is_listed_for_the_h264_cells():
+    """Entered in PR 44 (reader and file on disk since PR 42): a device
+    reading, so a rehearsal leaves it out for the stated reason; listed for
+    the H.264 cells of ``workloads``, and the H.264 cell kept on file names
+    it itself."""
     body = spec_checks.per_layer_metric_has_a_reader(
         "ready_stamp_lag_p50_ms", ROOT)
     assert body == {"reader": "ready_stamp", "args": {"percentile": 50}}
-    assert "ready_stamp_lag_p50_ms" not in {
-        m["name"] for m in SPEC["per_layer"]}
+    m = next(x for x in SPEC["per_layer"]
+             if x["name"] == "ready_stamp_lag_p50_ms")
+    spec_checks.metric_entry(SPEC, m)
+    assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"]) == (
+        "ms", "lower", "device_trace", "device", "latency_p50_ms")
+    assert set(m["workloads"]) <= set(spec_checks.h264_cells(SPEC, ROOT))
+    assert "h264-1080p120.scroll" in m["workloads"]
+    assert m["name"] in spec_checks.no_device_in_a_rehearsal(SPEC, ROOT)
+    assert m["name"] in {x["name"] for x in
+                         cells.load_cell("h264-1080p60.scroll").per_layer}
+    assert m["name"] not in {x["name"] for x in
+                             cells.load_cell("jpeg-1080p60.scroll").per_layer}
 
 
 # -- the reader, on a hand-made traced run ---------------------------------
@@ -209,5 +236,5 @@ def test_the_rehearsals_traced_line_holds_the_five_as_finite_numbers(
     assert got["driver_device_run_p50_ms"]["value"] <= \
         got["driver_in_device_p50_ms"]["value"] \
         + got["driver_fetch_wait_p50_ms"]["value"] + 1e-6
-    # no device, no lag: the reader's entry is in no cell's list anyway
+    # no device, no lag, whichever cell lists the reader's entry
     assert "ready_stamp_lag_p50_ms" not in got
