@@ -85,6 +85,15 @@ class AsyncEncodeDriver:
         #: server ladder hook: called with the exception for every frame
         #: lost to a device/entropy error (driver thread context)
         self.on_error: Optional[Callable[[BaseException], None]] = None
+        #: capture-loop hook: called with nothing, on the driver thread,
+        #: each time results were appended to ``_out`` — the loop's cue to
+        #: ``poll()`` now and not at its next tick. It only signals; the
+        #: loop stays the one consumer of ``_out``
+        self.on_ready: Optional[Callable[[], None]] = None
+        self.on_ready_errors_total = 0
+        #: frames the capture loop took at a tick, at such a cue
+        #: (:meth:`count_harvests`)
+        self.harvests_total = [0, 0]
 
         self._cond = threading.Condition()
         #: the mailbox: the one capture waiting for a slot of the pipe,
@@ -198,6 +207,12 @@ class AsyncEncodeDriver:
             self._out.clear()
         return out
 
+    def count_harvests(self, n: int, on_ready: bool) -> None:
+        """The capture loop says at which of its wake-ups it took ``n``
+        polled frames, for the ``stats()`` line (the loop's thread is the
+        only writer)."""
+        self.harvests_total[on_ready] += n
+
     def flush(self, timeout: float = 60.0) -> List[Tuple[int, list]]:
         """Drain everything submitted so far (deterministic: on return
         the mailbox is empty and every capture accepted has been
@@ -268,6 +283,8 @@ class AsyncEncodeDriver:
         with self._cond:
             # (first: a reader that prints the head of the line shows it)
             st = {"frames_replaced": self.frames_replaced_total,
+                  "harvests_on_ready": self.harvests_total[True],
+                  "harvests_on_tick": self.harvests_total[False],
                   **self._stats_cache}
             st["submit_queue_depth"] = len(self._in_q)     # 0 or 1
         st["frames_dropped"] = (st.get("frames_dropped", 0)
@@ -325,6 +342,15 @@ class AsyncEncodeDriver:
             for k in [k for k in self._seq_map if k < horizon]:
                 self._waits.pop(self._seq_map.pop(k), None)
             self._cond.notify_all()
+        on_ready = self.on_ready
+        if on_ready is not None:
+            # outside _cond: the hook wakes another thread's event loop
+            try:
+                on_ready()
+            except Exception:
+                # a loop that has gone (teardown) must not cost a frame
+                self.on_ready_errors_total += 1
+                logger.debug("on_ready hook failed", exc_info=True)
         self._track("emit", t_emit0, time.monotonic())
 
     def _harvest(self, flush_partial: bool, wait: bool = False) -> bool:

@@ -103,6 +103,20 @@ class Metrics:
             "watch had seen every earlier step's output ready): the chip "
             "stood idle before each of them",
             registry=self.registry)
+        # which of the capture loop's two wake-ups took each frame out of
+        # its encoder: the encoder's ready cue, or the next capture tick
+        self.harvest_on_ready_share = Gauge(
+            "tpuenc_harvest_on_ready_share", "Share of harvested frames "
+            "the capture loop took at the encoder's ready cue, not at its "
+            "next tick (a frame taken at a tick waited for the clock)",
+            registry=self.registry)
+        self.harvests_on_ready = Counter(
+            "harvests_on_ready_total", "Frames the capture loop harvested "
+            "when the encoder said one was ready", registry=self.registry)
+        self.harvests_on_tick = Counter(
+            "harvests_on_tick_total", "Frames the capture loop harvested "
+            "at a capture tick", registry=self.registry)
+        self._harvests = [0, 0]             # at a tick, at a ready cue
         # ISSUE 12: the dispatch/fetch-floor claims must stay measured —
         # the async pipeline driver keeps >=2 batches in flight, and
         # these series prove (or disprove) it per deployment
@@ -351,6 +365,16 @@ class Metrics:
     def set_launch_idle_share(self, share: float) -> None:
         if HAVE_PROM:
             self.launch_idle_share.set(share)
+
+    def count_harvests(self, n: int, on_ready: bool) -> None:
+        """``n`` frames left an encoder for the capture loop, at the
+        encoder's ready cue or at a tick."""
+        if HAVE_PROM and n > 0:
+            (self.harvests_on_ready if on_ready
+             else self.harvests_on_tick).inc(n)
+            self._harvests[on_ready] += n
+            self.harvest_on_ready_share.set(
+                self._harvests[1] / sum(self._harvests))
 
     def set_inflight_batches(self, n: int) -> None:
         if HAVE_PROM:

@@ -86,6 +86,22 @@ def _clamp_dim(v: int) -> int:
     return min(MAX_DISPLAY_DIM, max(16, int(v) & ~1))
 
 
+async def _ready_before(ready: asyncio.Event, deadline: float) -> bool:
+    """Sleep until ``deadline`` (``time.monotonic``) or until ``ready`` is
+    set, whichever comes first; True where it was ``ready``, which is
+    cleared. Always yields to the loop once, as ``asyncio.sleep(0)``."""
+    delay = deadline - time.monotonic()
+    if delay <= 0.0:
+        await asyncio.sleep(0)
+        return False
+    try:
+        await asyncio.wait_for(ready.wait(), delay)
+    except asyncio.TimeoutError:
+        return False
+    ready.clear()
+    return True
+
+
 def _ws_broadcast(targets, message) -> None:
     """Fan one message out to many clients.
 
@@ -1446,6 +1462,14 @@ class DataStreamingServer:
             # encode errors harvested off-loop (worker thread futures) feed
             # the same ladder as loop-crashing EncoderFaults
             encoder.on_error = lambda exc: st.ladder.record_failure()
+        #: set (from the encoder's thread) when a finished frame lies in
+        #: the encoder: the loop then harvests it now, between two ticks,
+        #: where it would have lain until the next one. An encoder without
+        #: the hook is harvested at the ticks alone
+        ready = asyncio.Event()
+        if hasattr(encoder, "on_ready"):
+            wake = asyncio.get_running_loop().call_soon_threadsafe
+            encoder.on_ready = lambda: wake(ready.set)
         if getattr(encoder, "faults", False) is None:
             # the async driver checks fetch.hang at ITS harvest site, so
             # one SELKIES_TPU_FAULTS entry can wedge either side of the
@@ -1478,6 +1502,9 @@ class DataStreamingServer:
             #: ladder's own threshold, force a supervised rebuild rather
             #: than streaming nothing forever
             error_ticks = 0
+            #: frames with stripes that left at a ready cue since the last
+            #: tick (the tick's ladder and wedge bookkeeping counts them)
+            emitted_on_ready = 0
             #: a pipeline that stops ACCEPTING submits and harvesting
             #: anything is wedged even though the loop itself still ticks
             #: (e.g. a dead mesh worker); a first-use jit compile is told
@@ -1556,53 +1583,20 @@ class DataStreamingServer:
                             pending.add(seq, tr)
                         progressed = True
                 await faults.maybe_hang("fetch.hang")
-                try:
-                    harvested = encoder.poll()
-                except Exception as e:
-                    raise EncoderFault(
-                        f"encoder poll failed: {e!r}") from e
+                ready.clear()       # this poll takes what it was set for
+                frame_id, emitted = self._harvest_and_emit(
+                    st, encoder, recorder, pending, frame_id)
                 if sup is not None:
                     # submit/poll can legitimately block the loop for one
                     # long stretch (first-use jit compile); beating after
                     # them keeps that from reading as a stall
                     sup.beat()
-                t_harvest = time.monotonic() if harvested else 0.0
-                for _seq, stripes in harvested:
-                    tr = pending.take(_seq)
-                    if tr is not None:
-                        # fold in the encoder-side stage intervals
-                        # (submit_wait ... pack) harvested with the frame
-                        pop_trace = getattr(encoder, "pop_trace", None)
-                        if pop_trace is not None:
-                            try:
-                                tr.merge(pop_trace(_seq))
-                            except Exception:
-                                logger.debug("pop_trace failed",
-                                             exc_info=True)
-                        packed = tr.spans.get("pack")
-                        if packed is not None:
-                            # packed on the driver's thread -> taken by
-                            # this loop's poll()
-                            tr.mark("harvest_wait", packed[1],
-                                    max(packed[1], t_harvest))
-                    if not stripes:
-                        # damage gating emitted nothing: a coalesced
-                        # frame, closed (not dropped, not acked)
-                        if tr is not None:
-                            recorder.finish_empty(tr)
-                        continue
+                # frames that left at a ready cue since the last tick are
+                # this tick's progress as much as those it took itself
+                emitted += emitted_on_ready
+                emitted_on_ready = 0
+                if emitted:
                     progressed = True
-                    frame_id = FrameId.next(frame_id)
-                    viewers = self._viewers_of(st.display_id)
-                    try:
-                        self._emit_frame(st, encoder, frame_id, stripes,
-                                         viewers, tr)
-                    except BaseException:
-                        if tr is not None and tr.terminal is None:
-                            recorder.drop(tr, "send")
-                        raise
-                    st.bp.on_frame_sent(frame_id)
-                if any(stripes for _seq, stripes in harvested):
                     accepted = True
                 now = time.monotonic()
                 if accepted or 0.0 < compiling_for_s() < COMPILE_GRACE_S:
@@ -1646,11 +1640,16 @@ class DataStreamingServer:
                     self._spawn_background(st.ws.close(),
                                            f"ws.drop:{st.display_id}")
                 next_tick += interval
-                delay = next_tick - time.monotonic()
-                if delay < -1.0:  # fell badly behind; resynchronize
-                    next_tick = time.monotonic()
-                    delay = 0.0
-                await asyncio.sleep(max(0.0, delay))
+                if next_tick - time.monotonic() < -1.0:
+                    next_tick = time.monotonic()    # fell badly behind
+                # sleep to the tick; a ready cue on the way there is a
+                # finished frame: it leaves now, and the sleep goes on to
+                # the SAME tick (captures stay on their grid)
+                while await _ready_before(ready, next_tick):
+                    frame_id, emitted = self._harvest_and_emit(
+                        st, encoder, recorder, pending, frame_id,
+                        on_ready=True)
+                    emitted_on_ready += emitted
         finally:
             if source is not None:
                 try:
@@ -1670,6 +1669,63 @@ class DataStreamingServer:
                 except Exception:
                     logger.exception("encoder close for %s raised",
                                      st.display_id)
+
+    def _harvest_and_emit(self, st: DisplayState, encoder, recorder,
+                          pending, frame_id: int, *,
+                          on_ready: bool = False) -> Tuple[int, int]:
+        """Take what the encoder has finished and send it: the capture
+        loop's one ``poll()`` site, run at every tick and, ``on_ready``,
+        when the encoder's ready cue wakes the loop between two ticks.
+        Frames leave in harvest order under consecutive frame ids. Returns
+        the last frame id issued and how many frames carried stripes."""
+        try:
+            harvested = encoder.poll()
+        except Exception as e:
+            raise EncoderFault(
+                f"encoder poll failed: {e!r}") from e
+        if not harvested:
+            return frame_id, 0
+        t_harvest = time.monotonic()
+        for counter in (self.metrics, encoder):
+            count = getattr(counter, "count_harvests", None)
+            if count is not None:
+                count(len(harvested), on_ready)
+        pop_trace = getattr(encoder, "pop_trace", None)
+        emitted = 0
+        for _seq, stripes in harvested:
+            tr = pending.take(_seq)
+            if tr is not None:
+                # fold in the encoder-side stage intervals
+                # (submit_wait ... pack) harvested with the frame
+                if pop_trace is not None:
+                    try:
+                        tr.merge(pop_trace(_seq))
+                    except Exception:
+                        logger.debug("pop_trace failed", exc_info=True)
+                packed = tr.spans.get("pack")
+                if packed is not None:
+                    # packed on the driver's thread -> taken by this
+                    # loop's poll()
+                    tr.mark("harvest_wait", packed[1],
+                            max(packed[1], t_harvest))
+            if not stripes:
+                # damage gating emitted nothing: a coalesced frame,
+                # closed (not dropped, not acked)
+                if tr is not None:
+                    recorder.finish_empty(tr)
+                continue
+            emitted += 1
+            frame_id = FrameId.next(frame_id)
+            viewers = self._viewers_of(st.display_id)
+            try:
+                self._emit_frame(st, encoder, frame_id, stripes,
+                                 viewers, tr)
+            except BaseException:
+                if tr is not None and tr.terminal is None:
+                    recorder.drop(tr, "send")
+                raise
+            st.bp.on_frame_sent(frame_id)
+        return frame_id, emitted
 
     def _emit_frame(self, st: DisplayState, encoder, frame_id: int,
                     stripes, viewers, tr) -> None:
