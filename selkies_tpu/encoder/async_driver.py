@@ -27,6 +27,12 @@ idles waiting on the host:
   counted), so the chip's next frame is the newest picture and never
   the oldest of a backlog — the contract a mesh lane's facade speaks
   (``MeshSessionFacade.try_submit``), and the event loop never stalls;
+* the pipe is bounded twice (``pipe.has_room``): ``depth`` frames
+  between dispatch and pack, which is the host's slack and the staging
+  ring, and of them two steps unfinished on the chip once a capture is
+  launched, one running and one queued — a capture waits in the mailbox,
+  replaceable, until the chip can nearly take it, and not on the chip
+  behind work it was given earlier;
 * ``flush()`` drains deterministically; ``close()`` mid-flight neither
   deadlocks nor leaks a staging slot, so PR 2 supervisor restarts and
   PR 3 evictions stay safe.
@@ -357,7 +363,8 @@ class AsyncEncodeDriver:
         """One harvest pass; True if anything completed. ``wait`` blocks
         until the pipe's oldest frame is in (the pipe marks that
         ``fetch_wait``): only ever asked with a capture waiting behind a
-        full pipe, when nothing else is left for this thread to do."""
+        pipe with no room, when nothing else is left for this thread to
+        do."""
         if self.faults is not None:
             self.faults.maybe_hang_sync(FETCH_HANG_POINT)
         results = self.pipe.poll(flush_partial=flush_partial, wait=wait)
@@ -376,14 +383,19 @@ class AsyncEncodeDriver:
 
     def _feed(self) -> None:
         """Dispatch the waiting capture into a free slot of the pipe. A
-        capture leaves the mailbox only when the pipe has room for it:
-        what cannot be staged yet waits where ``try_submit`` sees it (and
+        capture leaves the mailbox only when the pipe has room for it
+        (fewer than ``depth`` frames unpacked and, of them, at most the
+        running step unfinished on the chip: ``pipe.has_room``): what
+        cannot be launched yet waits where ``try_submit`` sees it (and
         puts a newer one in its place), not in a list of this thread's
-        behind a submit that blocks. An erroring frame costs ITSELF
+        behind a submit that blocks, nor on the chip behind steps it was
+        given earlier. An erroring frame costs ITSELF
         (counted + reported), never the rest of the pass; a frame the
         pipe never accepted gets no seq mapping, so its loss cannot shift
         later results onto wrong seqs."""
-        while self.pipe.has_room:
+        # (the pipe is asked only with a capture waiting: it counts the
+        # launches it holds back)
+        while self._in_q and self.pipe.has_room:
             with self._cond:
                 if self._stop or not self._in_q:
                     return
@@ -413,9 +425,12 @@ class AsyncEncodeDriver:
         self._feed()
         try:
             # 2. harvest whatever is ready. With a capture still waiting
-            # the pipe is full: block for its oldest frame, whose slot
-            # the next pass hands on; its successors keep the device busy
-            # while this thread packs it.
+            # the pipe has no room: block for its oldest frame, the one
+            # whose end makes room (its slot, and the chip's queue: frames
+            # are packed in order, so the oldest unpacked is the step that
+            # runs or one that has ended); the step behind it keeps the
+            # device busy while this thread packs it, and the next pass
+            # launches the capture that is the newest then.
             with self._cond:
                 backlog = bool(self._in_q)
             # (with the mailbox dry, JPEG's partly filled fetch group ships)

@@ -60,11 +60,23 @@ class _PipelineTelemetry:
     a ``tpuenc-ready`` thread, which stamps when it became ready; at
     harvest the stamp splits ``in_device`` + ``fetch_wait`` into
     ``device_wait``, ``device_run`` and ``ready_wait``
-    (observability/device_probe.py ``ready_stages``)."""
+    (observability/device_probe.py ``ready_stages``).
+
+    The pipe is bounded twice (ISSUE 47, :attr:`has_room`): ``depth``
+    bounds the frames between dispatch and pack (the staging ring, and
+    what the host has still to pack), ``CHIP_STEPS`` the steps of them
+    that the chip has still to do."""
 
     #: ``callable(state, t0, t1)`` or None: where the thread that drives
     #: this pipe reports the states it is in (AsyncEncodeDriver sets it)
     track = None
+
+    #: steps of this stream the chip holds once a capture is launched: the
+    #: one that runs and one queued behind it, which is all it takes for
+    #: the chip to go from a step to the next without waiting for the
+    #: host. A step queued beyond that buys nothing and ages its capture
+    #: by a whole step (PERF.md: ``device_wait`` 39 ms above the knee)
+    CHIP_STEPS = 2
 
     def _init_telemetry(self) -> None:
         self._dispatch_ms: deque = deque(maxlen=256)
@@ -75,6 +87,11 @@ class _PipelineTelemetry:
         #: never grow it unboundedly
         self._trace_out: "dict" = {}
         self._ready_watch = ReadyWatch("tpuenc-ready")
+        #: launches that ``has_room`` held back for the chip's queue while
+        #: ``depth`` alone would have admitted them (and whether the one
+        #: to come has been)
+        self.launches_held_for_chip = 0
+        self._held_for_chip = False
 
     def compiling_for_s(self) -> float:
         """The base encoder's first-use compile signal (runtime.CompileWatch)."""
@@ -82,10 +99,33 @@ class _PipelineTelemetry:
 
     @property
     def has_room(self) -> bool:
-        """True while ``submit`` would dispatch without first blocking
-        for the oldest frame: what the async driver asks before it takes
-        a capture out of its queue."""
-        return self.n_inflight < self.depth
+        """What the async driver asks, with a capture waiting, before it
+        takes it out of its mailbox. Two bounds: fewer than ``depth``
+        frames between dispatch and pack (``submit`` would not block for
+        the oldest first), and fewer than ``CHIP_STEPS`` steps of them
+        unfinished on the chip, so that the capture launched is the
+        freshest at the moment the chip can nearly take it. The steps
+        unfinished are the ready watch's count, never taken to exceed the
+        pipe's own frames: an empty pipe always has room, and stamps that
+        never land cannot hold a capture back for ever (the driver blocks
+        for the oldest frame while there is no room, and each one packed
+        is one fewer). A stopped watch counts nothing: ``depth`` alone."""
+        n = self.n_inflight
+        if n >= self.depth:
+            return False
+        watch = self._ready_watch
+        if watch.stopped or min(watch.ahead, n) < self.CHIP_STEPS:
+            return True
+        self._held_for_chip = True
+        return False
+
+    def _launched(self, step_out: Any, ahead: int) -> Optional[ReadyStamp]:
+        """One step was launched with ``ahead`` before it unfinished:
+        hand its own output to the ready watch, and count the launch if
+        the chip's queue had held it back."""
+        self.launches_held_for_chip += self._held_for_chip
+        self._held_for_chip = False
+        return self._ready_watch.launched(step_out, ahead)
 
     def _mark(self, trace: Optional[dict], state: str,
               t0: float, t1: float) -> None:
@@ -149,12 +189,15 @@ class _PipelineTelemetry:
             "dispatch_p50_ms": round(_p50(self._dispatch_ms), 3),
             "fetch_wait_p50_ms": round(_p50(self._fetch_wait_ms), 3),
             **self._ready_watch.counts(),
+            "launches_held_for_chip": self.launches_held_for_chip,
         }
 
     def _publish_launch_idle(self, st: dict) -> None:
         if st["launches"]:
             self.metrics.set_launch_idle_share(
                 st["launches_into_idle"] / st["launches"])
+            self.metrics.set_launch_held_for_chip_share(
+                st["launches_held_for_chip"] / st["launches"])
 
 
 @dataclass
@@ -349,7 +392,7 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
             seq=self._seq, paint_candidate=paint_candidate,
             packed=packed, yq=yq, cbq=cbq, crq=crq, ticket=ticket,
         )
-        item.ready = self._ready_watch.launched(packed, ahead)
+        item.ready = self._launched(packed, ahead)
         if stage_iv is not None:
             self._mark(item.trace, "stage", *stage_iv)
         td1 = time.monotonic()
@@ -840,7 +883,7 @@ class PipelinedH264Encoder(_PipelineTelemetry):
         # the buffer the P step wrote (an IDR's step writes its fetch),
         # not the prefix slice a program of its own cuts from it
         buf = getattr(p, "buf", None)
-        item.ready = self._ready_watch.launched(
+        item.ready = self._launched(
             buf if buf is not None else p.fetch, ahead)
         if slot is not None:
             self._mark(item.trace, "stage", ts0, td0)

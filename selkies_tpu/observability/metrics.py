@@ -103,6 +103,13 @@ class Metrics:
             "watch had seen every earlier step's output ready): the chip "
             "stood idle before each of them",
             registry=self.registry)
+        self.launch_held_for_chip_share = Gauge(
+            "tpuenc_launch_held_for_chip_share", "Share of step launches "
+            "that the pipe held back until at most the running step of "
+            "their stream was unfinished on the chip, while its depth "
+            "alone would have admitted them (their captures waited in "
+            "the driver's mailbox, replaceable, and not on the chip)",
+            registry=self.registry)
         # which of the capture loop's two wake-ups took each frame out of
         # its encoder: the encoder's ready cue, or the next capture tick
         self.harvest_on_ready_share = Gauge(
@@ -365,6 +372,10 @@ class Metrics:
     def set_launch_idle_share(self, share: float) -> None:
         if HAVE_PROM:
             self.launch_idle_share.set(share)
+
+    def set_launch_held_for_chip_share(self, share: float) -> None:
+        if HAVE_PROM:
+            self.launch_held_for_chip_share.set(share)
 
     def count_harvests(self, n: int, on_ready: bool) -> None:
         """``n`` frames left an encoder for the capture loop, at the

@@ -4,6 +4,8 @@
   with room in the pipe and with the pipe full; behind a full pipe the
   driver's mailbox keeps the newest capture, and the span that rides
   with a delivered frame is the one its own capture opened (ISSUE 43);
+  where the step does not fit the tick a frame waits in the mailbox for
+  the chip's queue and under a step on the chip (ISSUE 47);
 * the driver thread's track never overlaps itself and covers the loop;
 * the device probe writes clock pairs, from a thread of its own;
 * the stall watch tells a blocked loop from a kept interpreter lock;
@@ -40,14 +42,28 @@ def anyio_backend():
     return "asyncio"
 
 
+class StepOut:
+    """A pretend step's output, for the ready watch to block for."""
+
+    def __init__(self, ready_at):
+        self.ready_at = ready_at
+
+    def block_until_ready(self):
+        time.sleep(max(0.0, self.ready_at - time.monotonic()))
+        return self
+
+
 class FakePipe(_PipelineTelemetry):
     """A pipelined encoder with a pretend device: one step at a time,
     ``step_s`` each; ``submit`` blocks draining the oldest when ``depth``
     frames are in flight, as the real pipes do. A stripe's payload says
-    which picture it was made from (the frame's first byte)."""
+    which picture it was made from (the frame's first byte). ``watched``:
+    each step's output goes to the ready watch, as the real pipes' does,
+    so that ``has_room`` counts the steps unfinished."""
 
-    def __init__(self, depth=4, step_s=0.004, stage_s=0.001):
+    def __init__(self, depth=4, step_s=0.004, stage_s=0.001, watched=False):
         self.depth, self.step_s, self.stage_s = depth, step_s, stage_s
+        self.watched = watched
         self.metrics = None
         self.d2h_bytes_total = 0
         self._inflight = deque()        # [seq, trace, ready_at, level]
@@ -76,16 +92,19 @@ class FakePipe(_PipelineTelemetry):
         time.sleep(self.stage_s)
         t1 = time.monotonic()
         self._mark(trace, "stage", t0, t1)
+        ahead = self._ready_watch.ahead
         self._device_free_at = max(self._device_free_at, t1) + self.step_s
+        stamp = self._launched(StepOut(self._device_free_at), ahead) \
+            if self.watched else None
         t2 = time.monotonic()
         self._mark(trace, "dispatch", t1, t2)
         seq, self._seq = self._seq, self._seq + 1
         self._inflight.append([seq, trace, self._device_free_at,
-                               int(np.asarray(frame).flat[0])])
+                               int(np.asarray(frame).flat[0]), stamp])
         return seq
 
     def _drain_one(self, block):
-        seq, trace, ready_at, level = self._inflight[0]
+        seq, trace, ready_at, level, stamp = self._inflight[0]
         t0 = time.monotonic()
         if t0 < ready_at:
             if not block:
@@ -96,7 +115,7 @@ class FakePipe(_PipelineTelemetry):
         self._mark(trace, "fetch_wait", t0, t1)
         time.sleep(0.0005)
         self._mark(trace, "pack", t1, time.monotonic())
-        self._trace_store(seq, trace)
+        self._trace_store(seq, trace, stamp)
         return seq, [StripeOutput(y_start=0, height=64,
                                   jpeg=b"\xff\xd8L%d\xff\xd9" % level,
                                   is_paintover=False)]
@@ -120,6 +139,7 @@ class FakePipe(_PipelineTelemetry):
 
     def close(self):
         self._inflight.clear()
+        self._ready_watch.stop()
 
 
 class NumberedSource:
@@ -257,6 +277,43 @@ async def test_a_frames_marks_run_from_capture_to_send_without_a_hole(
         assert rec.dropped_total > 0          # the older captures are lost
     else:
         assert waits[len(waits) // 2] < 5.0
+    assert rec.open_spans() == 0
+
+
+@pytest.mark.anyio
+async def test_above_the_knee_a_frame_waits_in_the_mailbox_not_on_the_chip(
+        tmp_path, monkeypatch):
+    """ISSUE 47, through the capture loop: a step of 60 ms against a tick
+    of 33 and a pipe of depth 4. A capture is launched when at most the
+    running step is unfinished, so a delivered frame's ``device_wait`` is
+    the rest of that step (under one step; behind three it was over two)
+    and its wait for that moment lies in ``submit_wait``, where a newer
+    capture takes its place: a tick at most."""
+    step_ms, pipes = 60.0, []
+
+    def factory(w, h, settings, overrides=None):
+        pipes.append(FakePipe(depth=4, step_s=step_ms / 1000.0,
+                              watched=True))
+        return AsyncEncodeDriver(pipes[-1])
+
+    server = make_server(tmp_path, monkeypatch, factory, [])
+    rec = server.recorder = FlightRecorder(capacity=4096)
+    await serve_frames(server, 2.0)
+    done = [t for t in rec._completed()
+            if t.terminal == "acked" and "device_wait" in t.spans]
+    assert len(done) >= 15
+
+    def p50(stage):
+        v = sorted(t.duration_ms(stage) for t in done)
+        return v[len(v) // 2]
+
+    assert p50("device_wait") < step_ms
+    assert p50("device_run") == pytest.approx(step_ms, abs=10.0)
+    assert p50("in_device") + p50("fetch_wait") < 2 * step_ms + 10.0
+    assert p50("submit_wait") < 33.4 + 15.0
+    assert rec.dropped_total > 0              # the older captures are lost
+    st = pipes[0]._telemetry_stats()
+    assert st["launches_held_for_chip"] >= 0.8 * st["launches"] > 0
     assert rec.open_spans() == 0
 
 

@@ -13,6 +13,11 @@ Covers the tentpole's safety obligations, not its throughput claims
   allocation instead;
 * a supervisor-style restart mid-flight (close + rebuild) neither
   deadlocks nor leaks a ring slot;
+* a capture is launched when at most the running step of its stream is
+  unfinished on the chip (ISSUE 47: the pipes' ``has_room`` over a
+  pretend chip), whatever ``depth`` would admit: the chip never waits,
+  the host launches after its pack, and a silent or stopped ready watch
+  wedges nothing;
 * slow-marked soak: ~10 s under ``fetch.hang`` chaos with no wedge and
   no monotonic in-flight growth.
 """
@@ -22,12 +27,14 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
 from selkies_tpu.encoder.async_driver import AsyncEncodeDriver
 from selkies_tpu.encoder.h264_device import StagingRing
+from selkies_tpu.encoder.pipeline import _PipelineTelemetry
 from selkies_tpu.robustness import FaultInjector
 
 
@@ -706,6 +713,310 @@ def test_midpass_harvest_error_preserves_completed_frames_and_tickets():
     assert [s for s, _ in pipe.flush()] == [2]
     # the failed frame's ring slot was freed, not leaked
     assert pipe._staging.in_use == 0
+    pipe.close()                             # and its ready watch's thread
+
+
+# ---------------------------------------------------------------------------
+# the rule a capture is admitted by (ISSUE 47): the real ``has_room`` of
+# the pipes' shared telemetry, over a pretend chip
+
+
+class _StepOut:
+    """A pretend step's output: ready at the instant the pretend chip has
+    run its step (for the ready watch too, unless the chip is ``silent``:
+    then the watch blocks for it until the pipe closes)."""
+
+    def __init__(self, ready_at, silent=None):
+        self.ready_at, self.silent = ready_at, silent
+
+    def is_ready(self):
+        return time.monotonic() >= self.ready_at
+
+    def wait(self):
+        time.sleep(max(0.0, self.ready_at - time.monotonic()))
+
+    def block_until_ready(self):
+        if self.silent is not None:
+            self.silent.wait(30.0)
+        self.wait()
+        return self
+
+
+class _ChipPipe(_PipelineTelemetry):
+    """The pipes' telemetry, so their ``has_room``, over a pretend chip
+    that runs one step at a time in launch order, ``step_s`` each; the
+    host's work for a frame is ``launch_s`` at the launch and ``pack_s``
+    at the harvest. ``launches`` and ``packs`` say what happened when:
+    a launch's frame, its instant, the steps truly unfinished then (by
+    the chip's own clock, not the watch's), how long the chip had stood
+    idle, when its step begins, and whether the pipe's oldest frame had
+    ended; a blocking wait's frame and whether its step had begun."""
+
+    def __init__(self, depth=4, step_s=0.06, launch_s=0.002, pack_s=0.012,
+                 silent=False):
+        self.depth = depth
+        self.step_s, self.launch_s, self.pack_s = step_s, launch_s, pack_s
+        self.metrics = None
+        self.silent = threading.Event() if silent else None
+        self._inflight: deque = deque()          # (seq, frame, out)
+        self._ready: list = []
+        self._seq = 0
+        self._free_at = 0.0
+        self.launches: list = []
+        self.packs: list = []
+        self.blocked: list = []
+        self.inflight_max = 0
+        self._init_telemetry()
+
+    inflight_batches = 0
+
+    @property
+    def n_inflight(self):
+        return len(self._inflight)
+
+    def submit(self, frame):
+        assert self.n_inflight < self.depth      # the driver asked first
+        ahead = self._ready_watch.ahead
+        time.sleep(self.launch_s)
+        now = time.monotonic()
+        outs = [out for _s, _f, out in self._inflight]
+        begins = max(now, self._free_at)
+        self.launches.append(SimpleNamespace(
+            frame=frame, t=now, begins=begins,
+            unfinished=sum(not out.is_ready() for out in outs),
+            idle_s=(now - self._free_at) if self._seq else 0.0,
+            oldest_ended=not outs or outs[0].is_ready()))
+        self._free_at = begins + self.step_s
+        out = _StepOut(self._free_at, self.silent)
+        self._launched(out, ahead)
+        seq, self._seq = self._seq, self._seq + 1
+        self._inflight.append((seq, frame, out))
+        self.inflight_max = max(self.inflight_max, self.n_inflight)
+        return seq
+
+    def _drain_one(self):
+        seq, frame, out = self._inflight[0]
+        if not out.is_ready():
+            # blocking for a step that has not even begun would be
+            # blocking for the one before it too
+            self.blocked.append(SimpleNamespace(
+                seq=seq, begun=time.monotonic() >= out.ready_at - self.step_s))
+            out.wait()
+        self._inflight.popleft()
+        t0 = time.monotonic()
+        time.sleep(self.pack_s)
+        self.packs.append(SimpleNamespace(seq=seq, t0=t0,
+                                          t1=time.monotonic()))
+        return seq, [frame]
+
+    def poll(self, flush_partial=True, wait=False):
+        if wait and self._inflight:
+            self._ready.append(self._drain_one())
+        while self._inflight and self._inflight[0][2].is_ready():
+            self._ready.append(self._drain_one())
+        out, self._ready = self._ready, []
+        return out
+
+    def flush(self):
+        while self._inflight:
+            self._ready.append(self._drain_one())
+        out, self._ready = self._ready, []
+        return out
+
+    def stats(self):
+        return {"frames": self._seq, **self._telemetry_stats()}
+
+    def close(self):
+        self._inflight.clear()
+        if self.silent is not None:
+            self.silent.set()
+        self._ready_watch.stop()
+
+
+class _DepthOnlyPipe(_ChipPipe):
+    """The same pretend chip under the rule before ISSUE 47."""
+
+    @property
+    def has_room(self):
+        return self.n_inflight < self.depth
+
+
+def _offer(drv, seconds, period_s):
+    """A source of numbered captures, one every ``period_s``: what was
+    offered and when, and what came out meanwhile."""
+    offered, out = [], []
+    t_end = time.monotonic() + seconds
+    while time.monotonic() < t_end:
+        offered.append(time.monotonic())
+        drv.try_submit(len(offered) - 1)
+        out += drv.poll()
+        time.sleep(period_s)
+    return offered, out
+
+
+def _reap(drv):
+    drv.close()
+    drv._thread.join(timeout=10.0)
+    assert not drv._thread.is_alive()
+    assert _wait_until(lambda: not drv.pipe._ready_watch.alive)
+
+
+def test_a_source_faster_than_the_step_keeps_one_step_running_and_one_queued():
+    """Above the knee (a capture every 10 ms, a step of 60): a capture is
+    launched only when at most the running step is unfinished, after the
+    pack of the frame that has just ended, and it is the newest one; the
+    chip goes from each step to the next without standing idle; what was
+    offered is what came out plus what was replaced."""
+    pipe = _ChipPipe(depth=4, step_s=0.06)
+    drv = AsyncEncodeDriver(pipe)
+    try:
+        offered, out = _offer(drv, 0.9, 0.010)
+        out += drv.flush(timeout=10.0)
+    finally:
+        _reap(drv)
+    ls, n = pipe.launches, len(pipe.launches)
+    assert 10 <= n == len(out)
+    assert len(offered) == len(out) + drv.frames_replaced_total
+    assert [f for _s, (f,) in out] == [l.frame for l in ls]
+    # one running and one queued, never more: depth alone would have made
+    # it three unfinished at every launch
+    assert max(l.unfinished for l in ls) <= 1
+    assert pipe.inflight_max <= 3
+    # ... and never fewer: the chip did not wait for the host
+    assert [l.idle_s for l in ls[1:] if l.idle_s > 0.0] == []
+    # pack, then feed: a launch comes after the pack of the frame two
+    # before it, into the step that runs then
+    packed = {p.seq: p.t1 for p in pipe.packs}
+    assert all(ls[k].t >= packed[k - 2] for k in range(3, n))
+    # the capture the chip is given is about a tick old when it is
+    # launched and under a step and a half old when its step begins
+    # (behind three unfinished steps it would be over two)
+    for l in ls[3:]:
+        assert l.t - offered[l.frame] < 0.010 + 0.030
+        assert l.begins - offered[l.frame] < 1.5 * pipe.step_s
+    st = drv.stats()
+    assert st["launches"] == n
+    assert st["launches_held_for_chip"] >= n - 3
+    # the driver blocked for the step that ran, never for a queued one
+    assert pipe.blocked and all(b.begun for b in pipe.blocked)
+
+
+def test_a_step_under_its_tick_is_launched_as_before_and_nothing_is_held():
+    """A step of 15 ms under a tick of 40: at every launch one step or
+    none is unfinished, the rule holds nothing back, and every capture is
+    launched, in order, as under ``depth`` alone."""
+    runs = []
+    for cls in (_ChipPipe, _DepthOnlyPipe):
+        pipe = cls(depth=4, step_s=0.015, pack_s=0.003)
+        drv = AsyncEncodeDriver(pipe)
+        try:
+            offered, out = _offer(drv, 0.5, 0.040)
+            out += drv.flush(timeout=10.0)
+        finally:
+            _reap(drv)
+        assert drv.frames_replaced_total == 0
+        assert drv.stats()["launches_held_for_chip"] == 0
+        assert max(l.unfinished for l in pipe.launches) <= 1
+        runs.append(([l.frame for l in pipe.launches], len(offered),
+                     [f for _s, (f,) in out]))
+    for launched, n_offered, delivered in runs:
+        assert launched == delivered == list(range(n_offered))
+
+
+def test_where_the_host_is_the_slower_side_the_launch_comes_before_the_pack():
+    """A traced window's regime, 27 ms of host work a frame (launch 6,
+    pack 21) against a step of 25: at the top of a pass one step or none
+    is unfinished, so the rule does not bind: the capture is launched
+    before the frame that has ended is packed, as under ``depth`` alone,
+    and the chip is given as many steps."""
+    frames = {}
+    for cls in (_ChipPipe, _DepthOnlyPipe):
+        pipe = cls(depth=4, step_s=0.025, launch_s=0.006, pack_s=0.021)
+        drv = AsyncEncodeDriver(pipe)
+        try:
+            offered, out = _offer(drv, 1.0, 0.0125)
+            out += drv.flush(timeout=10.0)
+        finally:
+            _reap(drv)
+        frames[cls] = len(out)
+        assert len(offered) == len(out) + drv.frames_replaced_total
+        if cls is _ChipPipe:
+            ls = pipe.launches
+            assert max(l.unfinished for l in ls) <= 1
+            # the frame before had ended, unpacked, at (nearly) every
+            # launch: the launch was not kept behind its pack, and few
+            # were held back at all
+            assert sum(l.oldest_ended for l in ls[2:]) >= 0.8 * (len(ls) - 2)
+            assert drv.stats()["launches_held_for_chip"] <= 0.2 * len(ls)
+            assert all(b.begun for b in pipe.blocked)
+    assert frames[_ChipPipe] >= 0.85 * frames[_DepthOnlyPipe] >= 20
+
+
+@pytest.mark.parametrize("watch", ["silent", "stopped"])
+def test_a_ready_watch_that_says_nothing_wedges_neither_the_driver_nor_flush(
+        watch):
+    """Stamps that never land (the watch's thread blocked for good): the
+    count of unfinished steps is never taken above the pipe's own frames,
+    so the capture waiting is launched once the frames ahead of it are
+    packed. A stopped watch (its owner's, or an array that raised):
+    ``depth`` alone, as before. Either way frames come out, ``flush()``
+    returns and what was offered is accounted for."""
+    pipe = _ChipPipe(depth=4, step_s=0.02, pack_s=0.004,
+                     silent=watch == "silent")
+    if watch == "stopped":
+        pipe._ready_watch.stop()
+    drv = AsyncEncodeDriver(pipe)
+    try:
+        offered, out = _offer(drv, 0.5, 0.005)
+        assert len(out) >= 5                      # it streams meanwhile
+        t0 = time.monotonic()
+        out += drv.flush(timeout=10.0)
+        assert time.monotonic() - t0 < 2.0 and not drv._in_q
+        assert pipe.n_inflight == 0
+        assert len(offered) == len(out) + drv.frames_replaced_total
+        assert [f for _s, (f,) in out] == sorted(f for _s, (f,) in out)
+        st = drv.stats()
+        if watch == "silent":
+            assert pipe._ready_watch.ahead == st["launches"] == len(out)
+            assert pipe.inflight_max <= 2         # one packed, one launched
+        else:
+            assert (st["launches"], st["launches_held_for_chip"]) == (0, 0)
+            assert pipe.inflight_max == pipe.depth
+    finally:
+        _reap(drv)
+
+
+def test_flush_and_close_midflight_return_while_the_rule_holds_a_capture():
+    """With one step running, one queued and a capture held back in the
+    mailbox: ``flush()`` takes the survivor too and returns with nothing
+    left anywhere; ``close()`` returns at once and the thread ends."""
+    pipe = _ChipPipe(depth=4, step_s=0.08)
+    drv = AsyncEncodeDriver(pipe)
+    try:
+        for i in range(2):
+            _submit_taken(drv, i)
+        assert drv.try_submit(2) == 2
+        assert drv.try_submit(3) is None and drv.replaced_seq == 2
+        time.sleep(0.02)
+        # depth has room for two more, the chip's queue for none
+        assert pipe.n_inflight == 2 < pipe.depth and drv._in_q
+        assert not pipe.has_room
+        out = drv.flush(timeout=10.0)
+        assert [(s, f) for s, (f,) in out] == [(0, 0), (1, 1), (2, 3)]
+        assert not drv._in_q and pipe.n_inflight == 0
+        assert drv.stats()["launches_held_for_chip"] == 1
+
+        for i in range(4, 6):
+            _submit_taken(drv, i)
+        assert drv.try_submit(6) is not None
+        time.sleep(0.01)
+        assert drv._in_q and pipe.n_inflight == 2
+        t0 = time.monotonic()
+        drv.close()
+        assert time.monotonic() - t0 < 0.5
+    finally:
+        _reap(drv)
+    assert not drv._in_q
 
 
 # ---------------------------------------------------------------------------

@@ -8,7 +8,10 @@ launched step's output became ready, and the harvest turns the stamp into
 * both pipes: the H.264 pipe over a fake base encoder whose step output and
   fetch land when the test says, the JPEG pipe over the real tiny encoder
   with its step's ``packed`` wrapped so that the watch sees it ready when
-  the test says.
+  the test says;
+* what the pipes admit a capture by (ISSUE 47, ``has_room``): the watch's
+  count of unfinished steps, never above the pipe's own frames, and
+  ``depth`` alone once the watch has stopped.
 """
 
 from __future__ import annotations
@@ -524,3 +527,119 @@ def test_a_watch_its_owner_stopped_resumes_and_keeps_its_counts():
     watch.join(2.0)
     watch.resume()
     assert watch.stopped
+
+
+# ---------------------------------------------------------------------------
+# what a pipe admits a capture by (ISSUE 47)
+
+
+@pytest.fixture(params=["jpeg", "h264"])
+def by_hand(request):
+    """A pipe of depth 4 whose steps end when the test says: ``submit()``,
+    ``end(k)`` (step k's output is ready, for the watch to see) and
+    ``harvest()`` (every frame in flight is fetched and packed, whether
+    the watch has seen its step end or not)."""
+    if request.param == "jpeg":
+        pipe, outs = request.getfixturevalue("jpeg_pipe")
+        pictures = iter(frames(16))
+        yield SimpleNamespace(
+            pipe=pipe, submit=lambda: pipe.submit(next(pictures)),
+            out=lambda k: outs[k], harvest=pipe.flush)
+        return
+    pipe, base, frame = h264_pipe()
+
+    def harvest():
+        for p in base.pendings:
+            p.fetch.gate.set()
+        return pipe.flush()
+
+    yield SimpleNamespace(pipe=pipe, submit=lambda: pipe.submit(frame),
+                          out=lambda k: base.pendings[k].buf,
+                          harvest=harvest)
+    for p in base.pendings:
+        land(p)
+    pipe.close()
+
+
+def test_a_pipe_has_room_while_fewer_than_two_steps_are_unfinished(by_hand):
+    """One rule for both pipes: room while fewer than ``depth`` frames are
+    unpacked and, of them, fewer than two steps unfinished on the chip
+    (the one that runs; after the launch, one queued behind it); the
+    launches the second bound held back are counted, once each, and
+    those that ``depth`` refused are not."""
+    h, pipe = by_hand, by_hand.pipe
+    watch = pipe._ready_watch
+    assert pipe.depth == 4 and pipe.CHIP_STEPS == 2
+    assert pipe.has_room                         # empty
+    h.submit()
+    assert pipe.has_room                         # one runs
+    h.submit()
+    assert not pipe.has_room and not pipe.has_room   # one runs, one queued
+    assert pipe.n_inflight == 2 < pipe.depth
+    assert pipe.stats()["launches_held_for_chip"] == 0   # none launched yet
+    h.out(0).land()
+    until(lambda: watch.readied == 1)
+    assert pipe.has_room                         # its end made room
+    h.submit()
+    assert pipe.stats()["launches_held_for_chip"] == 1   # asked twice: one
+    assert not pipe.has_room
+    h.out(1).land()
+    until(lambda: watch.readied == 2)
+    h.submit()
+    assert pipe.stats()["launches_held_for_chip"] == 2
+    # four frames unpacked: depth's bound, whatever the chip still holds
+    h.out(2).land()
+    h.out(3).land()
+    until(lambda: watch.ahead == 0)
+    assert pipe.n_inflight == 4 and not pipe.has_room
+    assert len(h.harvest()) == 4 and pipe.has_room
+    h.submit()
+    st = pipe.stats()
+    assert (st["launches"], st["launches_held_for_chip"]) == (5, 2)
+    h.out(4).land()
+
+
+def test_an_empty_pipe_has_room_whatever_the_watch_still_counts(by_hand):
+    """Stamps that never land: the steps unfinished are never taken to
+    exceed the pipe's own frames in flight, so an empty pipe has room,
+    and so has one with a single frame."""
+    h, pipe = by_hand, by_hand.pipe
+    h.submit()
+    h.submit()
+    assert not pipe.has_room
+    assert len(h.harvest()) == 2                 # packed; never stamped
+    assert pipe._ready_watch.ahead == 2 and pipe.n_inflight == 0
+    assert pipe.has_room
+    h.submit()
+    assert pipe._ready_watch.ahead == 3 and pipe.has_room
+    h.submit()
+    assert not pipe.has_room                     # two of its own frames
+    assert len(h.harvest()) == 2 and pipe.has_room
+    assert pipe.stats()["ready_stamps_missed"] == 4
+    for k in range(4):
+        h.out(k).land()
+
+
+@pytest.mark.parametrize("by", ["its owner", "an array that raised"])
+def test_a_stopped_watch_leaves_depth_as_the_only_bound(by_hand, by, caplog):
+    h, pipe = by_hand, by_hand.pipe
+    watch = pipe._ready_watch
+    n = 0
+    if by == "its owner":
+        watch.stop()
+    else:
+        with caplog.at_level(logging.WARNING,
+                             "selkies_tpu.observability.device_probe"):
+            h.submit()
+            h.out(0).raises = RuntimeError("deleted buffer")
+            h.out(0).land()
+            watch.join(2.0)
+        n = 1
+    assert watch.stopped
+    for _ in range(n, pipe.depth):
+        assert pipe.has_room                     # as before ISSUE 47
+        h.submit()
+    assert pipe.n_inflight == pipe.depth and not pipe.has_room
+    st = pipe.stats()
+    assert (st["launches"], st["launches_held_for_chip"]) == (n, 0)
+    assert len(h.harvest()) == pipe.depth and pipe.has_room
