@@ -37,16 +37,15 @@ stuffing (0xFF→0xFF00) happens on host over the ~75 KB result.
 Overflow containment: a block whose bitstream exceeds ``32*block_words``
 bits, or a stripe exceeding ``max_stripe_bytes``, flags its stripe in the
 returned ``overflow`` array; flagged stripes are host-coded by the caller
-(encoder/jpeg.py _scans_from_packed). The default ``block_words=56`` covers
-the worst legal JPEG block (~1660 bits), so overflow can only be a stripe-
-size event; the streaming pipeline uses the faster ``block_words=16``
-variant where pathological blocks fall back to the host coder.
+(encoder/jpeg.py _scans_from_packed), bit-exactly. Both budgets are the
+packer's own (16 words, :func:`default_max_stripe_bytes`); 56 words would
+cover the worst legal JPEG block (~1660 bits) at 3.5x the slot work.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -153,6 +152,27 @@ def _lut512(idx_flat):
     return picked.astype(jnp.int32)
 
 
+def default_max_stripe_bytes(stripe_h: int, pad_w: int) -> int:
+    """Per-stripe scan capacity of the device pack, from the stripe's padded
+    geometry alone: 16 KiB up to 64x1920 pixels, above that a sixth of a
+    byte a pixel rounded up to 1,024 words (28 KiB at 64x2560, 40 KiB at
+    64x3840).
+
+    Fitted to the benchmark's synthetic desktop at quality 40, scans
+    without their headers (tests/test_jpeg_budget.py reads them again):
+    1080p stripes reach 13.3 kB (0.108 B a pixel, 16 KiB is 1.23x that),
+    4K stripes 34.8 kB (0.142 B a pixel, a sixth is 1.18x). The margin is
+    thin because capacity costs step time whether filled or not
+    (docs/entropy.md, The JPEG tier's budget), and it was not read on real
+    content: a stripe past it is flagged and coded by the host, so
+    ``host_fallback_stripes`` of ``stripes_emitted`` is what to watch.
+    """
+    px = stripe_h * pad_w
+    if px <= 64 * 1920:
+        return 16 * 1024
+    return -(-px // (6 * 4096)) * 4096
+
+
 class DeviceEntropyPacker:
     """Per-geometry compiled entropy pack: coefficients → packed bitstreams.
 
@@ -173,13 +193,16 @@ class DeviceEntropyPacker:
         pad_h: int,
         pad_w: int,
         stripe_h: int,
-        max_stripe_bytes: int = 1 << 15,
-        block_words: int = 56,
+        max_stripe_bytes: Optional[int] = None,
+        block_words: int = 16,
     ) -> None:
         perm, is_chroma, dc_prev, bps = scan_geometry(pad_h, pad_w, stripe_h)
         self.n_stripes = pad_h // stripe_h
         self.blocks_per_stripe = bps
-        self.max_stripe_words = max_stripe_bytes // 4
+        self.max_stripe_words = (
+            max_stripe_bytes or default_max_stripe_bytes(stripe_h, pad_w)) // 4
+        if self.max_stripe_words >= 1 << 15:
+            raise ValueError("a stripe's word index is carried in 15 bits")
         self.block_words = block_words
         self.cap_words = self.n_stripes * self.max_stripe_words
 

@@ -123,14 +123,7 @@ def _device_pipeline(pad_h: int, pad_w: int, stripe_h: int,
     shared event loop otherwise)."""
     from .device_entropy import DeviceEntropyPacker
 
-    # Streaming fast path: 16-word (512-bit) per-block budget and a 16 KB
-    # per-stripe cap (typical q40 1080p stripes are ~3 KB; the boundary
-    # machinery costs ~10 ns per word-slot, so halving the cap buys ~3 ms
-    # per frame). Blocks/stripes beyond either budget flag their stripe,
-    # which falls back to the host coder in _scans_from_packed — output
-    # stays bit-exact.
-    packer = DeviceEntropyPacker(pad_h, pad_w, stripe_h, block_words=16,
-                                 max_stripe_bytes=1 << 14)
+    packer = DeviceEntropyPacker(pad_h, pad_w, stripe_h)
     packer_fn = packer._pack_fn
     n_stripes = pad_h // stripe_h
 
@@ -240,6 +233,7 @@ class JpegStripeEncoder:
         #: sustained growth means the device packing budget is wrong for
         #: this content and the degradation ladder's host rung is cheaper
         self.host_fallback_stripes_total = 0
+        self.stripes_emitted_total = 0      # the whole they are a share of
 
         #: first-use compile signal for this encoder's step (read by
         #: the capture loop's wedge detector through the wrappers)
@@ -407,6 +401,7 @@ class JpegStripeEncoder:
         for s in range(self.n_stripes):
             if not emit[s]:
                 continue
+            self.stripes_emitted_total += 1
             if ovf_np[s]:  # pathological stripe: host-code its coeffs
                 self.host_fallback_stripes_total += 1
                 scans[s] = _entropy_encode_420(

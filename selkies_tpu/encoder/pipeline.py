@@ -205,7 +205,7 @@ class _FetchGroup:
     """One D2H read and the JPEG frames it brings: their packed buffers
     concatenated on device. A ``fetch_group`` above 1 was sized for a
     device that paid a fixed latency per read; whether this one still
-    wants it is ROADMAP D10."""
+    wants it is ROADMAP D8."""
 
     arr: Any                        # device array, one async host copy
     stride: int = 0                 # member size in a 1-D concat; 0: one
@@ -298,11 +298,11 @@ class PipelinedJpegEncoder(_PipelineTelemetry):
             "d2h_bytes_per_frame": self.d2h_bytes_total / n,
             "host_entropy_ms_per_frame": self.host_entropy_ms_total / n,
             "frames_dropped": self.frames_dropped_total,
-            "host_fallback_stripes": getattr(
-                self.base, "host_fallback_stripes_total", 0),
-            "entropy": self.base.entropy,
+            "host_fallback_stripes": self.base.host_fallback_stripes_total,
+            "stripes_emitted": self.base.stripes_emitted_total,
             "staging_stalls": self._staging.stalls_total,
             **self._telemetry_stats(),
+            "entropy": self.base.entropy,
         }
 
     def _publish_metrics(self) -> None:

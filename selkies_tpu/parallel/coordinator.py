@@ -778,7 +778,14 @@ class MeshEncodeCoordinator:
 
     def launch_stats(self) -> dict:
         """Integers with one writer each: read without the lock."""
-        return self._ready_watch.counts()
+        encs = [ln.enc for ln in self.lanes]      # H.264's has no whole yet
+        return {
+            **self._ready_watch.counts(),
+            "host_fallback_stripes": sum(
+                getattr(e, "host_fallback_stripes_total", 0) for e in encs),
+            "stripes_emitted": sum(
+                getattr(e, "stripes_emitted_total", 0) for e in encs),
+        }
 
     def stats(self) -> dict:
         """Scheduler + per-slot fault accounting for health feeds/tests."""

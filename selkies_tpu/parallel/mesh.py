@@ -268,10 +268,7 @@ def make_batched_entropy_step(mesh: Mesh, pad_h: int, pad_w: int,
     if pad_h % (n_stripe_ax * stripe_h):
         raise ValueError("pad_h must divide into stripe_ax × stripe_h bands")
     h_local = pad_h // n_stripe_ax
-    # Same budgets as the solo streaming path (jpeg._device_pipeline):
-    # pathological blocks/stripes overflow-flag and fall back to host coding.
-    packer = DeviceEntropyPacker(h_local, pad_w, stripe_h,
-                                 block_words=16, max_stripe_bytes=1 << 14)
+    packer = DeviceEntropyPacker(h_local, pad_w, stripe_h)
     s_local = h_local // stripe_h
     mw = 4 * s_local
     cap = packer.cap_words
@@ -487,6 +484,8 @@ class MeshStripeEncoder:
         #: adaptive D2H prefix (words per (session, shard) fetched besides
         #: metadata); a miss costs one extra read of the missing slice
         self._guess = self._packer.bucket_words(8192)
+        self.stripes_emitted_total = 0        # and those the host coded:
+        self.host_fallback_stripes_total = 0
         #: fetch/concat split of the latest harvest wall, with per-shard
         #: fetch attribution (the coordinator's flight-recorder feed)
         self.last_harvest_stages: Optional[dict] = None
@@ -686,7 +685,9 @@ class MeshStripeEncoder:
             g = k * self.s_local + s
             if not emit[g]:
                 continue
+            self.stripes_emitted_total += 1
             if ovf[s]:  # pathological stripe: host-code its coefficients
+                self.host_fallback_stripes_total += 1
                 scan = _entropy_encode_420(
                     np.asarray(yq[n, g * yrows:(g + 1) * yrows]),
                     np.asarray(cbq[n, g * crows:(g + 1) * crows]),

@@ -312,6 +312,7 @@ def test_host_low_tier_count_equals_the_device_predicate(monkeypatch):
         pipe.submit(frame)
         pipe.poll()
     pipe.flush()
+    pipe.close()        # or its ready thread outlives the test
     st = pipe.stats()
     V = msb // 4
     rungs = (V, V // 4, V // 16)         # 64 B/MB and 16 B/MB of 64 MBs

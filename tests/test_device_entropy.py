@@ -29,13 +29,17 @@ def host_reference(yq, cbq, crq, stripe_h):
     ]
 
 
-@pytest.mark.parametrize("pad_h,pad_w,stripe_h,density", [
-    (64, 64, 64, 0.15),
-    (128, 96, 64, 0.3),
-    (192, 128, 64, 0.02),
-    (64, 32, 32, 0.6),
+# the budgets the server runs (16 words a block, the rule's bytes a stripe)
+# where a case names none; blocks this dense need the 56 words that hold
+# the worst legal block
+@pytest.mark.parametrize("pad_h,pad_w,stripe_h,density,budget", [
+    (64, 64, 64, 0.15, {}),
+    (128, 96, 64, 0.3, {"block_words": 56}),
+    (192, 128, 64, 0.02, {}),
+    (64, 32, 32, 0.6, {"block_words": 56}),
 ])
-def test_device_pack_matches_host_oracle(pad_h, pad_w, stripe_h, density):
+def test_device_pack_matches_host_oracle(pad_h, pad_w, stripe_h, density,
+                                         budget):
     rng = np.random.default_rng(pad_h * 1000 + pad_w)
     by, bx = pad_h // 8, pad_w // 8
     cby, cbx = pad_h // 16, pad_w // 16
@@ -43,7 +47,7 @@ def test_device_pack_matches_host_oracle(pad_h, pad_w, stripe_h, density):
     cbq = random_coeffs(rng, cby, cbx, density / 2, amp=200)
     crq = random_coeffs(rng, cby, cbx, density / 2, amp=200)
 
-    packer = DeviceEntropyPacker(pad_h, pad_w, stripe_h)
+    packer = DeviceEntropyPacker(pad_h, pad_w, stripe_h, **budget)
     words, nbytes, base_words, overflow = packer.pack(yq, cbq, crq)
     assert not np.asarray(overflow).any()
     stripes = words_to_stripe_bytes(
@@ -64,7 +68,9 @@ def test_device_pack_extreme_values():
     yq[:, :, 0] = rng.integers(-1000, 1000, size=(by, bx))  # wild DC deltas
     cbq = random_coeffs(rng, 4, 4, 0.9, amp=800)
     crq = random_coeffs(rng, 4, 4, 0.9, amp=800)
-    packer = DeviceEntropyPacker(pad_h, pad_w, 64)
+    # blocks near the worst legal one need its 56 words (the 96 of them
+    # together stay under the served 16 KiB)
+    packer = DeviceEntropyPacker(pad_h, pad_w, 64, block_words=56)
     words, nbytes, base_words, overflow = packer.pack(yq, cbq, crq)
     assert not np.asarray(overflow).any()
     dev = words_to_stripe_bytes(

@@ -65,6 +65,7 @@ def _through_pipe(enc, frames, depth=3):
         pipe.submit(f)
         out.update(pipe.poll())
     out.update(pipe.flush())
+    pipe.close()        # or its ready thread outlives the test
     return [(_bytes(out[i]),) + counters[i]
             for i in range(len(frames))], pipe.stats()
 

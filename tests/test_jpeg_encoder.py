@@ -151,6 +151,7 @@ def test_pipelined_encoder_matches_sync():
             got[seq] = [(s.y_start, s.jpeg) for s in stripes]
     for seq, stripes in pipe.flush():
         got[seq] = [(s.y_start, s.jpeg) for s in stripes]
+    pipe.close()        # or its ready thread outlives the test
     assert [got[i] for i in range(len(frames))] == want
 
 
@@ -168,6 +169,7 @@ def test_pipelined_paintover_not_duplicated():
         pipe.submit(frame)
         outs.extend(s for _, st in pipe.poll() for s in st)
     outs.extend(s for _, st in pipe.flush() for s in st)
+    pipe.close()
     paint = [s for s in outs if s.is_paintover]
     assert len(paint) == 1
 
@@ -190,6 +192,7 @@ def test_pipeline_partial_group_flushed_by_poll():
         got += enc.poll()
         if len(got) == 2:
             break
+    enc.close()
     assert len(got) == 2
     assert all(stripes for _, stripes in got)
 

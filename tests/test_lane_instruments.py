@@ -396,6 +396,20 @@ def test_a_served_lanes_frames_carry_the_ready_watchs_three_and_they_tile(
     assert st["launches"] == served["coord"].stats()["launches"]
 
 
+def test_a_served_lane_counts_the_stripes_it_emitted_and_the_hosts_share(
+        served):
+    """``encoder_share`` reads a part over a whole, both keys of ``stats()``:
+    the lane's, as the solo pipe's, has both."""
+    st = served["facades"][0].stats()
+    assert st["host_fallback_stripes"] == 0
+    assert st["stripes_emitted"] >= sum(
+        len(tr) for tr in served["traces"].values()) > 0
+
+
+#: what ``stats()`` says of stripes for an encoder that codes none
+NO_STRIPES = {"host_fallback_stripes": 0, "stripes_emitted": 0}
+
+
 class StepOut:
     """A lane step's output: ready when the test says."""
 
@@ -457,7 +471,8 @@ def test_a_lanes_launches_into_idle_are_those_with_nothing_ahead():
         facade.try_submit(b"1")
         coord._tick()                        # step 0 unfinished
         assert facade.stats() == {"launches": 2, "launches_into_idle": 1,
-                                  "ready_stamps_missed": 0}
+                                  "ready_stamps_missed": 0,
+                                  **NO_STRIPES}
         enc.outs[0].gate.set()
         enc.outs[1].gate.set()
         wait_for(lambda: watch.readied == 2)
@@ -494,7 +509,8 @@ def test_a_lane_frame_harvested_before_its_stamp_has_none_and_is_counted():
         iv = facade.pop_trace(seq)
         assert set(iv) == set(TILING) | {"lane_step"}
         assert facade.stats() == {"launches": 1, "launches_into_idle": 1,
-                                  "ready_stamps_missed": 1}
+                                  "ready_stamps_missed": 1,
+                                  **NO_STRIPES}
         enc.outs[0].gate.set()
     finally:
         watch.stop()
